@@ -14,9 +14,10 @@ import jax.numpy as jnp
 
 from repro.core import BGConfig, add_gaussian_noise, grid_shape, synthetic_image
 from repro.kernels import bilateral_grid_filter_pallas
+from repro.launch.hlo_analysis import TARGET_KIND, chip_peaks
 
-HBM_BW = 819e9
-PEAK = 197e12
+HBM_BW = chip_peaks(TARGET_KIND)["hbm_bw"]
+PEAK = chip_peaks(TARGET_KIND)["peak_flops"]
 
 
 def traffic_model(h, w, cfg):

@@ -10,9 +10,11 @@ so the CPU curve is a dispatch-correctness/overhead measurement, not a
 speedup claim (labeled as such). On a TPU backend the same code path uses the
 real chips.
 
-The measurement runs in a subprocess: the parent bench process has already
-initialized jax with its default single-device view, and the forced device
-count must be set before the first jax import.
+Off-TPU the measurement runs in a subprocess: the parent bench process has
+already initialized jax with its default single-device view, and the forced
+device count must be set before the first jax import. On a TPU it runs in
+this process over the real chips: a chip belongs to one process, and the
+parent that has touched JAX already holds them.
 
 Emits two gated ``ratio/`` rows (sharded scaling d8-vs-d2, and sharded-8dev
 vs single-device frames/sec) for the hardware-independent regression gate in
@@ -41,48 +43,56 @@ DEVICE_COUNTS = (1, 2, 4, 8)
 SCALING_RATIO_FLOOR = 0.25
 VS_SINGLE_RATIO_FLOOR = 0.2
 
-_CHILD = """
-import json, time
-import jax
-from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
-from repro.sharding.bg_shard import batch_mesh, bg_denoise_sharded
+def measure(h, w, r, b, reps, counts):
+    """Best-of-``reps`` seconds per ``b``-frame sharded dispatch for each
+    device count in ``counts`` that this process can see."""
+    import time
 
-quick, h, w, r, b, reps, counts = json.loads({params!r})
-cfg = BGConfig(r=r, sigma_s=4.0, sigma_r=60.0)
-noisy = add_gaussian_noise(synthetic_batch(b, h, w, seed=0), 30.0, seed=1)
-results = []
-for nd in counts:
-    if nd > jax.device_count():
-        continue
-    mesh = batch_mesh(nd)
-    def call():
-        jax.block_until_ready(bg_denoise_sharded(noisy, cfg, mesh=mesh))
-    call()  # warm-up / compile
-    ts = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        call()
-        ts.append(time.perf_counter() - t0)
-    results.append([nd, min(ts)])
-print("RESULT " + json.dumps(results))
-"""
+    import jax
+
+    from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
+    from repro.sharding.bg_shard import batch_mesh, bg_denoise_sharded
+
+    cfg = BGConfig(r=r, sigma_s=4.0, sigma_r=60.0)
+    noisy = add_gaussian_noise(synthetic_batch(b, h, w, seed=0), 30.0, seed=1)
+    results = []
+    for nd in counts:
+        if nd > jax.device_count():
+            continue
+        mesh = batch_mesh(nd)
+
+        def call():
+            jax.block_until_ready(bg_denoise_sharded(noisy, cfg, mesh=mesh))
+
+        call()  # warm-up / compile
+        ts = []
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            call()
+            ts.append(time.perf_counter() - t0)
+        results.append([nd, min(ts)])
+    return results
 
 
-def run(quick: bool = False):
-    h, w, r = (32, 48, 4) if quick else (64, 96, 6)
-    b = 8 if quick else 16
-    reps = 3 if quick else 5
-    params = json.dumps([quick, h, w, r, b, reps, list(DEVICE_COUNTS)])
+_CHILD = (
+    "import json, sys; "
+    "from benchmarks.bench_bg_sharded import measure; "
+    "print('RESULT ' + json.dumps(measure(*json.loads(sys.argv[1]))))"
+)
+
+
+def _measure_forced_host_mesh(params):
+    """``measure`` in a child with 8 forced host devices (CPU hosts only)."""
     xla_flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in xla_flags:
         xla_flags = f"{xla_flags} --xla_force_host_platform_device_count=8".strip()
     env = dict(
         os.environ,
         XLA_FLAGS=xla_flags,
-        PYTHONPATH=os.path.join(REPO_ROOT, "src"),
+        PYTHONPATH=os.pathsep.join([os.path.join(REPO_ROOT, "src"), REPO_ROOT]),
     )
     out = subprocess.run(
-        [sys.executable, "-c", _CHILD.format(params=params)],
+        [sys.executable, "-c", _CHILD, json.dumps(params)],
         capture_output=True,
         text=True,
         timeout=900,
@@ -94,7 +104,20 @@ def run(quick: bool = False):
             f"sharded bench subprocess failed:\n{out.stdout}\n{out.stderr[-3000:]}"
         )
     line = next(l for l in out.stdout.splitlines() if l.startswith("RESULT "))
-    results = json.loads(line[len("RESULT "):])
+    return json.loads(line[len("RESULT "):])
+
+
+def run(quick: bool = False):
+    h, w, r = (32, 48, 4) if quick else (64, 96, 6)
+    b = 8 if quick else 16
+    reps = 3 if quick else 5
+    params = [h, w, r, b, reps, list(DEVICE_COUNTS)]
+    import jax
+
+    if jax.default_backend() == "tpu":
+        results = measure(*params)
+    else:
+        results = _measure_forced_host_mesh(params)
 
     rows = []
     fps_by_nd = {}

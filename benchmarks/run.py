@@ -164,6 +164,10 @@ def main() -> None:
     )
     args, _ = ap.parse_known_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         bench_bg_chaos,
         bench_bg_fleet,

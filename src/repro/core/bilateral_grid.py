@@ -183,15 +183,17 @@ def conv3_axis(x: jnp.ndarray, taps, axis: int) -> jnp.ndarray:
     the TPU kernels, (gy, gz, 2) in the streaming scan — so the caller's
     comment, not this helper, names which grid axis is being blurred.
     """
-    lo = jnp.roll(x, 1, axis=axis)
-    hi = jnp.roll(x, -1, axis=axis)
-    # zero the wrapped-around slices
-    idx_first = [slice(None)] * x.ndim
-    idx_first[axis] = slice(0, 1)
-    idx_last = [slice(None)] * x.ndim
-    idx_last[axis] = slice(-1, None)
-    lo = lo.at[tuple(idx_first)].set(0.0)
-    hi = hi.at[tuple(idx_last)].set(0.0)
+    # shift by one with a zero slab in place of the wrapped slice: exact,
+    # no scatter (the Pallas TPU lowering has none), and the same bits as
+    # rolling and then zeroing the wrapped slice
+    n = x.shape[axis]
+    zero = jnp.zeros_like(jax.lax.slice_in_dim(x, 0, 1, axis=axis))
+    lo = jnp.concatenate(
+        [zero, jax.lax.slice_in_dim(x, 0, n - 1, axis=axis)], axis=axis
+    )
+    hi = jnp.concatenate(
+        [jax.lax.slice_in_dim(x, 1, n, axis=axis), zero], axis=axis
+    )
     return taps[0] * lo + taps[1] * x + taps[2] * hi
 
 

@@ -169,6 +169,17 @@ class SubprocessWorker(Worker):
             )
         if transport not in ("unix", "tcp"):
             raise ValueError(f"transport must be 'unix' or 'tcp', got {transport!r}")
+        import jax
+
+        if jax.default_backend() == "tpu":
+            # a chip belongs to one process: this one already holds every
+            # local chip, so a child engine could not load the TPU library
+            raise RuntimeError(
+                "SubprocessWorker cannot run on a TPU host: this process "
+                "holds the chips and nothing gives each worker process a "
+                "chip of its own; use worker_backend='local' (one engine "
+                "per worker in this process)"
+            )
         self.wid = wid
         self._payload = payload
         self._hash = payload["plan_hash"]

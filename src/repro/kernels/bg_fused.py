@@ -40,7 +40,9 @@ Streaming input path — explicit double-buffered HBM->VMEM DMA
 -------------------------------------------------------------
 ``stream_input=True`` replaces Pallas's automatic input pipelining with an
 explicit two-slot DMA pipeline: the image stays in HBM (`pl.ANY` operand,
-laid out `(tiles, stripes, bt, r, w)`) and the kernel prefetches stripe s+1
+laid out `(tiles, stripes, bt, rp, w)`, each stripe zero-padded from r to a
+whole number rp of sublane tiles so every DMA window is tile-aligned) and
+the kernel prefetches stripe s+1
 into slot `(s+1) % 2` with `pltpu.make_async_copy` while computing stripe s
 from slot `s % 2`. The validity mask is not streamed at all — it is
 synthesized in-kernel from the frame/row counters (the FPGA's counter logic),
@@ -80,8 +82,8 @@ plane:
 so the grid still never round-trips HBM mid-frame: the warm path keeps the
 one-image-read/one-image-write traffic and adds only the grid-sized carry
 (two to three orders of magnitude smaller than the frame) as an extra
-input + output. ``alpha`` is a per-frame vector riding a tiny SMEM block,
-so one dispatch freely mixes warm streams (``a > 0``), cold streams and
+input + output. ``alpha`` is a per-frame ``(bt, 1, 1, 1)`` VMEM block, so
+one dispatch freely mixes warm streams (``a > 0``), cold streams and
 first-frame streams (``a == 0``) — an ``a == 0`` frame's blend is the exact
 float identity ``1.0*B + 0.0*C == B``, making its output *bit-identical* to
 the non-temporal path no matter which streams share the batch (asserted in
@@ -89,6 +91,14 @@ tests/test_temporal_fused.py). When ``h % r == 0`` the temporal grid runs
 one extra drain step so the last carry plane (``gx - 1``, which TI never
 reads but the EMA recursion must still advance) is produced; TI output
 writes are masked off for that step.
+
+Block layout (what Mosaic accepts): frames travel as ``(b, n, r, w)`` —
+the ``(b, h, w)`` array split into n stripes of r rows — and every image,
+mask and output block is ``(bt, <stripe>, r, w)`` with the stripe axis
+squeezed, so the last two block dims are whole array dims for any r (Mosaic
+requires the second-minor block dim to be a multiple of 8 or the whole dim).
+Every constant operand is built at the rank the kernel uses it at, and the
+GF blur shifts with zero slabs instead of a scatter.
 
 Paper normalization mode (eq. 4) only; r*gz is bounded (see common.py), so
 per-step temporaries are O(bt*r*gz*w) — a few MB for full-HD frames at the
@@ -188,6 +198,9 @@ def _pipeline_step(
     zgi = jnp.einsum(
         "bcizw,wg->bcizg", both, col_oh,
         preferred_element_type=jnp.float32,
+        # fp32 storage: HIGHEST keeps fp32 intensities exact on the MXU;
+        # bf16 operands are exact at the default (and refuse HIGHEST)
+        precision=jax.lax.Precision.HIGHEST if sdt == jnp.float32 else None,
     )  # one matmul, not four; fp32 accumulation
     contrib_cur = zgi[:, :, :split].sum(axis=2)  # (bt, 2, gz, gy) -> plane s
     contrib_next = zgi[:, :, split:].sum(axis=2)  # -> plane s+1
@@ -203,24 +216,17 @@ def _pipeline_step(
     if blend is not None:
         # ---- temporal EMA of the blurred homogeneous plane, in VMEM.
         # a == 0 frames reduce to the exact float identity 1*mix + 0*carry
-        # == mix (all operands are finite and non-negative), which is what
-        # makes the cold rows of a mixed pack bit-identical to the
-        # non-temporal kernel.
+        # == mix (all operands are finite and non-negative, with or without
+        # FMA contraction), which is what makes the cold rows of a mixed pack
+        # bit-identical to the non-temporal kernel.
+        # For a > 0 the compiler may contract the blend into an FMA
+        # differently per dispatch geometry (batch tile, mesh shard, and in
+        # interpret mode per XLA fusion), so warm rows are reproducible only
+        # within one geometry: across geometries the carry and the
+        # unquantized image agree to a few ulp, and the quantized image
+        # agrees bitwise except where a value sits on a rounding edge.
         carry_plane, a, carry_out_ref = blend
-        # The barriers materialize the two blend products exactly once, so
-        # the stored carry and the TI consumer below derive from identical
-        # bits within a dispatch (XLA would otherwise duplicate the blend
-        # into both fusions with potentially different FMA contraction).
-        # Across *different* dispatch geometries (batch tile, mesh shard)
-        # the carry may still differ by <= 1 ulp — LLVM picks FMA lanes per
-        # loop shape — while the image output is bit-stable; the contract
-        # tests assert image bitwise + carry ulp-tolerance accordingly.
-        # a == 0 stays the exact identity (1*mix + 0*carry == mix) either
-        # way, all operands being finite and non-negative.
-        one_minus_a = jax.lax.optimization_barrier(1.0 - a)
-        mix = jax.lax.optimization_barrier(
-            one_minus_a * mix
-        ) + jax.lax.optimization_barrier(a * carry_plane)
+        mix = (1.0 - a) * mix + a * carry_plane
         carry_out_ref[0, 0] = mix.astype(carry_out_ref.dtype)
     b_new = jnp.where(
         mix[:, 0] > 1e-12, mix[:, 1] / jnp.maximum(mix[:, 0], 1e-12), 0.0
@@ -236,21 +242,28 @@ def _pipeline_step(
         jnp.where(z0[:, :, None, :] == zi2, 1.0, 0.0) * (1.0 - zfr)[:, :, None, :]
         + jnp.where((z0 + 1)[:, :, None, :] == zi2, 1.0, 0.0) * zfr[:, :, None, :]
     ).astype(sdt)  # (bt, r, gz, w) — storage dtype (the other big stack)
-    planes = jnp.stack([b1_s[...].astype(jnp.float32), b_new], axis=0).astype(
-        sdt
+    # Both planes are rounded to the storage dtype (plane s-1 is read back
+    # from storage on the next step) but the stack itself is fp32: Mosaic
+    # cannot fold a packed bf16 (2, bt, gz, gy) into matmul rows when gz is
+    # not a multiple of 16, and a one-hot gather of bf16 values is exact in
+    # fp32, so this keeps the bf16 numerics bit for bit.
+    planes = jnp.stack(
+        [b1_s[...].astype(jnp.float32), b_new.astype(sdt).astype(jnp.float32)],
+        axis=0,
     )  # (2, bt, gz, gy)
     # all four y-corner gathers in one contraction over gy (minor on both
-    # operands: no transposition of the planes); fp32 accumulation
+    # operands: no transposition of the planes). HIGHEST keeps the fp32
+    # plane values exact on the MXU (the default would round them to bf16).
     gathered = jnp.einsum(
         "pbzg,cwg->pbzcw", planes, y_oh,
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (2, bt, gz, 2, w)
     # fold the x/y lerp weights before the z contraction (linearity)
     wy = gathered[:, :, :, 0] * (1.0 - yf) + gathered[:, :, :, 1] * yf
     q = (
-        wy[0][:, None] * (1.0 - xf)[None, :, None, None]
-        + wy[1][:, None] * xf[None, :, None, None]
-    )  # (bt, r, gz, w)
+        wy[0][:, None] * (1.0 - xf) + wy[1][:, None] * xf
+    )  # (bt, r, gz, w); xf is (1, r, 1, 1)
     sliced = jnp.sum(wz.astype(jnp.float32) * q, axis=2)
     if ti_valid is None:
         out_ref[...] = sliced.astype(out_ref.dtype)
@@ -300,11 +313,11 @@ def _kernel(
     s = pl.program_id(1)  # stripe index (minor grid dim; program_id(0) = tile)
     if temporal:
         # extra operands: carry plane (blocked (1, 1, bt, 2, gz, gy)) and the
-        # per-frame alpha vector (a tiny (1, bt) SMEM block); extra output:
-        # the blended plane written back as the new carry.
+        # per-frame alpha, a (bt, 1, 1, 1) VMEM block already at the rank it
+        # broadcasts at; extra output: the blended plane written back as the
+        # new carry.
         carry_ref, alpha_ref, out_ref, carry_out_ref, *scratch = rest
-        a = alpha_ref[...].reshape(-1, 1, 1, 1)  # (bt, 1, 1, 1)
-        blend = (carry_ref[0, 0].astype(jnp.float32), a, carry_out_ref)
+        blend = (carry_ref[0, 0].astype(jnp.float32), alpha_ref[...], carry_out_ref)
         ti_valid = s < n_stripes + 2  # mask TI on the extra carry drain step
     else:
         out_ref, *scratch = rest
@@ -324,8 +337,8 @@ def _kernel(
         msk,
         col_ref[...],
         yoh_ref[...],
-        yf_ref[0],
-        xf_ref[0],
+        yf_ref[...],
+        xf_ref[...],
         out_ref,
         r2_s,
         r1_s,
@@ -368,9 +381,10 @@ def _stream_kernel(
     b,
     h,
 ):
-    """Double-buffered variant: ``img_hbm`` is the full (nb, n, bt, r, w)
-    image in HBM; stripe blocks are DMA'd into the two ``px_slots`` with the
-    next stripe in flight while the current one computes."""
+    """Double-buffered variant: ``img_hbm`` is the full (nb, n, bt, rp, w)
+    image in HBM (stripes zero-padded from r to rp rows); stripe blocks are
+    DMA'd into the two ``px_slots`` with the next stripe in flight while the
+    current one computes."""
     bi = pl.program_id(0)
     s = pl.program_id(1)
     slot = jax.lax.rem(s, 2)
@@ -398,7 +412,7 @@ def _stream_kernel(
         # overlap: stripe s+1 streams in while stripe s computes below
         stripe_dma(s + 1, jax.lax.rem(s + 1, 2)).start()
 
-    px = px_slots[slot].astype(jnp.float32)
+    px = px_slots[slot, :, :r, :].astype(jnp.float32)  # drop the row padding
     # The validity mask is never streamed: synthesize it from the frame/row
     # counters (padding frames of the last tile and padding rows of the last
     # stripe are 0, drain steps are 0 via `live`) — identical values to the
@@ -412,8 +426,8 @@ def _stream_kernel(
         msk,
         col_ref[...],
         yoh_ref[...],
-        yf_ref[0],
-        xf_ref[0],
+        yf_ref[...],
+        xf_ref[...],
         out_ref,
         r2_s,
         r1_s,
@@ -505,23 +519,33 @@ def bg_fused_impl(
     bt = max(1, min(bt, b))
     nb = -(-b // bt)
     bp = nb * bt
+    # (bp, hp, w) -> (bp, n, r, w): every block is then (bt, <stripe>, r, w)
+    # with the stripe axis squeezed, so the last two block dims are whole
+    # array dims — what Mosaic requires for any r, not only multiples of 8.
     img_p = jnp.pad(
         image.astype(jnp.float32), ((0, bp - b), (0, hp - h), (0, 0))
-    ).astype(sdt)
+    ).astype(sdt).reshape(bp, n, r, w)
 
     oh0, oh1, yf = ti_col_onehots(w, gy, r)
     taps = tuple(float(t) for t in taps_np(cfg))
     const = lambda shape: pl.BlockSpec(shape, lambda bi, s: tuple(0 for _ in shape))
-    frame_spec = lambda imap: pl.BlockSpec((bt, r, w), imap)
-    # the one-hot matmul operands travel in the storage dtype (their 0/1
-    # entries are exact in bf16); the lerp fractions stay fp32
+    stripe_spec = lambda stripe: pl.BlockSpec(
+        (bt, None, r, w), lambda bi, s: (bi, stripe(s), 0, 0)
+    )
+    in_stripe = lambda s: jnp.minimum(s, n - 1)  # GC reads stripe s
+    out_stripe = lambda s: jnp.clip(s - 2, 0, n - 1)  # TI writes stripe s-2
+    # the GC one-hot travels in the storage dtype (its 0/1 entries are exact
+    # in bf16); the TI one-hots meet fp32 planes and the lerp fractions stay
+    # fp32. Every constant is built at the rank the kernel uses it at.
     consts = (
         jnp.asarray(gc_col_onehot(w, gy, r)).astype(sdt),
-        jnp.asarray(np.stack([oh0, oh1])).astype(sdt),
+        jnp.asarray(np.stack([oh0, oh1])),
         jnp.asarray(yf)[None],
-        jnp.asarray((np.arange(r) / r).astype(np.float32))[None],
+        jnp.asarray((np.arange(r) / r).astype(np.float32)).reshape(1, r, 1, 1),
     )
-    const_specs = [const((w, gy)), const((2, w, gy)), const((1, w)), const((1, r))]
+    const_specs = [
+        const((w, gy)), const((2, w, gy)), const((1, w)), const((1, r, 1, 1))
+    ]
     scratch = [
         pltpu.VMEM((bt, 2, gz, gy), sdt),  # raw plane s-2
         pltpu.VMEM((bt, 2, gz, gy), sdt),  # raw plane s-1
@@ -530,6 +554,9 @@ def bg_fused_impl(
         pltpu.VMEM((bt, r, w), sdt),  # line buffer stripe s-2
         pltpu.VMEM((bt, r, w), sdt),  # line buffer stripe s-1
     ]
+    out_shape = jax.ShapeDtypeStruct((bp, n, r, w), sdt)
+    kw = dict(taps=taps, inv_rs=1.0 / cfg.range_scale, gz=gz,
+              split=gc_row_split(r), n_stripes=n)
 
     if temporal:
         if carry.shape != (b, gx, gy, gz, 2):
@@ -547,45 +574,30 @@ def bg_fused_impl(
         )
         ck = carry_p.transpose(1, 0, 4, 3, 2)  # (gx, bp, 2, gz, gy)
         ck = ck.reshape(gx, nb, bt, 2, gz, gy).swapaxes(0, 1)
-        alpha_p = jnp.pad(alpha.astype(jnp.float32), (0, bp - b)).reshape(nb, bt)
+        alpha_p = jnp.pad(alpha.astype(jnp.float32), (0, bp - b)).reshape(
+            bp, 1, 1, 1
+        )
         msk_p = jnp.pad(
             jnp.ones((b, h, w), sdt), ((0, bp - b), (0, hp - h), (0, 0))
-        )
+        ).reshape(bp, n, r, w)
         # blurred plane p completes (and its carry blend lands) at step
         # s = p + 1, so emitting all gx carry planes takes gx + 1 steps:
         # for ragged h that is the usual n + 2, for h % r == 0 it is one
         # extra drain step whose TI write is masked off in the kernel.
         plane_idx = lambda bi, s: (bi, jnp.clip(s - 1, 0, gx - 1), 0, 0, 0, 0)
         carry_spec = pl.BlockSpec((1, 1, bt, 2, gz, gy), plane_idx)
-        kern = functools.partial(
-            _kernel,
-            taps=taps,
-            inv_rs=1.0 / cfg.range_scale,
-            gz=gz,
-            split=gc_row_split(r),
-            n_stripes=n,
-            temporal=True,
-        )
         out, ck_new = pl.pallas_call(
-            kern,
+            functools.partial(_kernel, temporal=True, **kw),
             grid=(nb, gx + 1),
-            in_specs=[
-                frame_spec(lambda bi, s: (bi, jnp.minimum(s, n - 1), 0)),
-                frame_spec(lambda bi, s: (bi, jnp.minimum(s, n - 1), 0)),
-            ]
+            in_specs=[stripe_spec(in_stripe), stripe_spec(in_stripe)]
             + const_specs
             + [
                 carry_spec,
-                pl.BlockSpec(
-                    (1, bt), lambda bi, s: (bi, 0), memory_space=pltpu.SMEM
-                ),
+                pl.BlockSpec((bt, 1, 1, 1), lambda bi, s: (bi, 0, 0, 0)),
             ],
-            out_specs=[
-                frame_spec(lambda bi, s: (bi, jnp.clip(s - 2, 0, n - 1), 0)),
-                carry_spec,
-            ],
+            out_specs=[stripe_spec(out_stripe), carry_spec],
             out_shape=[
-                jax.ShapeDtypeStruct((bp, hp, w), sdt),
+                out_shape,
                 jax.ShapeDtypeStruct((nb, gx, bt, 2, gz, gy), sdt),
             ],
             scratch_shapes=scratch,
@@ -596,36 +608,33 @@ def bg_fused_impl(
             .reshape(gx, bp, 2, gz, gy)
             .transpose(1, 0, 4, 3, 2)[:b]
         )
-        out = out[:b, :h]
+        out = out.reshape(bp, hp, w)[:b, :h]
         if squeeze:
             return out[0], new_carry[0]
         return out, new_carry
 
     if stream_input:
-        # (bp, hp, w) -> (nb, n, bt, r, w): tile/stripe major so one DMA
-        # descriptor (.at[tile, stripe]) names a whole (bt, r, w) block.
-        img_t = img_p.reshape(nb, bt, n, r, w).swapaxes(1, 2)
-        kern = functools.partial(
-            _stream_kernel,
-            taps=taps,
-            inv_rs=1.0 / cfg.range_scale,
-            gz=gz,
-            split=gc_row_split(r),
-            n_stripes=n,
-            bt=bt,
-            r=r,
-            b=b,
-            h=h,
+        # (bp, n, r, w) -> (nb, n, bt, rp, w): tile/stripe major, so one DMA
+        # descriptor (.at[tile, stripe]) names a whole (bt, rp, w) block.
+        # Stripes are zero-padded to rp rows, a whole number of the storage
+        # dtype's sublane tiles: Mosaic refuses a DMA window over a tiled
+        # array whose second-minor dim is not tile-aligned, and HBM holds
+        # the padded rows physically either way.
+        tile_rows = 32 // jnp.dtype(sdt).itemsize  # 8 fp32, 16 bf16
+        rp = -(-r // tile_rows) * tile_rows
+        img_t = jnp.pad(
+            img_p.reshape(nb, bt, n, r, w).swapaxes(1, 2),
+            ((0, 0),) * 3 + ((0, rp - r), (0, 0)),
         )
         out = pl.pallas_call(
-            kern,
+            functools.partial(_stream_kernel, bt=bt, r=r, b=b, h=h, **kw),
             grid=(nb, n + 2),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] + const_specs,
-            out_specs=frame_spec(lambda bi, s: (bi, jnp.maximum(s - 2, 0), 0)),
-            out_shape=jax.ShapeDtypeStruct((bp, hp, w), sdt),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + const_specs,
+            out_specs=stripe_spec(out_stripe),
+            out_shape=out_shape,
             scratch_shapes=scratch
             + [
-                pltpu.VMEM((2, bt, r, w), sdt),  # DMA stripe slots
+                pltpu.VMEM((2, bt, rp, w), sdt),  # DMA stripe slots
                 pltpu.SemaphoreType.DMA((2,)),  # per-slot completion
             ],
             interpret=interpret,
@@ -633,29 +642,18 @@ def bg_fused_impl(
     else:
         msk_p = jnp.pad(
             jnp.ones((b, h, w), sdt), ((0, bp - b), (0, hp - h), (0, 0))
-        )
-        kern = functools.partial(
-            _kernel,
-            taps=taps,
-            inv_rs=1.0 / cfg.range_scale,
-            gz=gz,
-            split=gc_row_split(r),
-            n_stripes=n,
-        )
+        ).reshape(bp, n, r, w)
         out = pl.pallas_call(
-            kern,
+            functools.partial(_kernel, **kw),
             grid=(nb, n + 2),
-            in_specs=[
-                frame_spec(lambda bi, s: (bi, jnp.minimum(s, n - 1), 0)),
-                frame_spec(lambda bi, s: (bi, jnp.minimum(s, n - 1), 0)),
-            ]
+            in_specs=[stripe_spec(in_stripe), stripe_spec(in_stripe)]
             + const_specs,
-            out_specs=frame_spec(lambda bi, s: (bi, jnp.maximum(s - 2, 0), 0)),
-            out_shape=jax.ShapeDtypeStruct((bp, hp, w), sdt),
+            out_specs=stripe_spec(out_stripe),
+            out_shape=out_shape,
             scratch_shapes=scratch,
             interpret=interpret,
         )(img_p, msk_p, *consts)
-    out = out[:b, :h]
+    out = out.reshape(bp, hp, w)[:b, :h]
     return out[0] if squeeze else out
 
 

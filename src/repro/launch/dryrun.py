@@ -22,7 +22,6 @@ from repro.models import (  # noqa: E402
     model_flops_per_token,
     param_logical_axes,
 )
-from repro.sharding.compat import set_mesh  # noqa: E402
 from repro.sharding.partitioning import (  # noqa: E402
     DEFAULT_RULES,
     axis_rules,
@@ -120,7 +119,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, rules=None) -> dict:
     b_axes = batch_logical_axes(cfg, shape)
     b_sh = _shard_tree(b_axes, mesh, rules, batch)
 
-    with axis_rules(rules), set_mesh(mesh):
+    with axis_rules(rules), jax.set_mesh(mesh):
         if shape.kind == "train":
             from repro.train.optimizer import adamw_init
 

@@ -5,26 +5,52 @@ cost_analysis, so we parse the post-SPMD optimized HLO text and sum the
 shaped operands of every all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute (async -start variants counted once).
 
-Hardware model (TPU v5e, per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware model: per-chip peaks keyed by ``device_kind`` (``chip_peaks``).
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict
+from typing import Dict, Optional
 
 __all__ = [
-    "HW",
+    "PEAKS",
+    "TARGET_KIND",
+    "chip_peaks",
     "collective_stats",
     "roofline_terms",
     "Roofline",
 ]
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s / chip
-ICI_BW = 50e9  # B/s / link
-HW = {"peak_flops": PEAK_FLOPS, "hbm_bw": HBM_BW, "ici_bw": ICI_BW}
+# Per-chip peaks keyed by jax's ``device_kind``. Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of ICI over four links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# The chip this repo targets: a host with no TPU attached (CPU tests, the
+# described-topology compiles) models this one.
+TARGET_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: Optional[str] = None) -> dict:
+    """Peaks of ``device_kind``; by default the first local device's kind on
+    a TPU host, and ``TARGET_KIND`` on a host without one. A kind that is not
+    in ``PEAKS`` raises ``KeyError``: an unknown chip never silently gets
+    another chip's numbers."""
+    if device_kind is None:
+        import jax
+
+        dev = jax.devices()[0]
+        device_kind = dev.device_kind if dev.platform == "tpu" else TARGET_KIND
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak numbers for device kind {device_kind!r}; add its "
+            f"published peaks to repro.launch.hlo_analysis.PEAKS"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -118,12 +144,13 @@ def roofline_terms(cost: dict, hlo_text: str) -> Roofline:
     from .hlo_cost import analyze_hlo
 
     hc = analyze_hlo(hlo_text)
+    peaks = chip_peaks()
     return Roofline(
         flops_per_chip=hc.flops,
         hbm_bytes_per_chip=hc.hbm_bytes,
         collective_bytes_per_chip=hc.collective_bytes,
         collective_breakdown=hc.collectives,
-        compute_s=hc.flops / PEAK_FLOPS,
-        memory_s=hc.hbm_bytes / HBM_BW,
-        collective_s=hc.collective_bytes / ICI_BW,
+        compute_s=hc.flops / peaks["peak_flops"],
+        memory_s=hc.hbm_bytes / peaks["hbm_bw"],
+        collective_s=hc.collective_bytes / peaks["ici_bw"],
     )

@@ -332,6 +332,9 @@ def main():
     )
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.video:
         if args.workers:
             serve_fleet(args)

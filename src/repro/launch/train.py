@@ -42,7 +42,6 @@ def main():
     from repro.launch.dryrun import _shard_tree  # shared sharding helper
     from repro.models import param_logical_axes
     from repro.sharding.partitioning import DEFAULT_RULES, axis_rules
-    from repro.sharding.compat import set_mesh
     from repro.train import OptConfig, Trainer
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
@@ -56,7 +55,7 @@ def main():
         dims = tuple(int(x) for x in args.mesh.split("x"))
         axes = ("data", "model")[: len(dims)]
         mesh = jax.make_mesh(dims, axes)
-        ctx = (axis_rules(DEFAULT_RULES), set_mesh(mesh))
+        ctx = (axis_rules(DEFAULT_RULES), jax.set_mesh(mesh))
         for c in ctx:
             c.__enter__()
         p_sh = _shard_tree(

@@ -103,8 +103,8 @@ The roofline latency model (how ``plan_for`` ranks candidates)
 --------------------------------------------------------------
 The VMEM budget above decides which plans are *legal*; :func:`plan_cost`
 predicts which legal plan is *fastest*. Per candidate it charges, against
-the per-chip peaks in ``repro.launch.hlo_analysis`` (``PEAK_FLOPS``,
-``HBM_BW``):
+the per-chip peaks of ``repro.launch.hlo_analysis.chip_peaks`` (keyed by
+``device_kind``; a host without a TPU ranks for the v5e target):
 
   compute_s   FLOPs of the GC/GF/TI contractions per stripe step, summed
               over the padded dispatch (``ceil(b_dev/bt) * (ceil(h/r)+2)``
@@ -119,7 +119,7 @@ the per-chip peaks in ``repro.launch.hlo_analysis`` (``PEAK_FLOPS``,
               per grid step (why bigger tiles win: fewer steps) +
               ``STREAM_DMA_OVERHEAD_S`` per frame-step on the manual-DMA
               path (why tiny frames don't stream: the saved mask bytes,
-              ``4*r*w / HBM_BW``, must outweigh the DMA issue cost — the
+              ``4*r*w / hbm_bw``, must outweigh the DMA issue cost — the
               break-even sits at ``r*w ~ 16k``, reproducing the PR-5
               256 KiB ``auto_stream_input`` rule as a *derived* quantity).
 
@@ -221,7 +221,7 @@ MAX_AUTO_TILE = 64
 # Latency-model overhead constants (module docstring, "roofline latency
 # model"). Structural, not per-host-calibrated: they set the *break-even
 # points* of the ranking — STREAM_DMA_OVERHEAD_S puts the stream-vs-default
-# crossover at r*w ~ 16k (4*r*w/HBM_BW saved bytes vs the DMA issue cost,
+# crossover at r*w ~ 16k (4*r*w/hbm_bw saved bytes vs the DMA issue cost,
 # matching the PR-5 256 KiB rule), STEP_OVERHEAD_S makes fewer-bigger tiles
 # win whenever VMEM allows. Measured truth belongs in the plan cache.
 DISPATCH_OVERHEAD_S = 30e-6
@@ -304,7 +304,7 @@ def plan_cost_breakdown(plan: "BGPlan", h: int, w: int,
     ``max(compute, memory)`` roofline bound), and ``total_s`` (the
     no-overlap sum that ranks candidates).
     """
-    from repro.launch.hlo_analysis import HBM_BW, PEAK_FLOPS
+    from repro.launch.hlo_analysis import chip_peaks
 
     cfg = plan.cfg
     r = cfg.r
@@ -355,8 +355,9 @@ def plan_cost_breakdown(plan: "BGPlan", h: int, w: int,
         if plan.temporal:
             hbm += 2 * 4 * b_dev * grid_elems
 
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
+    peaks = chip_peaks()
+    compute_s = flops / peaks["peak_flops"]
+    memory_s = hbm / peaks["hbm_bw"]
     return {
         "flops": float(flops),
         "hbm_bytes": float(hbm),
@@ -425,7 +426,10 @@ class BGPlan:
                        normalize to ``None``.
       quantize_output: apply the paper's output rounding at the exit.
       interpret:       Pallas interpret-mode override (``None`` = auto:
-                       interpret everywhere except real TPUs).
+                       interpret everywhere except real TPUs). On a TPU
+                       backend only a caller's own ``interpret=True`` runs
+                       the kernels interpreted; a deserialized plan that
+                       carries it is refused (:meth:`from_json`).
       precision:       storage dtype contract — ``"fp32"`` (default) or
                        ``"bf16"`` (bf16 storage / fp32 accumulate; see the
                        module docstring). Only ``reference`` / ``fused`` /
@@ -605,6 +609,14 @@ class BGPlan:
         if int(data.get("version", 1)) != 1:
             raise ValueError(
                 f"unknown BGPlan serialization version {data.get('version')!r}"
+            )
+        if data.get("interpret") and jax.default_backend() == "tpu":
+            # interpret mode is a host-local choice: a plan shipped from a
+            # CPU host must not silently run its kernels interpreted here
+            raise ValueError(
+                "serialized plan pins Pallas interpret mode, but this host "
+                "compiles its kernels for the TPU; resolve the plan with "
+                "interpret=None, or construct it here with interpret=True"
             )
         if mesh == "auto":
             ms = int(data.get("mesh_size", 1))
@@ -998,22 +1010,21 @@ def warn_legacy_dispatch(site: str) -> None:
 def _mesh_call(inner, mesh, n_in: int, n_out: int):
     """The shared mesh composition: zero-pad every input's leading axis to a
     device multiple, shard_map ``inner`` with plain batch-axis specs
-    (``check_rep=False`` — pallas_call has no replication rule), and trim
+    (``check_vma=False`` — pallas_call has no varying-axes rule), and trim
     every output back to the original leading size. Returns
     ``fn(*arrays) -> output`` (tuple for ``n_out > 1``)."""
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.bg_shard import _pad_rows, _row_pad
-    from repro.sharding.compat import shard_map
 
     nd = int(mesh.devices.size)
     spec = P(mesh.axis_names[0])
-    sharded = shard_map(
+    sharded = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=spec if n_in == 1 else (spec,) * n_in,
         out_specs=spec if n_out == 1 else (spec,) * n_out,
-        check_rep=False,
+        check_vma=False,
     )
 
     def call(*arrays):

@@ -21,7 +21,7 @@ across frames, therefore:
     dropped after — so the sharded output is bit-identical to the
     single-device ``bg_fused_kernel_call`` on the same batch.
 
-``check_rep=False`` is required because ``pallas_call`` has no replication
+``check_vma=False`` is required because ``pallas_call`` has no varying-axes
 rule; it is safe here since no out spec claims replication.
 
 The pad -> shard_map -> trim -> quantize composition itself now lives in the
@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.bilateral_grid import BGConfig
-
-from .compat import shard_map
 
 __all__ = [
     "BATCH_AXIS",
@@ -58,7 +56,13 @@ def batch_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
     n = len(devices) if n_devices is None else n_devices
     if not 1 <= n <= len(devices):
         raise ValueError(f"n_devices={n} not in [1, {len(devices)}]")
-    return jax.make_mesh((n,), (BATCH_AXIS,), devices=devices[:n])
+    # Auto axes: the plan pads, shards and trims the batch axis itself, and
+    # an Explicit axis (make_mesh's default) refuses the trim to a length
+    # that is not a device multiple
+    return jax.make_mesh(
+        (n,), (BATCH_AXIS,), devices=devices[:n],
+        axis_types=(jax.sharding.AxisType.Auto,),
+    )
 
 
 def _service_mesh(mesh: jax.sharding.Mesh | None) -> jax.sharding.Mesh | None:
@@ -97,8 +101,8 @@ def shard_batch_call(fn, images: jnp.ndarray, mesh: jax.sharding.Mesh) -> jnp.nd
     axis = mesh.axis_names[0]
     b = images.shape[0]
     padded = _pad_rows(images, _row_pad(int(mesh.devices.size), b))
-    sharded = shard_map(
-        fn, mesh=mesh, in_specs=P(axis), out_specs=P(axis), check_rep=False
+    sharded = jax.shard_map(
+        fn, mesh=mesh, in_specs=P(axis), out_specs=P(axis), check_vma=False
     )
     return sharded(padded)[:b]
 

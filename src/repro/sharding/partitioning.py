@@ -81,9 +81,7 @@ def _mesh_axes_in_use() -> set:
 
 
 def get_abstract_mesh() -> Optional[Mesh]:
-    from .compat import get_active_mesh
-
-    m = get_active_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or m.empty:
         return None
     return m

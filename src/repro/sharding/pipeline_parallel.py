@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map
 
 __all__ = ["gpipe"]
 
@@ -47,12 +46,7 @@ def gpipe(
         idx = jax.lax.axis_index(axis)
         total = n_micro + n_stages - 1
         # initial carries must be marked varying over the pipe axis (vma typing)
-        if hasattr(jax.lax, "pcast"):
-            mark = lambda t: jax.lax.pcast(t, (axis,), to="varying")
-        elif hasattr(jax.lax, "pvary"):  # older spelling
-            mark = lambda t: jax.lax.pvary(t, (axis,))
-        else:  # jax <= 0.4.x: no vma typing, replicated carries are fine
-            mark = lambda t: t
+        mark = lambda t: jax.lax.pcast(t, (axis,), to="varying")
         buf = mark(jnp.zeros_like(xs[0]))
         outs = mark(jnp.zeros_like(xs))
 
@@ -80,7 +74,7 @@ def gpipe(
         return outs
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec_params, P()),

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import AttnSpec
-from repro.sharding.compat import shard_map
 from repro.models.attention import NEG_INF, _mask_logits
 
 __all__ = ["ring_attention"]
@@ -77,7 +76,7 @@ def ring_attention(
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return jnp.moveaxis(out, 3, 1).reshape(B, Sl, H, D).astype(q.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.compat import shard_map
 
 __all__ = ["compressed_mean_grads", "quantize_dequantize_roundtrip"]
 
@@ -29,12 +28,7 @@ def _psum_int8(g, axes: Sequence[str]):
     total = jax.lax.psum(q, axes)
     n = 1
     for a in axes:
-        # jax.lax.axis_size is a post-0.4 addition; psum(1) is the classic
-        # spelling of "size of this mapped axis" and works everywhere.
-        if hasattr(jax.lax, "axis_size"):
-            n *= jax.lax.axis_size(a)
-        else:
-            n *= jax.lax.psum(1, a)
+        n *= jax.lax.axis_size(a)
     return (total.astype(jnp.float32) * scale) / n
 
 
@@ -49,7 +43,7 @@ def compressed_mean_grads(grads, mesh, dp_axes=("pod", "data")):
         return grads
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(*axes),
         out_specs=P(*axes),

@@ -37,7 +37,6 @@ def test_sharded_train_step_runs_and_matches_single_device():
         from repro.launch.dryrun import _shard_tree
         from repro.models import init_params, param_logical_axes
         from repro.sharding.partitioning import DEFAULT_RULES, axis_rules
-        from repro.sharding.compat import set_mesh
         from repro.train import OptConfig, make_train_step
         from repro.train.train_step import init_train_state
         from repro.data import lm_batches
@@ -52,10 +51,10 @@ def test_sharded_train_step_runs_and_matches_single_device():
         # single-device reference
         p_ref, _, m_ref = jax.jit(make_train_step(cfg, opt))(params, state, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         p_sh = _shard_tree(param_logical_axes(cfg), mesh, DEFAULT_RULES,
                            jax.eval_shape(lambda: params))
-        with axis_rules(DEFAULT_RULES), set_mesh(mesh):
+        with axis_rules(DEFAULT_RULES), jax.set_mesh(mesh):
             params_d = jax.tree.map(lambda x, s: jax.device_put(x, s), params, p_sh)
             state_d = {"m": jax.tree.map(lambda x, s: jax.device_put(x, s), state["m"], p_sh),
                        "v": jax.tree.map(lambda x, s: jax.device_put(x, s), state["v"], p_sh),
@@ -80,7 +79,7 @@ def test_gpipe_matches_sequential():
         from repro.sharding.pipeline_parallel import gpipe
 
         P_STAGES, N_MICRO, MB, D = 4, 8, 2, 16
-        mesh = jax.make_mesh((P_STAGES,), ("pipe",))
+        mesh = jax.make_mesh((P_STAGES,), ("pipe",), axis_types=(jax.sharding.AxisType.Auto,))
         ks = jax.random.split(jax.random.PRNGKey(0), P_STAGES)
         ws = jnp.stack([jax.random.normal(k, (D, D)) / jnp.sqrt(D) for k in ks])
         xs = jax.random.normal(jax.random.PRNGKey(1), (N_MICRO, MB, D))
@@ -104,7 +103,7 @@ def test_compressed_grad_allreduce_close_to_exact():
         import jax, jax.numpy as jnp
         from repro.train.grad_compress import compressed_mean_grads
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32))
 
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -130,12 +129,11 @@ def test_mini_dryrun_cell_with_roofline():
         from repro.launch.hlo_analysis import roofline_terms
         from repro.models import init_params, param_logical_axes
         from repro.sharding.partitioning import DEFAULT_RULES, axis_rules
-        from repro.sharding.compat import set_mesh
         from repro.train import OptConfig, make_train_step
         from repro.train.optimizer import adamw_init
 
         cfg = dataclasses.replace(get_smoke_config("gemma2-9b"), grad_accum=2)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         pshape = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
         p_sh = _shard_tree(param_logical_axes(cfg), mesh, DEFAULT_RULES, pshape)
         oshape = jax.eval_shape(adamw_init, pshape)
@@ -144,7 +142,7 @@ def test_mini_dryrun_cell_with_roofline():
             "tokens": jax.ShapeDtypeStruct((8, 32), jax.numpy.int32),
             "labels": jax.ShapeDtypeStruct((8, 32), jax.numpy.int32),
         }
-        with axis_rules(DEFAULT_RULES), set_mesh(mesh):
+        with axis_rules(DEFAULT_RULES), jax.set_mesh(mesh):
             lowered = jax.jit(make_train_step(cfg, OptConfig()),
                               in_shardings=(p_sh, o_sh, None)).lower(pshape, oshape, batch)
             compiled = lowered.compile()
@@ -155,10 +153,7 @@ def test_mini_dryrun_cell_with_roofline():
         assert roof.hbm_bytes_per_chip > 0
         # accum scan x layer scan must be trip-count multiplied: raw cost
         # analysis undercounts vs the structural model
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax<=0.4.x: list per device
-            ca = ca[0] if ca else {}
-        raw = ca.get("flops", 0.0)
+        raw = compiled.cost_analysis().get("flops", 0.0)
         assert roof.flops_per_chip > 1.5 * raw, (roof.flops_per_chip, raw)
         print("OK dryrun", roof.dominant)
         """
@@ -182,7 +177,7 @@ def test_elastic_restore_across_meshes():
             mgr = CheckpointManager(d, async_save=False)
             mgr.save(3, {"params": params, "opt": opt}, {"step": 3})
             # restore onto a DIFFERENT topology (4x2 vs training's 1 device)
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
             p2, o2, meta = elastic_restore(mgr, cfg, mesh)
             assert meta["step"] == 3
             ok = jax.tree.map(lambda a, b: bool(jnp.allclose(a, jnp.asarray(b))), params, p2)
@@ -203,7 +198,7 @@ def test_ring_attention_matches_plain():
         from repro.models.attention import _sdpa_plain
         from repro.sharding.ring_attention import ring_attention
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         B, S, H, KV, D = 2, 64, 4, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)

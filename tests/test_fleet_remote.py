@@ -125,6 +125,16 @@ def test_wid_and_sid_must_be_json_plain(payload):
         sub.close(timeout=10)
 
 
+def test_refused_on_a_tpu_backend(payload, monkeypatch):
+    """On a TPU host this process holds every chip, so a child engine could
+    not load the TPU library: construction refuses before spawning."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="TPU host"):
+        _sub(payload)
+
+
 def test_tampered_payload_rejected_at_construction(payload):
     """A payload whose plan_hash does not match the plan it carries must
     fail the constructor with the child's structured PlanMismatch — not
@@ -220,12 +230,14 @@ def test_dropped_submit_fails_by_sweep_never_hangs(payload):
     never a hang."""
     inj = FaultInjector([Fault(kind="drop_message", message="submit",
                                times=1)])
-    sub = _sub(payload, submit_timeout_s=2.0)
+    sub = _sub(payload)
     try:
         sub.open_stream(0, alpha=ALPHA)
-        # warm clean first (the compile frame must not race the sweep's
-        # short timeout), then install the injector for the faulted phase
+        # warm clean first under the default sweep timeout (the compile
+        # frame must not race a short one), then shorten the timeout and
+        # install the injector for the faulted phase
         np.asarray(sub.submit(_frame(0), stream_id=0).result(timeout=120))
+        sub.submit_timeout_s = 2.0
         sub.fault_injector = inj
         fut = sub.submit(_frame(1), stream_id=0)  # this one is dropped
         with pytest.raises(WorkerDown, match="unresolved"):

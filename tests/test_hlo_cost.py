@@ -98,14 +98,13 @@ def test_collectives_counted_with_ring_model():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_cost import analyze_hlo
-        from repro.sharding.compat import set_mesh
 
-        mesh = jax.make_mesh((8,), ("x",))
+        mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
         sh = NamedSharding(mesh, P("x", None))
         rep = NamedSharding(mesh, P())
         def f(a):
             return jnp.sum(a * 2.0)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             txt = jax.jit(f, in_shardings=(sh,), out_shardings=rep).lower(
                 jax.ShapeDtypeStruct((64, 32), jnp.float32)).compile().as_text()
         hc = analyze_hlo(txt)
@@ -149,3 +148,18 @@ def test_roofline_report_renders():
     t2 = render_roofline_table(cells)
     assert "SKIP" in t1 and "all-gather×3" in t1
     assert "**memory**" in t2 and "50.0%" in t2
+
+
+def test_peaks_keyed_by_device_kind():
+    """The v5e peaks answer to v5e's device_kind; a host with no TPU models
+    the target chip; an unknown TPU kind raises instead of borrowing v5e's
+    numbers."""
+    from repro.launch.hlo_analysis import PEAKS, TARGET_KIND, chip_peaks
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert v5e["peak_flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    assert TARGET_KIND in PEAKS
+    assert chip_peaks() == PEAKS[TARGET_KIND]  # this host has no TPU
+    with pytest.raises(KeyError, match="TPU v9"):
+        chip_peaks("TPU v9")
+

@@ -590,6 +590,19 @@ def test_plan_serialization_round_trip():
         BGPlan.from_json({**d, "version": 99})
 
 
+def test_serialized_interpret_pin_refused_on_tpu(monkeypatch):
+    """A plan shipped from a CPU host (interpret=True) must not run its
+    kernels interpreted on a TPU host; interpret=None and a local pin are
+    fine."""
+    d = BGPlan(cfg=CFG, backend="fused", interpret=True).to_json()
+    assert BGPlan.from_json(d).interpret is True  # off-TPU: honoured
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret"):
+        BGPlan.from_json(d)
+    assert BGPlan.from_json({**d, "interpret": None}).interpret is None
+    assert BGPlan(cfg=CFG, backend="fused", interpret=True).interpret is True
+
+
 # --------------------------------------------------------- mixed precision
 def test_precision_validation_and_serialization():
     from repro.plan import PRECISIONS, precision_bytes

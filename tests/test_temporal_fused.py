@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from repro.core import BGConfig, add_gaussian_noise
+from repro.core.bilateral_grid import quantize_intensity
 from repro.data import synthetic_video
 from repro.kernels import bg_fused
 from repro.video import MultiStreamPacker, blurred_grid_batch, carry_shape, temporal_denoise
@@ -175,11 +176,12 @@ def test_mixed_pack_is_single_dispatch(monkeypatch):
 
 
 def test_kernel_level_carry_isolation():
-    """Row i of a temporal pack == the same stream dispatched alone: the
-    image output is per-stream *bitwise* (batch composition can never touch
-    a stream's pixels); the carry matches to <= 1 ulp — LLVM picks FMA lanes
-    for the in-kernel blend per dispatch geometry, so only same-geometry
-    dispatches are bit-reproducible (see the blend comment in bg_fused)."""
+    """Row i of a temporal pack == the same stream dispatched alone. The
+    blend may be contracted into an FMA differently per dispatch geometry
+    (see the blend comment in bg_fused), so across geometries the quantized
+    image is per-stream *bitwise* (batch composition never moves a served
+    pixel), while the unquantized image and the carry agree to a few ulp.
+    Identical geometry gives identical bits."""
     n, h, w = 3, 45, 55
     frames = _noisy_stack(n, h, w, seed=9)
     rng = np.random.default_rng(0)
@@ -199,9 +201,15 @@ def test_kernel_level_carry_isolation():
             carry=carry[i : i + 1],
             alpha=alpha[i : i + 1],
         )
-        np.testing.assert_array_equal(np.asarray(out[i]), np.asarray(oi[0]))
-        np.testing.assert_allclose(
-            np.asarray(new_carry[i]), np.asarray(ci[0]), atol=2e-3, rtol=1e-6
+        np.testing.assert_array_equal(
+            np.asarray(quantize_intensity(out[i], CFG)),
+            np.asarray(quantize_intensity(oi[0], CFG)),
+        )
+        np.testing.assert_array_max_ulp(
+            np.asarray(out[i]), np.asarray(oi[0]), maxulp=4
+        )
+        np.testing.assert_array_max_ulp(
+            np.asarray(new_carry[i]), np.asarray(ci[0]), maxulp=4
         )
     # identical geometry => identical bits (the reproducibility contract)
     out2, new_carry2 = bg_fused(
@@ -268,11 +276,11 @@ def run_sub(body: str, devices: int = 8, timeout: int = 420) -> str:
 
 def test_temporal_sharded_identical_1_vs_8_devices():
     """The temporal call shards the stream axis over the ("batch",) mesh with
-    zero collectives: the 8-device *image output* is bit-identical to the
-    single-device call for ragged stream counts (n % nd != 0, n < nd); the
-    carries agree to <= 1 ulp (per-shard loop shapes pick different FMA
-    lanes in the blend — see bg_fused) and bit-exactly when the per-shard
-    geometry matches the single-device tiling."""
+    zero collectives: for ragged stream counts (n % nd != 0, n < nd) the
+    8-device *quantized image* is bit-identical to the single-device call,
+    while the unquantized image and the carries agree to a few ulp
+    (per-shard loop shapes may contract the blend into FMAs differently —
+    see bg_fused)."""
     run_sub(
         """
         import jax, numpy as np, jax.numpy as jnp
@@ -296,9 +304,17 @@ def test_temporal_sharded_identical_1_vs_8_devices():
                 frames, carry, alpha, cfg, mesh=batch_mesh(1), interpret=True)
             out, new_c = bg_temporal_sharded(
                 frames, carry, alpha, cfg, mesh=batch_mesh(nd), interpret=True)
-            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_o))
-            np.testing.assert_allclose(
-                np.asarray(new_c), np.asarray(ref_c), atol=2e-3, rtol=1e-6)
+            q_ref, _ = bg_temporal_sharded(
+                frames, carry, alpha, cfg, mesh=batch_mesh(1), interpret=True,
+                quantize_output=True)
+            q_out, _ = bg_temporal_sharded(
+                frames, carry, alpha, cfg, mesh=batch_mesh(nd), interpret=True,
+                quantize_output=True)
+            np.testing.assert_array_equal(np.asarray(q_out), np.asarray(q_ref))
+            np.testing.assert_array_max_ulp(
+                np.asarray(out), np.asarray(ref_o), maxulp=4)
+            np.testing.assert_array_max_ulp(
+                np.asarray(new_c), np.asarray(ref_c), maxulp=4)
             print(f"OK n={n} nd={nd}")
 
         # the packer auto-meshes over all 8 devices; a mixed pack must stay
