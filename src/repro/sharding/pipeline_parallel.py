@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-
 __all__ = ["gpipe"]
 
 

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-
 __all__ = ["compressed_mean_grads", "quantize_dequantize_roundtrip"]
 
 

@@ -162,4 +162,3 @@ def test_peaks_keyed_by_device_kind():
     assert chip_peaks() == PEAKS[TARGET_KIND]  # this host has no TPU
     with pytest.raises(KeyError, match="TPU v9"):
         chip_peaks("TPU v9")
-
