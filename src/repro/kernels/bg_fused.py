@@ -522,9 +522,13 @@ def bg_fused_impl(
     # (bp, hp, w) -> (bp, n, r, w): every block is then (bt, <stripe>, r, w)
     # with the stripe axis squeezed, so the last two block dims are whole
     # array dims — what Mosaic requires for any r, not only multiples of 8.
-    img_p = jnp.pad(
-        image.astype(jnp.float32), ((0, bp - b), (0, hp - h), (0, 0))
-    ).astype(sdt).reshape(bp, n, r, w)
+    # named stages for a profiler trace: bg.relayout before the kernel,
+    # bg.kernel, bg.trim after it (the module docstring's layout notes)
+    relayout = functools.partial(jax.named_scope, "bg.relayout")
+    with relayout():
+        img_p = jnp.pad(
+            image.astype(jnp.float32), ((0, bp - b), (0, hp - h), (0, 0))
+        ).astype(sdt).reshape(bp, n, r, w)
 
     oh0, oh1, yf = ti_col_onehots(w, gy, r)
     taps = tuple(float(t) for t in taps_np(cfg))
@@ -537,12 +541,13 @@ def bg_fused_impl(
     # the GC one-hot travels in the storage dtype (its 0/1 entries are exact
     # in bf16); the TI one-hots meet fp32 planes and the lerp fractions stay
     # fp32. Every constant is built at the rank the kernel uses it at.
-    consts = (
-        jnp.asarray(gc_col_onehot(w, gy, r)).astype(sdt),
-        jnp.asarray(np.stack([oh0, oh1])),
-        jnp.asarray(yf)[None],
-        jnp.asarray((np.arange(r) / r).astype(np.float32)).reshape(1, r, 1, 1),
-    )
+    with relayout():
+        consts = (
+            jnp.asarray(gc_col_onehot(w, gy, r)).astype(sdt),
+            jnp.asarray(np.stack([oh0, oh1])),
+            jnp.asarray(yf)[None],
+            jnp.asarray((np.arange(r) / r).astype(np.float32)).reshape(1, r, 1, 1),
+        )
     const_specs = [
         const((w, gy)), const((2, w, gy)), const((1, w)), const((1, r, 1, 1))
     ]
@@ -569,49 +574,53 @@ def bg_fused_impl(
         # (b, gx, gy, gz, 2) -> (nb, gx, bt, 2, gz, gy): plane-major with the
         # kernel's scratch layout minor, so one block index names the whole
         # (bt, 2, gz, gy) plane the EMA touches at step s.
-        carry_p = jnp.pad(
-            carry.astype(sdt), ((0, bp - b),) + ((0, 0),) * 4
-        )
-        ck = carry_p.transpose(1, 0, 4, 3, 2)  # (gx, bp, 2, gz, gy)
-        ck = ck.reshape(gx, nb, bt, 2, gz, gy).swapaxes(0, 1)
-        alpha_p = jnp.pad(alpha.astype(jnp.float32), (0, bp - b)).reshape(
-            bp, 1, 1, 1
-        )
-        msk_p = jnp.pad(
-            jnp.ones((b, h, w), sdt), ((0, bp - b), (0, hp - h), (0, 0))
-        ).reshape(bp, n, r, w)
+        with relayout():
+            carry_p = jnp.pad(
+                carry.astype(sdt), ((0, bp - b),) + ((0, 0),) * 4
+            )
+            ck = carry_p.transpose(1, 0, 4, 3, 2)  # (gx, bp, 2, gz, gy)
+            ck = ck.reshape(gx, nb, bt, 2, gz, gy).swapaxes(0, 1)
+            alpha_p = jnp.pad(alpha.astype(jnp.float32), (0, bp - b)).reshape(
+                bp, 1, 1, 1
+            )
+            msk_p = jnp.pad(
+                jnp.ones((b, h, w), sdt), ((0, bp - b), (0, hp - h), (0, 0))
+            ).reshape(bp, n, r, w)
         # blurred plane p completes (and its carry blend lands) at step
         # s = p + 1, so emitting all gx carry planes takes gx + 1 steps:
         # for ragged h that is the usual n + 2, for h % r == 0 it is one
         # extra drain step whose TI write is masked off in the kernel.
         plane_idx = lambda bi, s: (bi, jnp.clip(s - 1, 0, gx - 1), 0, 0, 0, 0)
         carry_spec = pl.BlockSpec((1, 1, bt, 2, gz, gy), plane_idx)
-        out, ck_new = pl.pallas_call(
-            functools.partial(_kernel, temporal=True, **kw),
-            grid=(nb, gx + 1),
-            in_specs=[stripe_spec(in_stripe), stripe_spec(in_stripe)]
-            + const_specs
-            + [
-                carry_spec,
-                pl.BlockSpec((bt, 1, 1, 1), lambda bi, s: (bi, 0, 0, 0)),
-            ],
-            out_specs=[stripe_spec(out_stripe), carry_spec],
-            out_shape=[
-                out_shape,
-                jax.ShapeDtypeStruct((nb, gx, bt, 2, gz, gy), sdt),
-            ],
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(img_p, msk_p, *consts, ck, alpha_p)
-        new_carry = (
-            ck_new.swapaxes(0, 1)
-            .reshape(gx, bp, 2, gz, gy)
-            .transpose(1, 0, 4, 3, 2)[:b]
-        )
-        out = out.reshape(bp, hp, w)[:b, :h]
-        if squeeze:
-            return out[0], new_carry[0]
-        return out, new_carry
+        with jax.named_scope("bg.kernel"):
+            out, ck_new = pl.pallas_call(
+                functools.partial(_kernel, temporal=True, **kw),
+                grid=(nb, gx + 1),
+                in_specs=[stripe_spec(in_stripe), stripe_spec(in_stripe)]
+                + const_specs
+                + [
+                    carry_spec,
+                    pl.BlockSpec((bt, 1, 1, 1), lambda bi, s: (bi, 0, 0, 0)),
+                ],
+                out_specs=[stripe_spec(out_stripe), carry_spec],
+                out_shape=[
+                    out_shape,
+                    jax.ShapeDtypeStruct((nb, gx, bt, 2, gz, gy), sdt),
+                ],
+                scratch_shapes=scratch,
+                interpret=interpret,
+                name="bg_fused_temporal",
+            )(img_p, msk_p, *consts, ck, alpha_p)
+        with jax.named_scope("bg.trim"):
+            new_carry = (
+                ck_new.swapaxes(0, 1)
+                .reshape(gx, bp, 2, gz, gy)
+                .transpose(1, 0, 4, 3, 2)[:b]
+            )
+            out = out.reshape(bp, hp, w)[:b, :h]
+            if squeeze:
+                return out[0], new_carry[0]
+            return out, new_carry
 
     if stream_input:
         # (bp, n, r, w) -> (nb, n, bt, rp, w): tile/stripe major, so one DMA
@@ -622,39 +631,46 @@ def bg_fused_impl(
         # the padded rows physically either way.
         tile_rows = 32 // jnp.dtype(sdt).itemsize  # 8 fp32, 16 bf16
         rp = -(-r // tile_rows) * tile_rows
-        img_t = jnp.pad(
-            img_p.reshape(nb, bt, n, r, w).swapaxes(1, 2),
-            ((0, 0),) * 3 + ((0, rp - r), (0, 0)),
-        )
-        out = pl.pallas_call(
-            functools.partial(_stream_kernel, bt=bt, r=r, b=b, h=h, **kw),
-            grid=(nb, n + 2),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + const_specs,
-            out_specs=stripe_spec(out_stripe),
-            out_shape=out_shape,
-            scratch_shapes=scratch
-            + [
-                pltpu.VMEM((2, bt, rp, w), sdt),  # DMA stripe slots
-                pltpu.SemaphoreType.DMA((2,)),  # per-slot completion
-            ],
-            interpret=interpret,
-        )(img_t, *consts)
+        with relayout():
+            img_t = jnp.pad(
+                img_p.reshape(nb, bt, n, r, w).swapaxes(1, 2),
+                ((0, 0),) * 3 + ((0, rp - r), (0, 0)),
+            )
+        with jax.named_scope("bg.kernel"):
+            out = pl.pallas_call(
+                functools.partial(_stream_kernel, bt=bt, r=r, b=b, h=h, **kw),
+                grid=(nb, n + 2),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + const_specs,
+                out_specs=stripe_spec(out_stripe),
+                out_shape=out_shape,
+                scratch_shapes=scratch
+                + [
+                    pltpu.VMEM((2, bt, rp, w), sdt),  # DMA stripe slots
+                    pltpu.SemaphoreType.DMA((2,)),  # per-slot completion
+                ],
+                interpret=interpret,
+                name="bg_fused_streamed",
+            )(img_t, *consts)
     else:
-        msk_p = jnp.pad(
-            jnp.ones((b, h, w), sdt), ((0, bp - b), (0, hp - h), (0, 0))
-        ).reshape(bp, n, r, w)
-        out = pl.pallas_call(
-            functools.partial(_kernel, **kw),
-            grid=(nb, n + 2),
-            in_specs=[stripe_spec(in_stripe), stripe_spec(in_stripe)]
-            + const_specs,
-            out_specs=stripe_spec(out_stripe),
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(img_p, msk_p, *consts)
-    out = out.reshape(bp, hp, w)[:b, :h]
-    return out[0] if squeeze else out
+        with relayout():
+            msk_p = jnp.pad(
+                jnp.ones((b, h, w), sdt), ((0, bp - b), (0, hp - h), (0, 0))
+            ).reshape(bp, n, r, w)
+        with jax.named_scope("bg.kernel"):
+            out = pl.pallas_call(
+                functools.partial(_kernel, **kw),
+                grid=(nb, n + 2),
+                in_specs=[stripe_spec(in_stripe), stripe_spec(in_stripe)]
+                + const_specs,
+                out_specs=stripe_spec(out_stripe),
+                out_shape=out_shape,
+                scratch_shapes=scratch,
+                interpret=interpret,
+                name="bg_fused",
+            )(img_p, msk_p, *consts)
+    with jax.named_scope("bg.trim"):
+        out = out.reshape(bp, hp, w)[:b, :h]
+        return out[0] if squeeze else out
 
 
 # The public jitted entry point. ``bg_fused_impl`` stays importable unjitted

@@ -1050,7 +1050,23 @@ def _plan_executable(plan: BGPlan):
     layer (pipeline, engine, packer, launcher) issued them. Compositions
     mirror the pre-plan routes operation-for-operation, which is what keeps
     legacy shims bit-identical.
+
+    The executable is named by its backend (its module reads
+    ``jit_bg_<backend>``, with ``_temporal`` for temporal plans), and the
+    fused backends run their stages under ``jax.named_scope``:
+    ``bg.relayout`` (cast, pad, reshape and carry transpose before the
+    kernel), ``bg.kernel``, ``bg.trim`` (padding cut, carry layout back) and
+    ``bg.quantize``, so a profiler trace names every device operation.
     """
+    fn = _executable_fn(plan)
+    fn.__name__ = fn.__qualname__ = (
+        f"bg_{plan.backend}" + ("_temporal" if plan.temporal else "")
+    )
+    return jax.jit(fn)
+
+
+def _executable_fn(plan: BGPlan):
+    """The Python function that :func:`_plan_executable` jits."""
     cfg = plan.cfg
     quant = plan.quantize_output
     interpret = plan.interpret
@@ -1058,7 +1074,13 @@ def _plan_executable(plan: BGPlan):
     mesh = plan.mesh
 
     def _maybe_quantize(out):
-        return quantize_intensity(out, cfg) if quant else out
+        with jax.named_scope("bg.quantize"):
+            out = out.astype(jnp.float32)
+            return quantize_intensity(out, cfg) if quant else out
+
+    def _as_f32(frames):
+        with jax.named_scope("bg.relayout"):
+            return frames.astype(jnp.float32)
 
     # ------------------------------------------------------------- temporal
     if plan.temporal:
@@ -1087,7 +1109,7 @@ def _plan_executable(plan: BGPlan):
                 )
                 return _maybe_quantize(out), new_carry.astype(sdt)
 
-            return jax.jit(fn)
+            return fn
 
         # the unjitted impl: traced directly into this plan's one executable
         # (a nested pjit costs ~10% dispatch time in interpret mode)
@@ -1107,20 +1129,18 @@ def _plan_executable(plan: BGPlan):
         if mesh is None:
 
             def fn(frames, carry, alpha):
-                out, new_carry = inner_temporal(
-                    frames.astype(jnp.float32), carry, alpha
-                )
-                return _maybe_quantize(out.astype(jnp.float32)), new_carry
+                out, new_carry = inner_temporal(_as_f32(frames), carry, alpha)
+                return _maybe_quantize(out), new_carry
 
-            return jax.jit(fn)
+            return fn
 
         meshed = _mesh_call(inner_temporal, mesh, n_in=3, n_out=2)
 
         def fn(frames, carry, alpha):
-            out, new_carry = meshed(frames.astype(jnp.float32), carry, alpha)
-            return _maybe_quantize(out.astype(jnp.float32)), new_carry
+            out, new_carry = meshed(_as_f32(frames), carry, alpha)
+            return _maybe_quantize(out), new_carry
 
-        return jax.jit(fn)
+        return fn
 
     # --------------------------------------------------------- non-temporal
     if plan.backend == "reference":
@@ -1142,7 +1162,7 @@ def _plan_executable(plan: BGPlan):
                 return jax.vmap(single)(frames)
             return single(frames)
 
-        return jax.jit(fn)
+        return fn
 
     if plan.backend == "streaming":
         from repro.core.streaming import _streaming_single
@@ -1158,7 +1178,7 @@ def _plan_executable(plan: BGPlan):
                     return jax.vmap(single)(frames)
                 return single(frames)
 
-            return jax.jit(fn)
+            return fn
 
         meshed = _mesh_call(
             lambda x: jax.vmap(single)(x), mesh, n_in=1, n_out=1
@@ -1169,7 +1189,7 @@ def _plan_executable(plan: BGPlan):
                 return single(frames)
             return meshed(frames)
 
-        return jax.jit(fn)
+        return fn
 
     if plan.backend == "staged":
         from repro.kernels.ops import _staged_single
@@ -1184,7 +1204,7 @@ def _plan_executable(plan: BGPlan):
                 out = _staged_single(frames, cfg, interpret)
             return _maybe_quantize(out)
 
-        return jax.jit(fn)
+        return fn
 
     # fused / fused_streamed — the unjitted impl, traced into one executable
     from repro.kernels.bg_fused import bg_fused_impl
@@ -1201,23 +1221,22 @@ def _plan_executable(plan: BGPlan):
     if mesh is None:
 
         def fn(frames):
-            return _maybe_quantize(
-                inner(frames.astype(jnp.float32)).astype(jnp.float32)
-            )
+            return _maybe_quantize(inner(_as_f32(frames)))
 
-        return jax.jit(fn)
+        return fn
 
     meshed = _mesh_call(inner, mesh, n_in=1, n_out=1)
 
     def fn(frames):
-        frames = frames.astype(jnp.float32)
+        frames = _as_f32(frames)
         squeeze = frames.ndim == 2
         if squeeze:
             frames = frames[None]
-        out = _maybe_quantize(meshed(frames).astype(jnp.float32))
-        return out[0] if squeeze else out
+        out = _maybe_quantize(meshed(frames))
+        with jax.named_scope("bg.trim"):
+            return out[0] if squeeze else out
 
-    return jax.jit(fn)
+    return fn
 
 
 def executable_cache_info():

@@ -28,6 +28,7 @@ needed to map flag rows back to requests.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Hashable, Optional, Tuple
 
 import numpy as np
@@ -100,16 +101,32 @@ def validate_frame(frame, *, stream_id: Hashable = None) -> np.ndarray:
     return arr
 
 
+@functools.lru_cache(maxsize=None)
+def _guard_programs():
+    """Both guards as jitted programs whose operations run under the scope
+    ``bg.guard`` (built on first use: this module imports without JAX)."""
+    import jax
+    import jax.numpy as jnp
+
+    def finite_rows(x):
+        with jax.named_scope("bg.guard"):
+            return jnp.all(jnp.isfinite(x).reshape(x.shape[0], -1), axis=1)
+
+    def carry_ok_rows(carry, limit):
+        with jax.named_scope("bg.guard"):
+            flat = carry.reshape(carry.shape[0], -1)
+            return jnp.all(jnp.isfinite(flat) & (jnp.abs(flat) < limit), axis=1)
+
+    return jax.jit(finite_rows), jax.jit(carry_ok_rows, static_argnames="limit")
+
+
 def finite_rows(x):
     """Lazy per-row finite flags: ``(n, ...) -> (n,)`` bool, True = finite.
 
     A ``jnp.isfinite`` reduction launched with the dispatch — the cheap
     post-dispatch output guard. Realize it alongside the outputs.
     """
-    import jax.numpy as jnp
-
-    x = jnp.asarray(x)
-    return jnp.all(jnp.isfinite(x).reshape(x.shape[0], -1), axis=1)
+    return _guard_programs()[0](x)
 
 
 def carry_ok_rows(carry, limit: float = DEFAULT_CARRY_LIMIT):
@@ -118,8 +135,4 @@ def carry_ok_rows(carry, limit: float = DEFAULT_CARRY_LIMIT):
     The quarantine detector: a False row means that stream's temporal carry
     would poison every later frame and must be reset to cold.
     """
-    import jax.numpy as jnp
-
-    carry = jnp.asarray(carry)
-    flat = carry.reshape(carry.shape[0], -1)
-    return jnp.all(jnp.isfinite(flat) & (jnp.abs(flat) < limit), axis=1)
+    return _guard_programs()[1](carry, limit=limit)

@@ -35,6 +35,20 @@ and the ``benchmarks/bench_bg_chaos.py`` CI soak. The synchronous engine
 stays guard-free on purpose — it is the simple, deterministic oracle the
 async front is equivalence-tested against.
 
+**Telemetry** of the async front: ``EngineStats`` latencies run from
+``submit`` to the future being set (the consumer's copy of the answer to
+host memory comes after). Every request is stamped as it is taken off the
+queue, as its batch closes, as the launch returns and as its future is set,
+with the ``dispatch`` id of its batch; ``engine.timeline()`` returns the
+last finished requests' stamps (``StageRecord``), from which queue, hold,
+prep and completion time add up to each frame's latency. Under a profiler
+the dispatch thread runs the spans ``engine.wait`` / ``engine.hold`` /
+``engine.prep`` / ``engine.backpressure``, the completion thread
+``engine.await`` / ``engine.resolve``, one per dispatch with its id and
+size; the device programs are named ``jit_bg_<backend>`` with the scopes
+``bg.relayout`` / ``bg.kernel`` / ``bg.trim`` / ``bg.quantize``, and the
+engine's own ``bg.ingest`` / ``bg.guard`` / ``bg.split``.
+
 **Scaling out**: one ``AsyncFrameEngine`` is a single worker. The fleet
 layer (``repro.fleet``) fronts N of them behind a ``FleetRouter`` — sticky
 per-stream affinity (a temporal carry lives on exactly one worker),
@@ -47,7 +61,7 @@ rolls per-worker snapshots into exact fleet percentiles (union of the
 latency reservoirs, never averaged percentiles); see the ``repro.fleet``
 package docstring for the full architecture.
 """
-from .async_engine import AsyncFrameEngine, AsyncFrameRequest, EngineStats
+from .async_engine import AsyncFrameEngine, AsyncFrameRequest, EngineStats, StageRecord
 from .engine import Request, ServeEngine, make_prefill, make_serve_step
 from .frames import FrameDenoiseEngine, FrameRequest
 from .sampling import greedy, sample_temperature, sample_topk

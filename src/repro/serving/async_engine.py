@@ -76,7 +76,40 @@ latency, deadline misses, plus the reliability counters ``failed`` /
 ``retries`` / ``fallbacks`` / ``carry_resets`` / ``shed`` /
 ``watchdog_trips``) consumed by ``benchmarks/bench_video_stream.py`` and
 its ``BENCH_<ts>.json`` exporter; ``stats()["key"]`` indexing survives as a
-legacy shim.
+legacy shim. A request's latency runs from ``submit`` to its future being
+set: the consumer's copy of the answer to host memory comes after it.
+
+Every request carries ``time.monotonic`` stamps of its stages
+(:class:`AsyncFrameRequest`): ``t_submit``; ``t_taken``, when the dispatch
+thread takes it off the queue (or the held list) into its batch;
+``t_closed``, when that batch closes; ``t_launched``, when the guarded
+launch returns with the executable and its guards enqueued; ``t_resolved``,
+when its future is set. So per frame, queue = taken - submit, hold = closed
+- taken, prep = launched - closed, complete = resolved - launched, and the
+four add up to the latency. Each request also carries the ``dispatch`` id of
+the batch that took it. Finished requests land in a bounded ring (the last
+``TIMELINE_SIZE``); :meth:`AsyncFrameEngine.timeline` returns its
+:class:`StageRecord` tuples, and ``stats()`` reads its percentiles and
+``mean_batch`` from the ring's newest 4096. The stamps are always taken.
+
+The engine's two threads also run ``jax.profiler.TraceAnnotation`` spans,
+one per dispatch and stage (never one per frame), each with the ``dispatch``
+id and the batch size ``n`` as arguments; they cost nothing unless a
+profiler trace is being taken, and then share its clock with the device:
+
+  dispatch thread   ``engine.wait`` (blocked on an empty queue),
+                    ``engine.hold`` (the batch window), ``engine.prep``
+                    (stack, transfer, executable and guard dispatch; in
+                    video mode ``packer.pack`` inside it),
+                    ``engine.backpressure`` (blocked on a full in-flight
+                    queue)
+  completion thread ``engine.await`` (``block_until_ready`` under the
+                    watchdog), ``engine.resolve`` (guard flags, futures)
+
+The device programs are named too: the engine's ``bg.ingest``, ``bg.guard``
+and ``bg.split`` (``repro.serving.device_stages``) around the plan
+executable ``jit_bg_<backend>`` with its ``bg.relayout``, ``bg.kernel``,
+``bg.trim`` and ``bg.quantize`` stages (``repro.plan``).
 
 Dispatch is plan-driven: pass a ``repro.plan.BGPlan`` via ``plan=`` (or a
 packer whose plan carries the video dispatch); the legacy ``cfg``/``mesh``/
@@ -91,11 +124,14 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import Future
-from typing import Deque, Dict, Hashable, List, Optional, Sequence, Tuple
+from typing import (
+    Deque, Dict, Hashable, List, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.bilateral_grid import BGConfig
 from repro.reliability import (
@@ -109,17 +145,24 @@ from repro.reliability import (
     finite_rows,
     validate_frame,
 )
+from repro.serving.device_stages import host_f32, split_rows, stack_rows
 
-__all__ = ["AsyncFrameEngine", "AsyncFrameRequest", "EngineStats"]
+__all__ = ["AsyncFrameEngine", "AsyncFrameRequest", "EngineStats", "StageRecord"]
 
 _SENTINEL = object()
+TIMELINE_SIZE = 65536  # finished requests kept in the stage ring
+_STATS_WINDOW = 4096  # newest finished requests that stats() reads
 
 
 @dataclasses.dataclass(frozen=True)
 class EngineStats:
     """End-of-interval engine telemetry snapshot (ROADMAP's "structured
     metrics" item): counts are since engine start, depths are instantaneous,
-    latencies are over the last 4096 completed requests.
+    latencies are over the last 4096 finished requests, each from ``submit``
+    to its future being set (the consumer's copy of the answer to host
+    memory is not in it), and ``mean_batch`` is over the launched
+    dispatches among them. Both are read from the engine's stage ring
+    (:meth:`AsyncFrameEngine.timeline`).
 
     Reliability counters (PR 6): ``failed`` — requests resolved with an
     exception (dispatch/completion failures, finite-guard rejections);
@@ -234,8 +277,10 @@ class EngineStats:
 
 @dataclasses.dataclass
 class AsyncFrameRequest:
-    """One queued frame. ``deadline`` is absolute ``time.monotonic`` seconds;
-    ``stream_id`` is set only in video (packer) mode."""
+    """One queued frame. ``deadline`` and the ``t_*`` stage stamps are
+    absolute ``time.monotonic`` seconds (see the module docstring; a stage
+    the request never reached stays None); ``dispatch`` is the id of the
+    batch that took it; ``stream_id`` is set only in video (packer) mode."""
 
     uid: int
     frame: jnp.ndarray
@@ -243,6 +288,29 @@ class AsyncFrameRequest:
     t_submit: float
     deadline: Optional[float] = None
     stream_id: Optional[Hashable] = None
+    t_taken: Optional[float] = None
+    t_closed: Optional[float] = None
+    t_launched: Optional[float] = None
+    t_resolved: Optional[float] = None
+    dispatch: Optional[int] = None
+
+
+class StageRecord(NamedTuple):
+    """One finished request in :meth:`AsyncFrameEngine.timeline`: its stage
+    stamps (``time.monotonic`` seconds; None for a stage it never reached,
+    e.g. a launch that failed), the ``dispatch`` id and size ``n`` of the
+    batch that took it, and whether its future got a result (``ok``)."""
+
+    uid: int
+    stream_id: Optional[Hashable]
+    dispatch: Optional[int]
+    n: int
+    t_submit: float
+    t_taken: Optional[float]
+    t_closed: Optional[float]
+    t_launched: Optional[float]
+    t_resolved: float
+    ok: bool
 
 
 class AsyncFrameEngine:
@@ -341,8 +409,8 @@ class AsyncFrameEngine:
         self._outstanding = 0
         self._drained = threading.Condition(self._lock)
         # telemetry
-        self._latencies: Deque[float] = deque(maxlen=4096)
-        self._batch_sizes: Deque[int] = deque(maxlen=4096)
+        self._ring: Deque[StageRecord] = deque(maxlen=TIMELINE_SIZE)
+        self._next_dispatch = 0  # id of the next batch to close
         self._dispatches = 0
         self._completed = 0
         self._submitted = 0
@@ -463,13 +531,24 @@ class AsyncFrameEngine:
         self.close()
 
     # ----------------------------------------------------------- telemetry
+    def timeline(self) -> List[StageRecord]:
+        """The stage stamps of the finished requests still in the ring
+        (oldest first): how an operator or a benchmark writes out where each
+        frame's latency went. Requests shed at collect time are not in it
+        (``stats().shed`` counts them)."""
+        with self._lock:
+            return list(self._ring)
+
     def stats(self) -> EngineStats:
         def _pct(lat, q):
             return lat[min(int(q * len(lat)), len(lat) - 1)] * 1e3 if lat else 0.0
 
         with self._lock:
-            lat = sorted(self._latencies)
-            sizes = list(self._batch_sizes)
+            recent = list(itertools.islice(reversed(self._ring), _STATS_WINDOW))
+            lat = sorted(r.t_resolved - r.t_submit for r in recent)
+            sizes = list(
+                {r.dispatch: r.n for r in recent if r.t_launched is not None}.values()
+            )
             return EngineStats(
                 submitted=self._submitted,
                 completed=self._completed,
@@ -549,9 +628,13 @@ class AsyncFrameEngine:
     def _collect_batch(self) -> Optional[List[AsyncFrameRequest]]:
         """Block for the first request, then fill until batch-full, window
         expiry, or an imminent request deadline. Sheds already-expired
-        requests. Returns None on shutdown."""
+        requests. Returns None on shutdown. Stamps ``t_taken`` on each
+        request as it is taken, and ``t_closed`` and ``dispatch`` on the
+        batch it returns."""
+        d = self._next_dispatch
         while True:
-            first = self._get_next(timeout=0.1)
+            with TraceAnnotation("engine.wait", dispatch=d, n=0):
+                first = self._get_next(timeout=0.1)
             if first is None:
                 if self._stop.is_set():
                     self._drain_on_stop()
@@ -562,7 +645,20 @@ class AsyncFrameEngine:
                 return None
             if self._shed_expired(first):
                 continue
+            first.t_taken = time.monotonic()
             break
+        with TraceAnnotation("engine.hold", dispatch=d, n=0):
+            batch = self._fill(first)
+        t = time.monotonic()
+        for req in batch:
+            req.t_closed = t
+            req.dispatch = d
+        self._next_dispatch = d + 1
+        return batch
+
+    def _fill(self, first: AsyncFrameRequest) -> List[AsyncFrameRequest]:
+        """``first``'s batch: take requests until batch-full, window expiry
+        or an imminent deadline; same-stream repeats go to the held list."""
         batch = [first]
         streams = {first.stream_id}
         deferred: List[AsyncFrameRequest] = []
@@ -593,6 +689,7 @@ class AsyncFrameEngine:
             if self.packer is not None and nxt.stream_id in streams:
                 deferred.append(nxt)  # one frame per stream per pack
                 continue
+            nxt.t_taken = time.monotonic()
             batch.append(nxt)
             streams.add(nxt.stream_id)
             if nxt.deadline is not None:
@@ -612,14 +709,14 @@ class AsyncFrameEngine:
                     carry_limit=self.carry_limit,
                 )
             return [out[r.stream_id] for r in batch], guard
-        stacked = jnp.stack([jnp.asarray(r.frame, jnp.float32) for r in batch])
+        stacked = stack_rows([host_f32(r.frame) for r in batch], jnp.float32)
         if plan.mesh is None:
             stacked = jax.device_put(stacked)  # overlap transfer with compute
         out = plan(stacked)
         guard = DispatchGuard(
             out_ok=finite_rows(out) if self.output_guard else None
         )
-        return [out[i] for i in range(len(batch))], guard
+        return list(split_rows(out, len(batch))), guard
 
     def _guarded_launch(self, batch: List[AsyncFrameRequest]):
         """One guarded dispatch: retries + fallback ladder + breakers.
@@ -645,28 +742,33 @@ class AsyncFrameEngine:
                 return
             if not batch:
                 continue
+            d, n = batch[0].dispatch, len(batch)
             try:
-                outs, guard, rung, didx = self._guarded_launch(batch)
+                with TraceAnnotation("engine.prep", dispatch=d, n=n):
+                    outs, guard, rung, didx = self._guarded_launch(batch)
             except Exception as exc:  # caller errors + exhausted ladder
                 self._finish(batch, error=exc)
                 continue
+            t = time.monotonic()
+            for req in batch:
+                req.t_launched = t
             with self._lock:
                 self._dispatches += 1
-                self._batch_sizes.append(len(batch))
             # backpressure: at most max_inflight launched batches downstream;
             # stop-aware so a wedged completion thread cannot pin shutdown
             item = (batch, outs, guard, rung, didx)
-            while True:
-                try:
-                    self._inflight.put(item, timeout=0.2)
-                    break
-                except queue.Full:
-                    if self._stop.is_set():
-                        self._finish(
-                            batch,
-                            error=EngineClosed("engine closed mid-flight"),
-                        )
+            with TraceAnnotation("engine.backpressure", dispatch=d, n=n):
+                while True:
+                    try:
+                        self._inflight.put(item, timeout=0.2)
                         break
+                    except queue.Full:
+                        if self._stop.is_set():
+                            self._finish(
+                                batch,
+                                error=EngineClosed("engine closed mid-flight"),
+                            )
+                            break
 
     # ---------------------------------------------------------- completion
     def _await(self, payload, didx, batch, with_hook: bool = True):
@@ -813,7 +915,12 @@ class AsyncFrameEngine:
                 req.future.set_result(outs[i])
         with self._lock:
             for i, req in enumerate(batch):
-                self._latencies.append(now - req.t_submit)
+                req.t_resolved = now
+                self._ring.append(StageRecord(
+                    req.uid, req.stream_id, req.dispatch, len(batch),
+                    req.t_submit, req.t_taken, req.t_closed, req.t_launched,
+                    now, per_req[i] is None,
+                ))
                 if req.deadline is not None and now > req.deadline:
                     self._deadline_misses += 1
                 self._completed += per_req[i] is None
@@ -835,11 +942,15 @@ class AsyncFrameEngine:
             if item is _SENTINEL:
                 return
             batch, outs, guard, rung, didx = item
+            d, n = batch[0].dispatch, len(batch)
             try:
-                outs, out_ok, carry_ok = self._await(
-                    (outs, guard.out_ok, guard.carry_ok), didx, batch
-                )
+                with TraceAnnotation("engine.await", dispatch=d, n=n):
+                    outs, out_ok, carry_ok = self._await(
+                        (outs, guard.out_ok, guard.carry_ok), didx, batch
+                    )
             except Exception as exc:
-                self._on_completion_failure(batch, guard, rung, exc)
+                with TraceAnnotation("engine.resolve", dispatch=d, n=n):
+                    self._on_completion_failure(batch, guard, rung, exc)
                 continue
-            self._resolve(batch, outs, guard, out_ok, carry_ok, didx=didx)
+            with TraceAnnotation("engine.resolve", dispatch=d, n=n):
+                self._resolve(batch, outs, guard, out_ok, carry_ok, didx=didx)

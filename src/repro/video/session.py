@@ -35,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Hashable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -247,13 +248,22 @@ class MultiStreamPacker:
         ``jnp.isfinite`` reductions launched with the dispatch (they ride
         the same async dataflow; nothing here synchronizes) — the engine
         realizes them with the outputs and quarantines bad carries.
+
+        The pack runs in a ``packer.pack`` profiler span; its device work is
+        the named programs of ``repro.serving.device_stages`` (``bg.ingest``,
+        ``bg.split``), the guards (``bg.guard``) and the plan executable.
         """
+        with jax.profiler.TraceAnnotation("packer.pack", n=len(frames)):
+            return self._pack_guarded(frames, plan, carry_limit)
+
+    def _pack_guarded(self, frames, plan, carry_limit):
         from repro.reliability.guards import (
             DEFAULT_CARRY_LIMIT,
             DispatchGuard,
             carry_ok_rows,
             finite_rows,
         )
+        from repro.serving.device_stages import host_f32, split_rows, stack_rows
 
         if carry_limit is None:
             carry_limit = DEFAULT_CARRY_LIMIT
@@ -263,12 +273,12 @@ class MultiStreamPacker:
         if missing:
             raise KeyError(f"streams not open: {missing!r}")
         sids = sorted(frames, key=repr)
-        arrs = {s: jnp.asarray(frames[s], jnp.float32) for s in sids}
-        shapes = {a.shape for a in arrs.values()}
+        rows = [host_f32(frames[s]) for s in sids]
+        shapes = {r.shape for r in rows}
         if len(shapes) != 1 or len(next(iter(shapes))) != 2:
             raise ValueError(f"pack needs equal (h, w) frames, got {sorted(shapes)}")
         sessions = {s: self.sessions[s] for s in sids}
-        batch = jnp.stack([arrs[s] for s in sids])
+        batch = stack_rows(rows, jnp.float32)
         warm = [s for s in sids if sessions[s].alpha > 0.0]
         results = {}
         # the packer asks the plan for this pack's tile (the plan's own
@@ -284,18 +294,19 @@ class MultiStreamPacker:
             # all-cold pack: the carry-free per-frame fused path — nothing
             # temporal is materialized anywhere (temporal_denoise contract)
             out, _ = temporal_denoise(batch, alpha=0.0, plan=plan)
-            for i, s in enumerate(sids):
-                results[s] = out[i]
+            results = dict(zip(sids, split_rows(out, len(sids))))
         else:
             # ONE dispatch for the whole pack: the fused kernel's in-kernel
             # EMA takes a per-stream alpha row, and a == 0 rows (cold
             # streams, first temporal frames) are bit-identical to the
             # per-frame path, so cold and warm streams mix freely.
             h, w = batch.shape[1:]
-            zero = jnp.zeros(carry_shape(h, w, self.cfg), plan.storage_dtype)
-            carry = jnp.stack(
+            if any(sessions[s].carry is None for s in sids):
+                zero = jnp.zeros(carry_shape(h, w, self.cfg), plan.storage_dtype)
+            carry = stack_rows(
                 [zero if sessions[s].carry is None else sessions[s].carry
-                 for s in sids]
+                 for s in sids],
+                plan.storage_dtype,
             )
             # first temporal frame of a stream: no history, blend weight 0
             alpha = np.asarray(
@@ -307,14 +318,18 @@ class MultiStreamPacker:
                 batch, carry=carry, alpha=alpha, plan=plan
             )
             warm_rows = [i for i, s in enumerate(sids) if sessions[s].alpha > 0.0]
+            outs = split_rows(out, len(sids))
+            carries = split_rows(new_carry, len(sids))
             for i, s in enumerate(sids):
-                results[s] = out[i]
+                results[s] = outs[i]
                 if sessions[s].alpha > 0.0:
                     # cold sessions stay carry-free (the per-frame path
                     # needs no history); warm sessions advance their EMA
-                    sessions[s].carry = new_carry[i]
+                    sessions[s].carry = carries[i]
             carry_sids = tuple(sids[i] for i in warm_rows)
-            carry_ok = carry_ok_rows(new_carry[jnp.asarray(warm_rows)], carry_limit)
+            warm_carry = (new_carry if len(warm_rows) == len(sids)
+                          else new_carry[jnp.asarray(warm_rows)])
+            carry_ok = carry_ok_rows(warm_carry, carry_limit)
         for s in sids:
             sessions[s].frames_seen += 1
         guard = DispatchGuard(
