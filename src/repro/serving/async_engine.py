@@ -8,8 +8,9 @@ engine closes ROADMAP's "async/pipelined host-to-device frame feeding" item
 by splitting that loop across threads:
 
   client threads   -- submit(frame) -> Future          (bounded queue)
-  dispatch thread  -- collect micro-batch, stack on host, device_put +
-                      launch (JAX async dispatch)       -> in-flight queue
+  dispatch thread  -- collect micro-batch, transfer it in its own dtype,
+                      stack and widen on the device, launch
+                      (JAX async dispatch)              -> in-flight queue
   completion thread-- block_until_ready, resolve futures, record latency
 
 The in-flight queue holds at most ``max_inflight`` (default 2) launched
@@ -145,7 +146,7 @@ from repro.reliability import (
     finite_rows,
     validate_frame,
 )
-from repro.serving.device_stages import host_f32, split_rows, stack_rows
+from repro.serving.device_stages import ingest_rows, split_rows, stack_rows
 
 __all__ = ["AsyncFrameEngine", "AsyncFrameRequest", "EngineStats", "StageRecord"]
 
@@ -178,6 +179,12 @@ class EngineStats:
     ``reconnects`` — transport connections re-established to a
     process-spanning worker. Both are zero for in-process engines.
 
+    ``host_casts`` — frames the engine (or its packer) converted to float32
+    on the host because their dtype, or the mix of dtypes in their batch,
+    could not travel to the device as submitted
+    (``repro.serving.device_stages.ingest_rows``); ``completed -
+    host_casts`` frames crossed the host link in their own dtype.
+
     ``stats["key"]`` indexing is kept as a legacy shim for the former dict
     form; prefer attribute access. ``as_dict()`` feeds exporters (the
     ``BENCH_<ts>.json`` snapshot rows in benchmarks/bench_video_stream.py).
@@ -208,6 +215,7 @@ class EngineStats:
     watchdog_trips: int = 0
     restores: int = 0
     reconnects: int = 0
+    host_casts: int = 0
     latency_samples: Tuple[float, ...] = ()
 
     def __getitem__(self, key: str):
@@ -271,6 +279,7 @@ class EngineStats:
             watchdog_trips=sum(p.watchdog_trips for p in parts),
             restores=sum(p.restores for p in parts),
             reconnects=sum(p.reconnects for p in parts),
+            host_casts=sum(p.host_casts for p in parts),
             latency_samples=tuple(samples),
         )
 
@@ -420,6 +429,7 @@ class AsyncFrameEngine:
         self._fallbacks = 0
         self._carry_resets = 0
         self._shed = 0
+        self._host_casts = 0
         self._watchdog_trips = 0
 
         self._dispatcher = threading.Thread(
@@ -566,6 +576,8 @@ class AsyncFrameEngine:
                 shed=self._shed,
                 watchdog_trips=self._watchdog_trips,
                 restores=getattr(self.packer, "carry_restores", 0) or 0,
+                host_casts=self._host_casts
+                + (getattr(self.packer, "host_casts", 0) or 0),
                 latency_samples=tuple(x * 1e3 for x in lat),
             )
 
@@ -698,7 +710,7 @@ class AsyncFrameEngine:
         return batch
 
     def _launch_with(self, plan, batch: List[AsyncFrameRequest]):
-        """Stack on host, transfer, and dispatch (async) one micro-batch via
+        """Transfer, stack and dispatch (async) one micro-batch via
         ``plan``. Returns ``(outs, guard)``: the lazy per-request outputs in
         submission order plus the batch's lazy finite-guard flags."""
         if self.packer is not None:
@@ -709,7 +721,11 @@ class AsyncFrameEngine:
                     carry_limit=self.carry_limit,
                 )
             return [out[r.stream_id] for r in batch], guard
-        stacked = stack_rows([host_f32(r.frame) for r in batch], jnp.float32)
+        rows, casts = ingest_rows([r.frame for r in batch])
+        if casts:
+            with self._lock:
+                self._host_casts += casts
+        stacked = stack_rows(rows, jnp.float32)
         if plan.mesh is None:
             stacked = jax.device_put(stacked)  # overlap transfer with compute
         out = plan(stacked)

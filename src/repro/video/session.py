@@ -116,6 +116,7 @@ class MultiStreamPacker:
         self.sessions: Dict[Hashable, StreamSession] = {}
         self.carry_resets = 0    # lifetime count of quarantined carries
         self.carry_restores = 0  # lifetime count of snapshot-restored carries
+        self.host_casts = 0      # lifetime count of frames converted on the host
 
     @property
     def cfg(self) -> BGConfig:
@@ -263,7 +264,7 @@ class MultiStreamPacker:
             carry_ok_rows,
             finite_rows,
         )
-        from repro.serving.device_stages import host_f32, split_rows, stack_rows
+        from repro.serving.device_stages import ingest_rows, split_rows, stack_rows
 
         if carry_limit is None:
             carry_limit = DEFAULT_CARRY_LIMIT
@@ -273,7 +274,8 @@ class MultiStreamPacker:
         if missing:
             raise KeyError(f"streams not open: {missing!r}")
         sids = sorted(frames, key=repr)
-        rows = [host_f32(frames[s]) for s in sids]
+        rows, casts = ingest_rows([frames[s] for s in sids])
+        self.host_casts += casts
         shapes = {r.shape for r in rows}
         if len(shapes) != 1 or len(next(iter(shapes))) != 2:
             raise ValueError(f"pack needs equal (h, w) frames, got {sorted(shapes)}")

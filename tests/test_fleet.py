@@ -544,14 +544,14 @@ def test_engine_stats_merge_exact_percentiles():
     a = EngineStats(
         submitted=10, completed=10, dispatches=5, queue_depth=1,
         inflight_depth=0, deadline_misses=1, mean_batch=2.0,
-        latency_ms_p50=2.0, latency_ms_p99=4.0,
+        latency_ms_p50=2.0, latency_ms_p99=4.0, host_casts=4,
         latency_samples=(1.0, 2.0, 3.0, 4.0),
     )
     b = EngineStats(
         submitted=6, completed=6, dispatches=3, queue_depth=0,
         inflight_depth=2, deadline_misses=0, mean_batch=1.0,
         latency_ms_p50=100.0, latency_ms_p99=400.0, failed=2,
-        carry_resets=3, latency_samples=(100.0, 200.0, 400.0),
+        carry_resets=3, host_casts=1, latency_samples=(100.0, 200.0, 400.0),
     )
     m = EngineStats.merge([a, b, None])
     union = sorted(a.latency_samples + b.latency_samples)
@@ -565,6 +565,11 @@ def test_engine_stats_merge_exact_percentiles():
     assert m.submitted == 16 and m.completed == 16 and m.failed == 2
     assert m.dispatches == 8 and m.deadline_misses == 1
     assert m.carry_resets == 3
+    assert m.host_casts == 5 and m.as_dict()["host_casts"] == 5
+    # a worker that predates the field reports no host_casts key
+    old = a.as_dict()
+    del old["host_casts"]
+    assert EngineStats(**old).host_casts == 0
     assert m.mean_batch == pytest.approx((2.0 * 5 + 1.0 * 3) / 8)
     # empty and sample-free fallbacks
     empty = EngineStats.merge([])
