@@ -264,13 +264,15 @@ def phase_four_chips(h: int = 1080, w: int = 1920, n_frames: int = 16, seed: int
     against the same plan on one device."""
     from repro.plan import plan_for
     from repro.serving import AsyncFrameEngine
+    from repro.serving.device_stages import place_rows
     from repro.sharding.bg_shard import batch_mesh
 
     cfg = _paper_cfg()
     frames = noisy_frames(n_frames, h, w, seed)
     plan = plan_for(cfg, h, w, n_frames=n_frames, mesh=batch_mesh(4), cache=False)
     single = plan.with_options(mesh=None)
-    compile_s = _compile_s(plan.executable(), np.stack(frames))
+    # the engine pads a batch to a multiple of the mesh before dispatch
+    compile_s = _compile_s(plan.executable(), place_rows(frames, plan.mesh)[0])
     outs, wall_s, stats, _, warmup_s = _serve(
         lambda: AsyncFrameEngine(plan=plan, max_batch=n_frames,
                                  batch_window_ms=WINDOW_MS),

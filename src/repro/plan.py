@@ -43,7 +43,8 @@ with it:
 
 ``mesh`` (a 1-D device mesh) shards the frame/stream batch axis via
 ``shard_map`` — pure data parallelism, zero collectives (see
-``repro.sharding.bg_shard``). ``temporal`` switches the executable to the
+``repro.sharding.bg_shard``); ragged batches are padded and trimmed around
+the executable, never inside it. ``temporal`` switches the executable to the
 ``(frames, carry, alpha) -> (out, new_carry)`` video form.
 
 Storage precision (``BGPlan.precision``)
@@ -387,8 +388,10 @@ def plan_cost_hlo(plan: "BGPlan", h: int, w: int, n_frames: int = 1):
     analytic model (a full XLA compile) — sweep/diagnostic use, not the
     ``plan_for`` hot path."""
     from repro.launch.hlo_analysis import roofline_terms
+    from repro.sharding.bg_shard import row_pad
 
-    frames = jax.ShapeDtypeStruct((int(n_frames), int(h), int(w)), jnp.float32)
+    n_frames = int(n_frames) + row_pad(plan.mesh_size, int(n_frames))
+    frames = jax.ShapeDtypeStruct((n_frames, int(h), int(w)), jnp.float32)
     fn = plan.executable()
     if plan.temporal:
         gx, gy, gz = grid_shape(h, w, plan.cfg)
@@ -720,7 +723,7 @@ class BGPlan:
                 alpha = jnp.asarray(alpha_np)
             elif alpha.ndim == 0:
                 alpha = jnp.broadcast_to(alpha, (n,))
-            out, new_carry = self.executable()(frames, carry, alpha)
+            out, new_carry = self._run(frames, carry, alpha)
             return (out[0], new_carry[0]) if squeeze else (out, new_carry)
         if carry is not None or alpha is not None:
             raise ValueError(
@@ -731,14 +734,23 @@ class BGPlan:
             # batch axis (frames and channels are equally independent)
             b, h, w, c = frames.shape
             folded = jnp.moveaxis(frames, -1, 1).reshape(b * c, h, w)
-            out = self.executable()(folded)
+            out = self._run(folded)
             return jnp.moveaxis(out.reshape(b, c, h, w), 1, -1)
         if frames.ndim not in (2, 3):
             raise ValueError(
                 f"expected (h, w), (b, h, w) or (b, h, w, c) frames, got "
                 f"{frames.shape}"
             )
-        return self.executable()(frames)
+        if self.mesh is not None and frames.ndim == 2:
+            return self._run(frames[None])[0]  # one frame: a batch of one
+        return self._run(frames)
+
+    def _run(self, *arrays):
+        """The executable on ``arrays``; on a mesh through
+        :func:`_mesh_call`, which pads and trims the batch around it."""
+        if self.mesh is None:
+            return self.executable()(*arrays)
+        return _mesh_call(self.executable(), self.mesh_size, *arrays)
 
 
 # ------------------------------------------------------------------ plan_for
@@ -1007,19 +1019,15 @@ def warn_legacy_dispatch(site: str) -> None:
     )
 
 
-def _mesh_call(inner, mesh, n_in: int, n_out: int):
-    """The shared mesh composition: zero-pad every input's leading axis to a
-    device multiple, shard_map ``inner`` with plain batch-axis specs
-    (``check_vma=False`` — pallas_call has no varying-axes rule), and trim
-    every output back to the original leading size. Returns
-    ``fn(*arrays) -> output`` (tuple for ``n_out > 1``)."""
+def _shard_map(inner, mesh, n_in: int, n_out: int):
+    """``inner`` over ``mesh`` with plain batch-axis specs on every input
+    and output (``check_vma=False`` — pallas_call has no varying-axes rule).
+    The batch must already be a multiple of the device count: each device
+    runs ``inner`` on its own rows, and nothing crosses the mesh."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.bg_shard import _pad_rows, _row_pad
-
-    nd = int(mesh.devices.size)
     spec = P(mesh.axis_names[0])
-    sharded = jax.shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=spec if n_in == 1 else (spec,) * n_in,
@@ -1027,15 +1035,25 @@ def _mesh_call(inner, mesh, n_in: int, n_out: int):
         check_vma=False,
     )
 
-    def call(*arrays):
-        n = arrays[0].shape[0]
-        pad = _row_pad(nd, n)
-        out = sharded(*(_pad_rows(a, pad) for a in arrays))
-        if n_out == 1:
-            return out[:n]
-        return tuple(o[:n] for o in out)
 
-    return call
+def _mesh_call(exe, n_devices: int, *arrays):
+    """A mesh plan's dispatch: zero-pad every input's leading axis to a
+    multiple of ``n_devices``, run the executable ``exe``, and trim every
+    output back to the original length. Padding and trim stay outside the
+    executable, so it compiles once per padded length and holds no
+    collective. A batch already at a multiple passes through untouched and
+    its answer stays sharded ``P("batch")``: the engine places its batches
+    so (``repro.serving.device_stages.place_rows``)."""
+    from repro.sharding.bg_shard import pad_rows, row_pad
+
+    n = arrays[0].shape[0]
+    pad = row_pad(n_devices, n)
+    if not pad:
+        return exe(*arrays)
+    out = exe(*(pad_rows(a, pad) for a in arrays))
+    if isinstance(out, tuple):
+        return tuple(o[:n] for o in out)
+    return out[:n]
 
 
 # -------------------------------------------------- compiled-executable cache
@@ -1043,13 +1061,16 @@ def _mesh_call(inner, mesh, n_in: int, n_out: int):
 def _plan_executable(plan: BGPlan):
     """ONE jitted callable per plan (the compiled-executable cache).
 
-    The callable owns the complete dispatch: dtype normalization, ragged-
-    batch padding, the shard_map wrapper for mesh plans, the kernel/scan/
-    reference compute, padding trim, and output quantization — so repeat
-    dispatches of a plan hit one compiled executable regardless of which
-    layer (pipeline, engine, packer, launcher) issued them. Compositions
-    mirror the pre-plan routes operation-for-operation, which is what keeps
-    legacy shims bit-identical.
+    The callable owns the complete dispatch: dtype normalization, the
+    shard_map wrapper for mesh plans, the kernel/scan/reference compute,
+    tile-padding trim, and output quantization — so repeat dispatches of a
+    plan hit one compiled executable regardless of which layer (pipeline,
+    engine, packer, launcher) issued them. Compositions mirror the pre-plan
+    routes operation-for-operation, which is what keeps legacy shims
+    bit-identical. A mesh plan's executable takes a batch at a multiple of
+    the device count, sharded ``P("batch")`` or placed by it, and returns it
+    so; :meth:`BGPlan.__call__` pads and trims other lengths around it
+    (:func:`_mesh_call`).
 
     The executable is named by its backend (its module reads
     ``jit_bg_<backend>``, with ``_temporal`` for temporal plans), and the
@@ -1134,7 +1155,7 @@ def _executable_fn(plan: BGPlan):
 
             return fn
 
-        meshed = _mesh_call(inner_temporal, mesh, n_in=3, n_out=2)
+        meshed = _shard_map(inner_temporal, mesh, n_in=3, n_out=2)
 
         def fn(frames, carry, alpha):
             out, new_carry = meshed(_as_f32(frames), carry, alpha)
@@ -1180,16 +1201,9 @@ def _executable_fn(plan: BGPlan):
 
             return fn
 
-        meshed = _mesh_call(
+        return _shard_map(
             lambda x: jax.vmap(single)(x), mesh, n_in=1, n_out=1
         )
-
-        def fn(frames):
-            if frames.ndim == 2:  # single frame: plain scan, no shard_map
-                return single(frames)
-            return meshed(frames)
-
-        return fn
 
     if plan.backend == "staged":
         from repro.kernels.ops import _staged_single
@@ -1225,16 +1239,10 @@ def _executable_fn(plan: BGPlan):
 
         return fn
 
-    meshed = _mesh_call(inner, mesh, n_in=1, n_out=1)
+    meshed = _shard_map(inner, mesh, n_in=1, n_out=1)
 
     def fn(frames):
-        frames = _as_f32(frames)
-        squeeze = frames.ndim == 2
-        if squeeze:
-            frames = frames[None]
-        out = _maybe_quantize(meshed(frames))
-        with jax.named_scope("bg.trim"):
-            return out[0] if squeeze else out
+        return _maybe_quantize(meshed(_as_f32(frames)))
 
     return fn
 

@@ -101,7 +101,11 @@ profiler trace is being taken, and then share its clock with the device:
   dispatch thread   ``engine.wait`` (blocked on an empty queue),
                     ``engine.hold`` (the batch window), ``engine.prep``
                     (stack, transfer, executable and guard dispatch; in
-                    video mode ``packer.pack`` inside it),
+                    video mode ``packer.pack`` inside it; for a mesh plan
+                    ``engine.place`` inside it, with the batch's ``pad``
+                    rows as a third argument: each shard's rows put on its
+                    chip, one thread per chip, and stacked and padded
+                    there),
                     ``engine.backpressure`` (blocked on a full in-flight
                     queue)
   completion thread ``engine.await`` (``block_until_ready`` under the
@@ -146,7 +150,9 @@ from repro.reliability import (
     finite_rows,
     validate_frame,
 )
-from repro.serving.device_stages import ingest_rows, split_rows, stack_rows
+from repro.serving.device_stages import (
+    ingest_rows, place_rows, split_rows, split_shards, stack_rows,
+)
 
 __all__ = ["AsyncFrameEngine", "AsyncFrameRequest", "EngineStats", "StageRecord"]
 
@@ -185,6 +191,10 @@ class EngineStats:
     (``repro.serving.device_stages.ingest_rows``); ``completed -
     host_casts`` frames crossed the host link in their own dtype.
 
+    ``mesh_pad_rows`` — zero rows dispatched to fill the shards of a mesh
+    plan's ragged micro-batches (``device_stages.place_rows``): device work
+    that answers no request.
+
     ``stats["key"]`` indexing is kept as a legacy shim for the former dict
     form; prefer attribute access. ``as_dict()`` feeds exporters (the
     ``BENCH_<ts>.json`` snapshot rows in benchmarks/bench_video_stream.py).
@@ -216,6 +226,7 @@ class EngineStats:
     restores: int = 0
     reconnects: int = 0
     host_casts: int = 0
+    mesh_pad_rows: int = 0
     latency_samples: Tuple[float, ...] = ()
 
     def __getitem__(self, key: str):
@@ -280,6 +291,7 @@ class EngineStats:
             restores=sum(p.restores for p in parts),
             reconnects=sum(p.reconnects for p in parts),
             host_casts=sum(p.host_casts for p in parts),
+            mesh_pad_rows=sum(p.mesh_pad_rows for p in parts),
             latency_samples=tuple(samples),
         )
 
@@ -430,6 +442,7 @@ class AsyncFrameEngine:
         self._carry_resets = 0
         self._shed = 0
         self._host_casts = 0
+        self._mesh_pad_rows = 0
         self._watchdog_trips = 0
 
         self._dispatcher = threading.Thread(
@@ -578,6 +591,7 @@ class AsyncFrameEngine:
                 restores=getattr(self.packer, "carry_restores", 0) or 0,
                 host_casts=self._host_casts
                 + (getattr(self.packer, "host_casts", 0) or 0),
+                mesh_pad_rows=self._mesh_pad_rows,
                 latency_samples=tuple(x * 1e3 for x in lat),
             )
 
@@ -712,7 +726,9 @@ class AsyncFrameEngine:
     def _launch_with(self, plan, batch: List[AsyncFrameRequest]):
         """Transfer, stack and dispatch (async) one micro-batch via
         ``plan``. Returns ``(outs, guard)``: the lazy per-request outputs in
-        submission order plus the batch's lazy finite-guard flags."""
+        submission order plus the batch's lazy finite-guard flags. A mesh
+        plan's batch is padded and placed across its chips in an
+        ``engine.place`` span, and each answer stays on its shard's chip."""
         if self.packer is not None:
             by_sid = {r.stream_id: r.frame for r in batch}
             with self._packer_lock:
@@ -725,14 +741,24 @@ class AsyncFrameEngine:
         if casts:
             with self._lock:
                 self._host_casts += casts
-        stacked = stack_rows(rows, jnp.float32)
+        n = len(batch)
         if plan.mesh is None:
+            stacked = stack_rows(rows, jnp.float32)
             stacked = jax.device_put(stacked)  # overlap transfer with compute
-        out = plan(stacked)
-        guard = DispatchGuard(
-            out_ok=finite_rows(out) if self.output_guard else None
-        )
-        return list(split_rows(out, len(batch))), guard
+            out = plan(stacked)
+            guard = DispatchGuard(
+                out_ok=finite_rows(out) if self.output_guard else None
+            )
+            return list(split_rows(out, n)), guard
+        with TraceAnnotation("engine.place", dispatch=batch[0].dispatch, n=n) as span:
+            placed, pad = place_rows(rows, plan.mesh)
+            span.set_metadata(pad=pad)
+        if pad:
+            with self._lock:
+                self._mesh_pad_rows += pad
+        out = plan(placed)
+        guard = DispatchGuard(out_ok=finite_rows(out) if self.output_guard else None)
+        return split_shards(out, n), guard
 
     def _guarded_launch(self, batch: List[AsyncFrameRequest]):
         """One guarded dispatch: retries + fallback ladder + breakers.

@@ -22,6 +22,18 @@ float32 on the host first, so a mixed batch compiles no program that a
 uniform one would not. Device arrays pass as they are. Every widening here
 is exact, so both paths give the same bits.
 
+A mesh plan's micro-batch takes another road. :func:`place_rows` gives
+each shard of the batch its rows and puts them straight on the shard's
+chip, one thread per chip, so the four host links work at once; there one
+:func:`stack_rows` stacks them in their own dtype and appends the zero rows
+that pad the batch to a multiple of the mesh size (``bg_shard.row_pad``).
+Device rows move chip to chip, never through the host. The shards form one
+batch sharded ``P("batch")``, so no row passes through a chip that does not
+own it, and the plan executable widens on each chip.
+:func:`split_shards` then splits each shard's answer rows off on the chip
+that made them (one ``split_rows`` per shard); pad rows are never split
+off.
+
 The plan executable's own stages (``bg.relayout``, ``bg.kernel``,
 ``bg.trim``, ``bg.quantize``) are scoped in ``repro.plan`` and
 ``repro.kernels.bg_fused``.
@@ -29,13 +41,17 @@ The plan executable's own stages (``bg.relayout``, ``bg.kernel``,
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-__all__ = ["ingest_rows", "split_rows", "stack_rows"]
+from repro.sharding.bg_shard import row_pad
+
+__all__ = ["ingest_rows", "place_rows", "split_rows", "split_shards", "stack_rows"]
 
 # host dtypes that travel to the device as submitted (besides integers of at
 # most 2 bytes); the rest are wider than the float32 they become
@@ -65,12 +81,14 @@ def ingest_rows(frames) -> Tuple[List, int]:
     return rows, len(host)
 
 
-@functools.partial(jax.jit, static_argnames="dtype")
-def stack_rows(rows, dtype):
-    """``(h, w)`` rows -> one ``(n, h, w)`` array of ``dtype`` (scope
-    ``bg.ingest``). One program per row count, shape and dtype."""
+@functools.partial(jax.jit, static_argnames=("dtype", "pad"))
+def stack_rows(rows, dtype, pad=0):
+    """``(h, w)`` rows -> one ``(n + pad, h, w)`` array of ``dtype``, the
+    last ``pad`` rows zero (scope ``bg.ingest``). One program per row count,
+    pad, shape and dtype."""
     with jax.named_scope("bg.ingest"):
-        return jnp.stack([r.astype(dtype) for r in rows])
+        rows = [r.astype(dtype) for r in rows]
+        return jnp.stack(rows + [jnp.zeros_like(rows[0])] * pad)
 
 
 @functools.partial(jax.jit, static_argnums=1)
@@ -78,3 +96,49 @@ def split_rows(x, n):
     """The first ``n`` rows of ``x`` as ``n`` arrays (scope ``bg.split``)."""
     with jax.named_scope("bg.split"):
         return tuple(x[i] for i in range(n))
+
+
+@functools.lru_cache(maxsize=None)
+def _placers(n: int) -> ThreadPoolExecutor:
+    """``n`` threads that put shards on their chips side by side (a
+    transfer releases the GIL)."""
+    return ThreadPoolExecutor(n, thread_name_prefix="bg-place")
+
+
+def place_rows(rows, mesh) -> Tuple[jax.Array, int]:
+    """One micro-batch's rows (from :func:`ingest_rows`) as one batch on
+    ``mesh``, sharded over its batch axis, and the number of zero rows that
+    pad it to a multiple of the mesh size (on the last shards). Each shard's
+    rows go to its own device, host rows over its host link and device rows
+    chip to chip, all shards at once; there :func:`stack_rows` stacks them
+    in the rows' common dtype."""
+    nd = int(mesh.devices.size)
+    pad = row_pad(nd, len(rows))
+    per = (len(rows) + pad) // nd
+    dtype = jnp.result_type(*(r.dtype for r in rows))
+    shape = (per * nd,) + tuple(rows[0].shape)
+    sharding = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
+    starts = {d: i[0].start or 0
+              for d, i in sharding.addressable_devices_indices_map(shape).items()}
+
+    def shard(device):
+        mine = rows[starts[device]:starts[device] + per]
+        if not mine:
+            return jnp.zeros((per,) + shape[1:], dtype, device=device)
+        return stack_rows(jax.device_put(mine, device), dtype, pad=per - len(mine))
+
+    shards = list(_placers(nd).map(shard, starts))
+    return jax.make_array_from_single_device_arrays(shape, sharding, shards), pad
+
+
+def split_shards(x, n) -> List:
+    """The first ``n`` rows of the batch-sharded ``x`` as ``n`` arrays, each
+    on the device of the shard that holds it: one :func:`split_rows` per
+    shard, on that shard's own data, so nothing crosses between chips."""
+    rows = []
+    for shard in sorted(x.addressable_shards, key=lambda s: s.index[0].start or 0):
+        start = shard.index[0].start or 0
+        k = min(n - start, shard.data.shape[0])
+        if k > 0:
+            rows.extend(split_rows(shard.data, k))
+    return rows

@@ -6,30 +6,34 @@ device runs the whole fused GC||GF||TI macro-pipeline on its slice of the
 frame batch. The same holds for the *temporal* video path
 (:func:`bg_temporal_sharded`): the per-stream grid carry and alpha rows
 shard with their stream's frame, so each device advances its streams' EMAs
-locally and still no data crosses the mesh. Nothing in the kernel reads
-across frames, therefore:
+locally. Nothing in the kernel reads across frames. The contract:
 
-  * in_specs / out_specs are plain ``P("batch")`` on the frame axis — the
-    constant operands (column one-hots, taps) are rebuilt inside the per-shard
-    call and live replicated in each device's VMEM;
-  * there are **zero cross-device collectives** — no psum, no ppermute, no
-    gradient of any kind crosses the mesh; throughput scales with the device
-    count until the host can no longer feed frames;
-  * ragged batches are padded up to a multiple of the device count with zero
-    frames *before* the shard_map (each shard then pads independently to its
-    batch tile, exactly as the single-device call does), and the padding is
-    dropped after — so the sharded output is bit-identical to the
-    single-device ``bg_fused_kernel_call`` on the same batch.
+  * a mesh plan's executable takes a batch whose length is a multiple of
+    the device count, sharded (or shardable) ``P("batch")`` on the frame
+    axis, and returns ``P("batch")``; the constant operands (column
+    one-hots, taps) are rebuilt inside the per-shard call and live
+    replicated in each device's VMEM;
+  * the executable holds no cross-device collective — no psum, no
+    ppermute, no all-gather, for any batch length — because it neither pads
+    nor trims: a ragged batch is padded with zero rows to a device multiple
+    before it (:func:`row_pad`, :func:`pad_rows`) and trimmed after it, by
+    ``repro.plan._mesh_call``; each shard then pads independently to its
+    batch tile, exactly as the single-device call does, so the output is
+    bit-identical to the single-device ``bg_fused_kernel_call`` on the same
+    batch;
+  * the serving engine places each micro-batch itself
+    (``repro.serving.device_stages.place_rows``): its rows go straight to
+    the chip of their shard, all shards at once, and the pad rows are
+    made there; each answer row is split off on that chip
+    (``split_shards``) and stays there.
 
 ``check_vma=False`` is required because ``pallas_call`` has no varying-axes
 rule; it is safe here since no out spec claims replication.
 
-The pad -> shard_map -> trim -> quantize composition itself now lives in the
-plan layer (``repro.plan``): :func:`bg_denoise_sharded` and
-:func:`bg_temporal_sharded` are thin shims that route their kwargs into a
-mesh-carrying :class:`repro.plan.BGPlan`, so repeat dispatches hit the
-plan's per-(plan, mesh) compiled-executable cache instead of this module
-maintaining its own shard_map/jit LRUs.
+:func:`bg_denoise_sharded` and :func:`bg_temporal_sharded` are thin shims
+that route their kwargs into a mesh-carrying :class:`repro.plan.BGPlan`, so
+repeat dispatches hit the plan's per-(plan, mesh) compiled-executable cache
+instead of this module maintaining its own shard_map/jit LRUs.
 """
 from __future__ import annotations
 
@@ -42,6 +46,8 @@ from repro.core.bilateral_grid import BGConfig
 __all__ = [
     "BATCH_AXIS",
     "batch_mesh",
+    "pad_rows",
+    "row_pad",
     "shard_batch_call",
     "bg_denoise_sharded",
     "bg_temporal_sharded",
@@ -75,14 +81,15 @@ def _service_mesh(mesh: jax.sharding.Mesh | None) -> jax.sharding.Mesh | None:
     return mesh
 
 
-def _row_pad(nd: int, n: int) -> int:
+def row_pad(nd: int, n: int) -> int:
     """Zero rows needed to bring a leading axis of ``n`` up to a device
     multiple (the shared ragged-batch rule: pad before shard_map, trim
     after)."""
-    return -(-n // nd) * nd - n
+    return -n % nd
 
 
-def _pad_rows(arr: jnp.ndarray, pad: int) -> jnp.ndarray:
+def pad_rows(arr: jnp.ndarray, pad: int) -> jnp.ndarray:
+    """``arr`` with ``pad`` zero rows appended to its leading axis."""
     return jnp.pad(arr, ((0, pad),) + ((0, 0),) * (arr.ndim - 1))
 
 
@@ -100,7 +107,7 @@ def shard_batch_call(fn, images: jnp.ndarray, mesh: jax.sharding.Mesh) -> jnp.nd
     """
     axis = mesh.axis_names[0]
     b = images.shape[0]
-    padded = _pad_rows(images, _row_pad(int(mesh.devices.size), b))
+    padded = pad_rows(images, row_pad(int(mesh.devices.size), b))
     sharded = jax.shard_map(
         fn, mesh=mesh, in_specs=P(axis), out_specs=P(axis), check_vma=False
     )

@@ -58,10 +58,10 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(plan, n, hw, sharding):
+def _compile(plan, n, hw, sharding, dtype=jnp.float32):
     """Lower + compile the plan's executable for ``n`` frames of ``hw``."""
     h, w = hw
-    frames = jax.ShapeDtypeStruct((n, h, w), jnp.float32, sharding=sharding)
+    frames = jax.ShapeDtypeStruct((n, h, w), dtype, sharding=sharding)
     args = [frames]
     if plan.temporal:
         args.append(jax.ShapeDtypeStruct(
@@ -106,20 +106,35 @@ def test_temporal_plan_compiles_for_v5e(one_chip):
     _compile(plan, 4, HD, one_chip)
 
 
-def test_four_device_mesh_plan_compiles_for_v5e(topo):
+@pytest.mark.parametrize(
+    "n,dtype",
+    [
+        (16, jnp.float32),  # a full batch, already float32 and sharded
+        # a ragged batch as the engine places it: 8-bit rows padded with
+        # zero rows to 8 (device_stages.place_rows)
+        (6, jnp.uint8),
+    ],
+)
+def test_four_device_mesh_plan_compiles_for_v5e(topo, n, dtype):
     """The batch-axis mesh plan over four chips: one kernel per device and no
-    collective (frames are independent)."""
+    collective (frames are independent), in the executable and in the
+    engine's guard over its answer, which stays sharded by batch."""
+    from repro.reliability.guards import _guard_programs
+
     mesh = Mesh(
         np.array(topo.devices[:4]), ("batch",),
         axis_types=(jax.sharding.AxisType.Auto,),
     )
     plan = plan_for(
-        _cfg(12), *HD, n_frames=16, mesh=mesh, interpret=False, cache=False
+        _cfg(12), *HD, n_frames=n, mesh=mesh, interpret=False, cache=False
     )
     assert plan.mesh_size == 4
-    compiled = _compile(
-        plan, 16, HD, NamedSharding(mesh, PartitionSpec("batch"))
-    )
-    text = compiled.as_text()
-    for coll in ("all-reduce", "all-gather", "all-to-all", "collective-permute"):
-        assert coll not in text, coll
+    by_batch = NamedSharding(mesh, PartitionSpec("batch"))
+    placed = -(-n // 4) * 4
+    compiled = _compile(plan, placed, HD, by_batch, dtype)
+    assert compiled.output_shardings.is_equivalent_to(by_batch, 3)
+    answer = jax.ShapeDtypeStruct((placed,) + HD, jnp.float32, sharding=by_batch)
+    guard = _guard_programs()[0].lower(answer).compile()
+    for text in (compiled.as_text(), guard.as_text()):
+        for coll in ("all-reduce", "all-gather", "all-to-all", "collective-permute"):
+            assert coll not in text, coll
