@@ -26,8 +26,25 @@ the GC / TI contractions become larger, MXU-friendlier matmuls:
     contraction (one dot instead of four), followed by a static row-split sum
     onto planes s / s+1.
   * TI: the four per-corner y-gather matmuls collapse into one
-    `(2*bt*gz, gy) x (gy, 2*w)` contraction against the stacked floor/ceil
-    column one-hots; the x/y lerp weights are folded before the z contraction.
+    `(k*2*bt*gz, gy) x (gy, 2*w)` contraction against the stacked floor/ceil
+    column one-hots (k = 3 bf16 parts per fp32 plane value, 1 under bf16
+    storage); the x/y lerp weights are folded before the z contraction.
+
+Contraction precision — one exact bf16 MXU pass each
+----------------------------------------------------
+v5e's MXU multiplies bf16 only: an fp32 `Precision.HIGHEST` product is
+emulated with several bf16 passes. Both contractions are one-hot, so one
+pass over bf16 operands that hold their values exactly gives the same bits:
+
+  * GC, for frames that arrive as 8-bit integer levels (`is_level_dtype`,
+    decided by `bg_fused_impl` from the frames' dtype) and under bf16
+    storage: mask (0/1), masked intensities (integers 0..255) and the column
+    one-hot (0/1) are all bf16 values, every product is exact, and every fp32
+    sum (at most r levels of at most 255) is far below 2**24. Fractional fp32
+    frames keep `HIGHEST`: their intensities are not bf16 values.
+  * TI, for every input: each fp32 plane value is split into three bf16
+    parts stacked along the rows (`ti_gather`); the one-hot picks one part
+    per output and `(hi + mid) + lo` rebuilds the fp32 value exactly.
 
 Per-batch scratch reset: the working set in VMEM persists across grid steps,
 so the kernel re-zeroes all six scratch buffers at stripe 0 of every batch
@@ -125,12 +142,52 @@ from .common import (
     ti_col_onehots,
 )
 
-__all__ = ["bg_fused_kernel_call", "bg_fused_impl", "DEFAULT_BATCH_TILE"]
+__all__ = [
+    "bg_fused_kernel_call", "bg_fused_impl", "DEFAULT_BATCH_TILE",
+    "is_level_dtype", "ti_gather",
+]
 
 # Frames per grid step. Bounded so the per-step working set (one-hot
 # z-reductions + two raw-plane stripes per frame) stays well under VMEM for
 # full-HD rows; raise per-call via `batch_tile=` on smaller frames.
 DEFAULT_BATCH_TILE = 4
+
+
+def is_level_dtype(dtype) -> bool:
+    """Whether frames of ``dtype`` are integer levels that bfloat16 holds
+    exactly (every value within +-256: the 8-bit integers). Such frames run
+    GC's contraction as one bf16 MXU pass (module docstring)."""
+    dtype = np.dtype(dtype)
+    return np.issubdtype(dtype, np.integer) and dtype.itemsize == 1
+
+
+def ti_gather(planes, y_oh, n_parts):
+    """TI's four y-corner gathers as one one-pass bf16 contraction.
+
+    ``planes`` is the fp32 ``(2, bt, gz, gy)`` stack, ``y_oh`` the bf16
+    ``(2, w, gy)`` floor/ceil column one-hots; returns ``(2, bt, gz, 2, w)``
+    fp32, bit for bit the ``Precision.HIGHEST`` gather. Each plane value is
+    split into ``n_parts`` bf16 parts stacked along the rows (each part the
+    bf16 rounding of what the earlier ones leave): three parts hold all 24
+    significand bits of an fp32 value, so ``(p0 + p1) + p2`` rebuilds it
+    exactly, and one part suffices for values that are already bf16. A
+    0/1 one-hot picks one part per output, so every product and every fp32
+    sum of the contraction is exact. gy is minor on both operands: no
+    transposition of the planes.
+    """
+    rest, parts = planes, []
+    for _ in range(n_parts):
+        part = rest.astype(jnp.bfloat16).astype(jnp.float32)
+        parts.append(part)
+        rest = rest - part
+    # fold the parts into matmul rows while they are fp32: Mosaic cannot
+    # fold a packed bf16 stack whose gz is not a multiple of 16
+    rows = jnp.stack(parts).reshape(-1, planes.shape[-1])
+    gathered = jnp.einsum(
+        "mg,cwg->mcw", rows.astype(jnp.bfloat16), y_oh,
+        preferred_element_type=jnp.float32,
+    ).reshape((n_parts,) + planes.shape[:-1] + y_oh.shape[:2])
+    return sum((gathered[k] for k in range(1, n_parts)), gathered[0])
 
 
 def _pipeline_step(
@@ -173,12 +230,22 @@ def _pipeline_step(
 
     Storage precision: the scratch refs' dtype is the plan's *storage*
     dtype (fp32 or bf16 — ``bg_fused_impl`` allocates them). Every scratch
-    read upcasts to fp32, every write downcasts to the ref dtype, the big
-    per-step stacks (GC one-hot z-stack, TI z-weights, the stacked blurred
-    planes) are materialized in the storage dtype, and both contractions
-    pin ``preferred_element_type=float32`` — bf16 operands, fp32
-    accumulation. On fp32 scratch every one of these casts is a same-dtype
-    no-op, so the fp32 jaxpr is byte-for-byte the pre-precision one.
+    read upcasts to fp32, every write downcasts to the ref dtype, the TI
+    z-weights are materialized in the storage dtype, and both contractions
+    pin ``preferred_element_type=float32`` (fp32 accumulation).
+
+    Contraction precision (v5e's MXU multiplies bf16 only; an fp32
+    ``HIGHEST`` product is emulated with several bf16 passes):
+
+      * GC's operands are the 0/1 mask, the masked intensities and the 0/1
+        column one-hot ``col_oh``, whose dtype ``bg_fused_impl`` chose. It
+        is bf16 when the frames are 8-bit levels or storage is bf16: every
+        operand is then a bf16 value, every product exact, and every fp32
+        sum (at most r levels of at most 255 per output) far below 2**24,
+        so the one default pass gives ``HIGHEST``'s bits. For fractional
+        fp32 frames it is fp32 and the contraction stays ``HIGHEST``.
+      * TI's y-gather (:func:`ti_gather`) is one bf16 pass over the planes
+        split into exact bf16 parts, for every input dtype.
     """
     sdt = r2_s.dtype  # the storage dtype (scratch allocation decides)
     # ---- GC: one dense one-hot z-reduction for all frames, rows and both
@@ -194,13 +261,15 @@ def _pipeline_step(
     ohz = jnp.where(eq, msk[:, :, None, :], 0.0)
     both = jnp.stack(
         [ohz, jnp.where(eq, (px * msk)[:, :, None, :], 0.0)], axis=1
-    ).astype(sdt)  # (bt, 2, r, gz, w) — storage dtype (the dominant stack)
+    ).astype(col_oh.dtype)  # (bt, 2, r, gz, w) — GC dtype (the dominant stack)
     zgi = jnp.einsum(
         "bcizw,wg->bcizg", both, col_oh,
         preferred_element_type=jnp.float32,
-        # fp32 storage: HIGHEST keeps fp32 intensities exact on the MXU;
-        # bf16 operands are exact at the default (and refuse HIGHEST)
-        precision=jax.lax.Precision.HIGHEST if sdt == jnp.float32 else None,
+        # fp32 operands: HIGHEST keeps fractional intensities exact on the
+        # MXU; bf16 operands (8-bit levels, bf16 storage) are exact in the
+        # one default pass (and refuse HIGHEST)
+        precision=(jax.lax.Precision.HIGHEST
+                   if col_oh.dtype == jnp.float32 else None),
     )  # one matmul, not four; fp32 accumulation
     contrib_cur = zgi[:, :, :split].sum(axis=2)  # (bt, 2, gz, gy) -> plane s
     contrib_next = zgi[:, :, split:].sum(axis=2)  # -> plane s+1
@@ -243,22 +312,13 @@ def _pipeline_step(
         + jnp.where((z0 + 1)[:, :, None, :] == zi2, 1.0, 0.0) * zfr[:, :, None, :]
     ).astype(sdt)  # (bt, r, gz, w) — storage dtype (the other big stack)
     # Both planes are rounded to the storage dtype (plane s-1 is read back
-    # from storage on the next step) but the stack itself is fp32: Mosaic
-    # cannot fold a packed bf16 (2, bt, gz, gy) into matmul rows when gz is
-    # not a multiple of 16, and a one-hot gather of bf16 values is exact in
-    # fp32, so this keeps the bf16 numerics bit for bit.
+    # from storage on the next step): under bf16 storage each value is one
+    # bf16 part, under fp32 it takes three (see ti_gather).
     planes = jnp.stack(
         [b1_s[...].astype(jnp.float32), b_new.astype(sdt).astype(jnp.float32)],
         axis=0,
     )  # (2, bt, gz, gy)
-    # all four y-corner gathers in one contraction over gy (minor on both
-    # operands: no transposition of the planes). HIGHEST keeps the fp32
-    # plane values exact on the MXU (the default would round them to bf16).
-    gathered = jnp.einsum(
-        "pbzg,cwg->pbzcw", planes, y_oh,
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )  # (2, bt, gz, 2, w)
+    gathered = ti_gather(planes, y_oh, 1 if sdt == jnp.bfloat16 else 3)
     # fold the x/y lerp weights before the z contraction (linearity)
     wy = gathered[:, :, :, 0] * (1.0 - yf) + gathered[:, :, :, 1] * yf
     q = (
@@ -460,10 +520,15 @@ def bg_fused_impl(
     b == 1 batch (same kernel, bit-identical output). Matches ref.ref_fused
     per frame (paper normalization, unquantized).
 
+    ``image`` may be any real dtype; it is widened to fp32 here, under
+    ``bg.relayout``. Frames of an 8-bit integer dtype (``is_level_dtype``)
+    run GC's contraction as one bf16 MXU pass, with the same bits as the
+    same values given as float32 (module docstring).
+
     ``precision="bf16"`` flips every storage surface — padded input, mask,
     the six scratch buffers, the DMA stripe slots, the temporal carry blocks
     and both outputs — to bfloat16 while the compute body accumulates fp32
-    (see ``_pipeline_step``); the fp32 path's jaxpr is unchanged.
+    (see ``_pipeline_step``).
 
     ``batch_tile`` caps frames per grid step (clamped to b; default
     ``DEFAULT_BATCH_TILE``). Batches not divisible by the tile are padded
@@ -538,13 +603,17 @@ def bg_fused_impl(
     )
     in_stripe = lambda s: jnp.minimum(s, n - 1)  # GC reads stripe s
     out_stripe = lambda s: jnp.clip(s - 2, 0, n - 1)  # TI writes stripe s-2
-    # the GC one-hot travels in the storage dtype (its 0/1 entries are exact
-    # in bf16); the TI one-hots meet fp32 planes and the lerp fractions stay
-    # fp32. Every constant is built at the rank the kernel uses it at.
+    # the GC one-hot's dtype is GC's operand dtype, and so tells the kernel
+    # which it is: bf16 for 8-bit levels (decided here, from the frames'
+    # dtype before the widening above) and under bf16 storage, else fp32.
+    # The TI one-hots are bf16 (ti_gather); the lerp fractions stay fp32.
+    # Every 0/1 entry is exact in bf16. Every constant is built at the rank
+    # the kernel uses it at.
+    gc_dtype = jnp.bfloat16 if is_level_dtype(image.dtype) else sdt
     with relayout():
         consts = (
-            jnp.asarray(gc_col_onehot(w, gy, r)).astype(sdt),
-            jnp.asarray(np.stack([oh0, oh1])),
+            jnp.asarray(gc_col_onehot(w, gy, r)).astype(gc_dtype),
+            jnp.asarray(np.stack([oh0, oh1])).astype(jnp.bfloat16),
             jnp.asarray(yf)[None],
             jnp.asarray((np.arange(r) / r).astype(np.float32)).reshape(1, r, 1, 1),
         )

@@ -1078,6 +1078,9 @@ def _plan_executable(plan: BGPlan):
     ``bg.relayout`` (cast, pad, reshape and carry transpose before the
     kernel), ``bg.kernel``, ``bg.trim`` (padding cut, carry layout back) and
     ``bg.quantize``, so a profiler trace names every device operation.
+    They take the frames in their own dtype: the kernel sees 8-bit frames
+    as levels and runs grid creation in one bf16 pass
+    (``repro.kernels.bg_fused.is_level_dtype``).
     """
     fn = _executable_fn(plan)
     fn.__name__ = fn.__qualname__ = (
@@ -1098,10 +1101,6 @@ def _executable_fn(plan: BGPlan):
         with jax.named_scope("bg.quantize"):
             out = out.astype(jnp.float32)
             return quantize_intensity(out, cfg) if quant else out
-
-    def _as_f32(frames):
-        with jax.named_scope("bg.relayout"):
-            return frames.astype(jnp.float32)
 
     # ------------------------------------------------------------- temporal
     if plan.temporal:
@@ -1150,7 +1149,7 @@ def _executable_fn(plan: BGPlan):
         if mesh is None:
 
             def fn(frames, carry, alpha):
-                out, new_carry = inner_temporal(_as_f32(frames), carry, alpha)
+                out, new_carry = inner_temporal(frames, carry, alpha)
                 return _maybe_quantize(out), new_carry
 
             return fn
@@ -1158,7 +1157,7 @@ def _executable_fn(plan: BGPlan):
         meshed = _shard_map(inner_temporal, mesh, n_in=3, n_out=2)
 
         def fn(frames, carry, alpha):
-            out, new_carry = meshed(_as_f32(frames), carry, alpha)
+            out, new_carry = meshed(frames, carry, alpha)
             return _maybe_quantize(out), new_carry
 
         return fn
@@ -1235,14 +1234,14 @@ def _executable_fn(plan: BGPlan):
     if mesh is None:
 
         def fn(frames):
-            return _maybe_quantize(inner(_as_f32(frames)))
+            return _maybe_quantize(inner(frames))
 
         return fn
 
     meshed = _shard_map(inner, mesh, n_in=1, n_out=1)
 
     def fn(frames):
-        return _maybe_quantize(meshed(_as_f32(frames)))
+        return _maybe_quantize(meshed(frames))
 
     return fn
 

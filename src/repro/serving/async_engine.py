@@ -139,6 +139,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.bilateral_grid import BGConfig
+from repro.kernels.bg_fused import is_level_dtype
 from repro.reliability import (
     DeadlineExceeded,
     DispatchGuard,
@@ -151,7 +152,7 @@ from repro.reliability import (
     validate_frame,
 )
 from repro.serving.device_stages import (
-    ingest_rows, place_rows, split_rows, split_shards, stack_rows,
+    ingest_rows, place_rows, row_dtype, split_rows, split_shards, stack_rows,
 )
 
 __all__ = ["AsyncFrameEngine", "AsyncFrameRequest", "EngineStats", "StageRecord"]
@@ -195,6 +196,10 @@ class EngineStats:
     plan's ragged micro-batches (``device_stages.place_rows``): device work
     that answers no request.
 
+    ``level_dispatches`` — launches (engine or packer) whose batch reached
+    the plan as 8-bit levels (``repro.kernels.bg_fused.is_level_dtype``),
+    so that a fused plan ran grid creation in one bf16 MXU pass.
+
     ``stats["key"]`` indexing is kept as a legacy shim for the former dict
     form; prefer attribute access. ``as_dict()`` feeds exporters (the
     ``BENCH_<ts>.json`` snapshot rows in benchmarks/bench_video_stream.py).
@@ -227,6 +232,7 @@ class EngineStats:
     reconnects: int = 0
     host_casts: int = 0
     mesh_pad_rows: int = 0
+    level_dispatches: int = 0
     latency_samples: Tuple[float, ...] = ()
 
     def __getitem__(self, key: str):
@@ -292,6 +298,7 @@ class EngineStats:
             reconnects=sum(p.reconnects for p in parts),
             host_casts=sum(p.host_casts for p in parts),
             mesh_pad_rows=sum(p.mesh_pad_rows for p in parts),
+            level_dispatches=sum(p.level_dispatches for p in parts),
             latency_samples=tuple(samples),
         )
 
@@ -443,6 +450,7 @@ class AsyncFrameEngine:
         self._shed = 0
         self._host_casts = 0
         self._mesh_pad_rows = 0
+        self._level_dispatches = 0
         self._watchdog_trips = 0
 
         self._dispatcher = threading.Thread(
@@ -592,6 +600,8 @@ class AsyncFrameEngine:
                 host_casts=self._host_casts
                 + (getattr(self.packer, "host_casts", 0) or 0),
                 mesh_pad_rows=self._mesh_pad_rows,
+                level_dispatches=self._level_dispatches
+                + (getattr(self.packer, "level_dispatches", 0) or 0),
                 latency_samples=tuple(x * 1e3 for x in lat),
             )
 
@@ -743,9 +753,10 @@ class AsyncFrameEngine:
                 self._host_casts += casts
         n = len(batch)
         if plan.mesh is None:
-            stacked = stack_rows(rows, jnp.float32)
+            stacked = stack_rows(rows, row_dtype(rows))
             stacked = jax.device_put(stacked)  # overlap transfer with compute
             out = plan(stacked)
+            self._count_levels(stacked)
             guard = DispatchGuard(
                 out_ok=finite_rows(out) if self.output_guard else None
             )
@@ -757,8 +768,14 @@ class AsyncFrameEngine:
             with self._lock:
                 self._mesh_pad_rows += pad
         out = plan(placed)
+        self._count_levels(placed)
         guard = DispatchGuard(out_ok=finite_rows(out) if self.output_guard else None)
         return split_shards(out, n), guard
+
+    def _count_levels(self, batch) -> None:
+        if is_level_dtype(batch.dtype):
+            with self._lock:
+                self._level_dispatches += 1
 
     def _guarded_launch(self, batch: List[AsyncFrameRequest]):
         """One guarded dispatch: retries + fallback ladder + breakers.

@@ -6,21 +6,23 @@ the answer. Each of these is one jitted program whose operations run under a
 ``jax.named_scope``, so a profiler trace names them by stage instead of by
 the primitive JAX happened to dispatch:
 
-  ``bg.ingest`` -- :func:`stack_rows`: the per-frame widening to the plan's
-                   dtype and the stack (frames, and in video the per-stream
-                   carries)
+  ``bg.ingest`` -- :func:`stack_rows`: the stack of the frames in their
+                   common dtype (:func:`row_dtype`), and in video of the
+                   per-stream carries in the plan's storage dtype
   ``bg.split``  -- :func:`split_rows`: the per-request output rows
   ``bg.guard``  -- ``repro.reliability.finite_rows`` / ``carry_ok_rows``
 
 :func:`ingest_rows` decides, from the dtypes it sees, how a micro-batch's
 host frames reach :func:`stack_rows`. A host frame of a narrow dtype (an
 integer of at most 2 bytes, float16, bfloat16 or float32) crosses the host
-link as submitted and is widened on the device: an 8-bit frame moves 1 byte
-per pixel instead of 4. Wider dtypes (float64, integers of 4 or more bytes)
-and every host row of a batch whose host rows mix dtypes are converted to
-float32 on the host first, so a mixed batch compiles no program that a
-uniform one would not. Device arrays pass as they are. Every widening here
-is exact, so both paths give the same bits.
+link as submitted and is stacked in its own dtype: an 8-bit frame moves 1
+byte per pixel over the link and into the plan, which widens it (and runs
+grid creation in one bf16 pass for 8-bit levels, ``repro.kernels.bg_fused``).
+Wider dtypes (float64, integers of 4 or more bytes) and every host row of a
+batch whose host rows mix dtypes are converted to float32 on the host first,
+so a mixed batch compiles no program that a uniform one would not. Device
+arrays pass as they are. Every widening is exact, so both paths give the
+same bits.
 
 A mesh plan's micro-batch takes another road. :func:`place_rows` gives
 each shard of the batch its rows and puts them straight on the shard's
@@ -51,7 +53,10 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.sharding.bg_shard import row_pad
 
-__all__ = ["ingest_rows", "place_rows", "split_rows", "split_shards", "stack_rows"]
+__all__ = [
+    "ingest_rows", "place_rows", "row_dtype", "split_rows", "split_shards",
+    "stack_rows",
+]
 
 # host dtypes that travel to the device as submitted (besides integers of at
 # most 2 bytes); the rest are wider than the float32 they become
@@ -79,6 +84,13 @@ def ingest_rows(frames) -> Tuple[List, int]:
     for i in host:
         rows[i] = np.asarray(rows[i], np.float32)
     return rows, len(host)
+
+
+def row_dtype(rows):
+    """The dtype the rows of one micro-batch (from :func:`ingest_rows`) are
+    stacked in: their common dtype, which is their own unless device rows
+    of another dtype join them."""
+    return jnp.result_type(*(r.dtype for r in rows))
 
 
 @functools.partial(jax.jit, static_argnames=("dtype", "pad"))
@@ -115,7 +127,7 @@ def place_rows(rows, mesh) -> Tuple[jax.Array, int]:
     nd = int(mesh.devices.size)
     pad = row_pad(nd, len(rows))
     per = (len(rows) + pad) // nd
-    dtype = jnp.result_type(*(r.dtype for r in rows))
+    dtype = row_dtype(rows)
     shape = (per * nd,) + tuple(rows[0].shape)
     sharding = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
     starts = {d: i[0].start or 0

@@ -117,6 +117,7 @@ class MultiStreamPacker:
         self.carry_resets = 0    # lifetime count of quarantined carries
         self.carry_restores = 0  # lifetime count of snapshot-restored carries
         self.host_casts = 0      # lifetime count of frames converted on the host
+        self.level_dispatches = 0  # lifetime count of packs stacked as 8-bit levels
 
     @property
     def cfg(self) -> BGConfig:
@@ -264,7 +265,10 @@ class MultiStreamPacker:
             carry_ok_rows,
             finite_rows,
         )
-        from repro.serving.device_stages import ingest_rows, split_rows, stack_rows
+        from repro.kernels.bg_fused import is_level_dtype
+        from repro.serving.device_stages import (
+            ingest_rows, row_dtype, split_rows, stack_rows,
+        )
 
         if carry_limit is None:
             carry_limit = DEFAULT_CARRY_LIMIT
@@ -280,7 +284,7 @@ class MultiStreamPacker:
         if len(shapes) != 1 or len(next(iter(shapes))) != 2:
             raise ValueError(f"pack needs equal (h, w) frames, got {sorted(shapes)}")
         sessions = {s: self.sessions[s] for s in sids}
-        batch = stack_rows(rows, jnp.float32)
+        batch = stack_rows(rows, row_dtype(rows))
         warm = [s for s in sids if sessions[s].alpha > 0.0]
         results = {}
         # the packer asks the plan for this pack's tile (the plan's own
@@ -334,6 +338,8 @@ class MultiStreamPacker:
             carry_ok = carry_ok_rows(warm_carry, carry_limit)
         for s in sids:
             sessions[s].frames_seen += 1
+        if is_level_dtype(batch.dtype):
+            self.level_dispatches += 1
         guard = DispatchGuard(
             out_ok=finite_rows(out),
             order=tuple(sids),

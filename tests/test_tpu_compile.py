@@ -84,16 +84,25 @@ def _compile(plan, n, hw, sharding, dtype=jnp.float32):
         # 4K UHD at the largest radius, streamed, bf16 storage
         ("uhd_r16_streamed_bf16", UHD, 16, "bf16", {"stream_input": True},
          "fused_streamed"),
+        # the paper cells' plan on 8-bit frames as the engine stacks them:
+        # grid creation on bf16 operands in one MXU pass ("frames" is the
+        # frames' dtype, not a plan_for argument)
+        ("paper_streamed_bt3_uint8", HD, 12, "fp32",
+         {"batch_tile": 3, "frames": jnp.uint8}, "fused_streamed"),
     ],
 )
 def test_frame_plan_compiles_for_v5e(one_chip, name, hw, r, precision, kw,
                                      backend):
+    kw = dict(kw)
+    dtype = kw.pop("frames", jnp.float32)
     plan = plan_for(
         _cfg(r), *hw, n_frames=8, precision=precision, interpret=False,
         sharded=False, cache=False, **kw,
     )
     assert plan.backend == backend and plan.precision == precision
-    _compile(plan, 8, hw, one_chip)
+    if "batch_tile" in kw:
+        assert plan.batch_tile == kw["batch_tile"]
+    _compile(plan, 8, hw, one_chip, dtype)
 
 
 def test_temporal_plan_compiles_for_v5e(one_chip):
@@ -104,6 +113,17 @@ def test_temporal_plan_compiles_for_v5e(one_chip):
     )
     assert plan.temporal and plan.batch_tile == 4
     _compile(plan, 4, HD, one_chip)
+
+
+def test_temporal_level_plan_compiles_for_v5e(one_chip):
+    """The video cell's plan (temporal, three streams per tile) on 8-bit
+    frames: grid creation on bf16 operands beside the grid EMA."""
+    plan = plan_for(
+        _cfg(12), *HD, n_frames=3, temporal=True, batch_tile=3,
+        interpret=False, sharded=False, cache=False,
+    )
+    assert plan.temporal and plan.batch_tile == 3
+    _compile(plan, 3, HD, one_chip, jnp.uint8)
 
 
 @pytest.mark.parametrize(
