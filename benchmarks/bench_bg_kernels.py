@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import BGConfig, add_gaussian_noise, grid_shape, synthetic_image
-from repro.kernels import bilateral_grid_filter_pallas
 from repro.launch.hlo_analysis import TARGET_KIND, chip_peaks
+from repro.plan import BGPlan
 
 HBM_BW = chip_peaks(TARGET_KIND)["hbm_bw"]
 PEAK = chip_peaks(TARGET_KIND)["peak_flops"]
@@ -60,10 +60,11 @@ def run(quick: bool = False):
     noisy = add_gaussian_noise(synthetic_image(h, w), 30.0)
     cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
     for fused in (False, True):
-        out = bilateral_grid_filter_pallas(noisy, cfg, fused=fused)
+        plan = BGPlan(cfg=cfg, backend="fused" if fused else "staged")
+        out = plan(noisy)
         jax.block_until_ready(out)
         t0 = time.perf_counter()
-        out = bilateral_grid_filter_pallas(noisy, cfg, fused=fused)
+        out = plan(noisy)
         jax.block_until_ready(out)
         dt = time.perf_counter() - t0
         rows.append(

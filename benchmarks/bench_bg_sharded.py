@@ -1,6 +1,6 @@
 """Frames/sec scaling vs device count for the sharded fused BG pipeline.
 
-The service path (`repro.sharding.bg_shard.bg_denoise_sharded`) shards the
+A mesh-carrying fused ``repro.plan.BGPlan`` (the service path) shards the
 batch axis of the fused kernel over a 1-D mesh with zero collectives, so on
 real hardware frames/sec should scale ~linearly with device count. This bench
 measures that curve on a *forced 8-device host mesh*
@@ -51,7 +51,8 @@ def measure(h, w, r, b, reps, counts):
     import jax
 
     from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
-    from repro.sharding.bg_shard import batch_mesh, bg_denoise_sharded
+    from repro.plan import BGPlan
+    from repro.sharding.bg_shard import batch_mesh
 
     cfg = BGConfig(r=r, sigma_s=4.0, sigma_r=60.0)
     noisy = add_gaussian_noise(synthetic_batch(b, h, w, seed=0), 30.0, seed=1)
@@ -59,10 +60,10 @@ def measure(h, w, r, b, reps, counts):
     for nd in counts:
         if nd > jax.device_count():
             continue
-        mesh = batch_mesh(nd)
+        plan = BGPlan(cfg=cfg, mesh=batch_mesh(nd), quantize_output=False)
 
         def call():
-            jax.block_until_ready(bg_denoise_sharded(noisy, cfg, mesh=mesh))
+            jax.block_until_ready(plan(noisy))
 
         call()  # warm-up / compile
         ts = []

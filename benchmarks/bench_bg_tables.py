@@ -35,7 +35,6 @@ from repro.core import (
     bilateral_filter,
     bilateral_grid_filter,
     bilateral_grid_filter_fixed,
-    bilateral_grid_filter_streaming,
     grid_shape,
     synthetic_batch,
     synthetic_image,
@@ -118,7 +117,7 @@ def _tuned_vs_default_rows():
 
 
 def run(quick: bool = False):
-    from repro.plan import plan_for
+    from repro.plan import BGPlan, plan_for
 
     rows = []
     # ---------------- Table I: r sweep at full HD, through the tuned plan
@@ -159,10 +158,11 @@ def run(quick: bool = False):
     r, ss, sr = 12, 8.0, 70.0
     cfg = BGConfig(r=r, sigma_s=ss, sigma_r=sr)
     cfg_p2 = BGConfig(r=r, sigma_s=ss, sigma_r=sr, weight_mode="pow2")
+    streaming = BGPlan(cfg=cfg, backend="streaming")
     impls = {
         "bf_exact": lambda: bilateral_filter(noisy2, r, ss, sr),
         "bg": lambda: bilateral_grid_filter(noisy2, cfg),
-        "bg_streaming": lambda: bilateral_grid_filter_streaming(noisy2, cfg),
+        "bg_streaming": lambda: streaming(noisy2),
         "bg_fixed_pow2": lambda: bilateral_grid_filter_fixed(noisy2, cfg_p2),
     }
     base = None

@@ -1,28 +1,20 @@
-"""Multi-stream video serving: async vs sync engine sustained frames/sec.
+"""Multi-stream video serving: the async engine's sustained frames/sec.
 
 The deployment shape for the paper's real-time denoiser is N concurrent
 video streams, each delivering frames that must come back denoised — so the
 figure of merit is sustained service throughput plus the request-latency
-tail, not single-dispatch time. This bench drives the same frame traffic
-(round-robin over N streams) through both serving fronts:
+tail, not single-dispatch time. This bench drives frame traffic
+(round-robin over N streams) through ``AsyncFrameEngine``: bounded-queue
+submission with futures; the dispatch thread stacks/transfers batch N+1
+while batch N computes and the completion thread realizes batch N-1
+(double-buffered feeding), so host-side work hides behind device compute.
 
-  * ``sync_engine``  — ``FrameDenoiseEngine``: the caller's thread stacks,
-    dispatches, and realizes each micro-batch's results before accepting
-    more frames (what a synchronous service loop does).
-  * ``async_engine`` — ``AsyncFrameEngine``: bounded-queue submission with
-    futures; the dispatch thread stacks/transfers batch N+1 while batch N
-    computes and the completion thread realizes batch N-1 (double-buffered
-    feeding), so host-side work hides behind device compute.
-
-Both realize every result to host memory (a service must). The async engine
-additionally reports p50/p99 request latency from its telemetry, and the
-end-of-run ``stats()`` dict is exported as ``bg_video/stats_*`` rows so the
-serving telemetry lands in the ``BENCH_<ts>.json`` perf trajectory instead
-of evaporating with the process. The ``ratio/bg_async_vs_sync_engine`` row
-gates the PR-3 claim on any machine: the async pipeline must sustain at
-least the synchronous engine's throughput (floor 1.0; measured ~1.3-1.9x on
-CPU hosts, where stacking and result realization are a large fraction of
-the interpret-mode batch cycle).
+Every result is realized to host memory (a service must) and checked
+against the plan's own answer on the stacked frames. The engine reports
+p50/p99 request latency from its telemetry, and the end-of-run ``stats()``
+dict is exported as ``bg_video/stats_*`` rows so the serving telemetry
+lands in the ``BENCH_<ts>.json`` perf trajectory instead of evaporating
+with the process.
 
 The ``ratio/bg_temporal_fused_vs_staged`` row gates the PR-4 warm path: one
 warm multi-stream pack dispatched through the fused temporal kernel (the
@@ -42,18 +34,10 @@ import numpy as np
 
 from repro.core import BGConfig, add_gaussian_noise
 from repro.data import synthetic_video
-from repro.serving import (
-    AsyncFrameEngine,
-    EngineStats,
-    FrameDenoiseEngine,
-    FrameRequest,
-)
+from repro.plan import BGPlan
+from repro.serving import AsyncFrameEngine, EngineStats
 from repro.video import MultiStreamPacker, temporal_denoise
 
-# Async >= sync is the PR-3 acceptance floor; the async engine's measured
-# edge comes from hiding host stacking + result realization behind compute,
-# which holds on any host (both sides timed in the same process).
-ASYNC_VS_SYNC_FLOOR = 1.0
 REPS_QUICK, REPS_FULL = 3, 5
 TEMPORAL_ALPHA = 0.6
 # Fused-temporal >= 2x the staged oracle on the same warm pack is the PR-4
@@ -82,22 +66,9 @@ def _traffic(n_streams, frames_per_stream, h, w):
     return arrivals
 
 
-def _run_sync(cfg, arrivals, max_batch):
-    eng = FrameDenoiseEngine(cfg, max_batch=max_batch)
-    t0 = time.perf_counter()
-    outs = []
-    for i, (_, frame) in enumerate(arrivals):
-        eng.submit(FrameRequest(uid=i, frame=frame))
-        for r in eng.step():
-            outs.append(np.asarray(r.result))  # the service realizes results
-    for r in eng.flush():
-        outs.append(np.asarray(r.result))
-    return time.perf_counter() - t0, outs
-
-
-def _run_async(cfg, arrivals, max_batch, packer=None):
+def _run_async(arrivals, max_batch, *, plan=None, packer=None):
     eng = AsyncFrameEngine(
-        cfg, max_batch=max_batch, batch_window_ms=50.0, packer=packer
+        plan=plan, packer=packer, max_batch=max_batch, batch_window_ms=50.0
     )
     t0 = time.perf_counter()
     futs = [
@@ -122,51 +93,37 @@ def run(quick: bool = False):
     frames_per_stream = 16 if quick else 12
     reps = REPS_QUICK if quick else REPS_FULL
     cfg = BGConfig(r=r, sigma_s=4.0, sigma_r=60.0)
+    plan = BGPlan(cfg=cfg)
     arrivals = _traffic(n_streams, frames_per_stream, h, w)
     n = len(arrivals)
     # micro-batch spans two stream rounds: per-dispatch handoff overhead
-    # (thread wakeups, queue hops) amortizes over more frames, for both
-    # engines equally. The temporal pack below is capped at one frame per
-    # stream by construction, so it keeps max_batch == n_streams.
+    # (thread wakeups, queue hops) amortizes over more frames. The temporal
+    # pack below is capped at one frame per stream by construction, so it
+    # keeps max_batch == n_streams.
     mb = min(2 * n_streams, n)
 
-    # warm-up compiles for every dispatch shape both engines will hit
-    _run_sync(cfg, arrivals, mb)
-    _, outs_async, _ = _run_async(cfg, arrivals, mb)
+    # warm-up compiles for the dispatch shapes the engine will hit
+    _run_async(arrivals, mb, plan=plan)
 
-    # interleaved best-of-reps (same robustness rationale as bench_bg_throughput)
-    t_sync, t_async = [], []
-    for _ in range(reps):
-        dt, outs_sync = _run_sync(cfg, arrivals, mb)
-        t_sync.append(dt)
-        dt, outs_async, stats = _run_async(cfg, arrivals, mb)
+    t_async = []
+    for _ in range(reps):  # best-of-reps, as in bench_bg_throughput
+        dt, outs_async, stats = _run_async(arrivals, mb, plan=plan)
         t_async.append(dt)
-    for a, b in zip(outs_sync, outs_async):
-        np.testing.assert_array_equal(a, b)  # same frames, same results
+    # the oracle: the plan's own answer on the stacked frames
+    ref = np.asarray(plan(np.stack([frame for _, frame in arrivals])))
+    for i, out in enumerate(outs_async):
+        np.testing.assert_array_equal(out, ref[i])
 
     stats_plain = stats  # last per-frame async engine snapshot (merged below)
-    fps_sync = n / min(t_sync)
     fps_async = n / min(t_async)
     tag = f"s{n_streams}_f{frames_per_stream}_{h}x{w}"
     rows = [
-        (
-            f"bg_video/sync_engine_{tag}",
-            min(t_sync) / n * 1e6,
-            f"fps={fps_sync:.0f}",
-        ),
         (
             f"bg_video/async_engine_{tag}",
             min(t_async) / n * 1e6,
             f"fps={fps_async:.0f} p50={stats.latency_ms_p50:.1f}ms "
             f"p99={stats.latency_ms_p99:.1f}ms "
             f"mean_batch={stats.mean_batch:.1f}",
-        ),
-        (
-            "ratio/bg_async_vs_sync_engine",
-            fps_async / fps_sync,
-            f"floor={ASYNC_VS_SYNC_FLOOR} async/sync sustained fps at "
-            f"{n_streams} streams {h}x{w} (double-buffered feeding vs "
-            f"per-batch blocking)",
         ),
     ]
 
@@ -184,8 +141,8 @@ def run(quick: bool = False):
     packer = MultiStreamPacker(plan=temporal_plan)
     for s in range(n_streams):
         packer.open(s, alpha=TEMPORAL_ALPHA)
-    _run_async(cfg, arrivals, n_streams, packer=packer)  # warm-up
-    dt, _, stats = _run_async(cfg, arrivals, n_streams, packer=packer)
+    _run_async(arrivals, n_streams, packer=packer)  # warm-up
+    dt, _, stats = _run_async(arrivals, n_streams, packer=packer)
     rows.append(
         (
             f"bg_video/async_temporal_a{TEMPORAL_ALPHA:g}_{tag}",

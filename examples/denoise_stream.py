@@ -28,7 +28,6 @@ from repro.core import (
     mssim,
     synthetic_batch,
 )
-from repro.kernels import bilateral_grid_filter_pallas
 from repro.plan import plan_for
 
 
@@ -58,13 +57,13 @@ def main():
     jax.block_until_ready(out_b)
     dt_batch = time.perf_counter() - t0
 
-    # looped single-frame baseline
+    # looped single-frame baseline: the same plan, one frame per dispatch
     for i in range(n_frames):
-        jax.block_until_ready(bilateral_grid_filter_pallas(noisy[i], cfg))
+        jax.block_until_ready(plan(noisy[i]))
     t0 = time.perf_counter()
     out_loop = []
     for i in range(n_frames):
-        out_loop.append(bilateral_grid_filter_pallas(noisy[i], cfg))
+        out_loop.append(plan(noisy[i]))
     jax.block_until_ready(out_loop)
     dt_loop = time.perf_counter() - t0
 

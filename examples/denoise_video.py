@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core import BGConfig, add_gaussian_noise, psnr
 from repro.data import synthetic_video
+from repro.plan import BGPlan, plan_for
 from repro.serving import AsyncFrameEngine
 from repro.video import MultiStreamPacker, temporal_denoise
 
@@ -33,6 +34,7 @@ H, W = 96, 128
 
 def main():
     cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
+    fused = BGPlan(cfg=cfg, backend="fused")
 
     # ---- 1. temporal accumulation on a static scene --------------------
     clean = synthetic_video(0, 1, H, W, motion=0.0)[0]
@@ -42,7 +44,7 @@ def main():
     print(f"static {H}x{W} scene, sigma=30 noise, {N_FRAMES} frames:")
     print(f"  noisy input:            psnr {float(psnr(clean, noisy[-1])):6.2f} dB")
     for alpha in (0.0, 0.3, 0.6, 0.8):
-        packer = MultiStreamPacker(cfg)
+        packer = MultiStreamPacker(fused)
         packer.open("cam", alpha=alpha)
         for t in range(N_FRAMES):
             out = packer.pack({"cam": noisy[t]})["cam"]
@@ -51,11 +53,9 @@ def main():
         )
 
     # ---- 2. a == 0 is the per-frame fused path, bit-identical ----------
-    from repro.plan import BGPlan
-
     frame = noisy[0]
-    out_t, carry = temporal_denoise(frame, cfg, alpha=0.0)
-    ref = BGPlan(cfg=cfg, backend="fused")(frame)
+    out_t, carry = temporal_denoise(frame, plan=fused, alpha=0.0)
+    ref = fused(frame)
     assert carry is None and bool(np.all(np.asarray(out_t) == np.asarray(ref)))
     print("alpha=0 output bit-identical to the per-frame fused path: True")
 
@@ -71,8 +71,6 @@ def main():
 
     # plan-driven dispatch: plan_for auto-tunes the fused-kernel batch tile
     # for the pack geometry; the packer asks the plan for its tile
-    from repro.plan import plan_for
-
     video_plan = plan_for(cfg, H, W, n_frames=n_streams, temporal=True)
     print(f"video plan: backend={video_plan.backend} "
           f"batch_tile={video_plan.batch_tile}")
@@ -85,13 +83,13 @@ def main():
 
     # warm-up compile through a throwaway engine so the timed engine's
     # latency telemetry and temporal stream state start clean
-    with AsyncFrameEngine(cfg, max_batch=n_streams, packer=fresh_packer()) as warm:
+    with AsyncFrameEngine(packer=fresh_packer(), max_batch=n_streams) as warm:
         for s in range(n_streams):
             warm.submit(traffic[s][0], stream_id=s)
         warm.flush()
 
     with AsyncFrameEngine(
-        cfg, max_batch=n_streams, batch_window_ms=20.0, packer=fresh_packer()
+        packer=fresh_packer(), max_batch=n_streams, batch_window_ms=20.0
     ) as eng:
         t0 = time.perf_counter()
         futs = [

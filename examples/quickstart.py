@@ -20,8 +20,7 @@ from repro.core import (
     psnr,
     synthetic_image,
 )
-from repro.kernels import bilateral_grid_filter_pallas
-from repro.plan import plan_for
+from repro.plan import BGPlan, plan_for
 
 
 def main():
@@ -41,7 +40,7 @@ def main():
         "BG pow2/shift-only": bilateral_grid_filter_fixed(
             noisy, BGConfig(r=7, sigma_s=4.0, sigma_r=50.0, weight_mode="pow2")
         ),
-        "BG fused Pallas kernel": bilateral_grid_filter_pallas(noisy, cfg),
+        "BG fused Pallas kernel": BGPlan(cfg=cfg, backend="fused")(noisy),
         "BG compiled plan (auto-tuned)": plan(noisy),
     }
     print(f"{'variant':34s} {'MSSIM':>8s} {'PSNR':>8s}")

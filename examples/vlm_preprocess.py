@@ -13,6 +13,7 @@ from repro.configs.registry import get_smoke_config
 from repro.core import BGConfig, add_gaussian_noise, mssim, synthetic_image
 from repro.data import vlm_preprocess
 from repro.models import forward, init_params
+from repro.plan import plan_for
 
 
 def main():
@@ -28,13 +29,11 @@ def main():
 
     # the denoiser dispatch is a compiled plan: fused Pallas kernel with an
     # auto-tuned batch tile (and mesh sharding on a multi-device host)
-    from repro.plan import plan_for
-
     bg_plan = plan_for(bg, h, w, n_frames=B)
 
-    ctx_noisy = vlm_preprocess(noisy, bg, patch, cfg.d_model, denoise=False)
-    ctx_clean = vlm_preprocess(clean, bg, patch, cfg.d_model, denoise=False)
-    ctx_denoised = vlm_preprocess(noisy, bg, patch, cfg.d_model, plan=bg_plan)
+    ctx_noisy = vlm_preprocess(noisy, None, patch, cfg.d_model)
+    ctx_clean = vlm_preprocess(clean, None, patch, cfg.d_model)
+    ctx_denoised = vlm_preprocess(noisy, bg_plan, patch, cfg.d_model)
     # denoising must pull patch embeddings toward the clean ones
     d_noisy = float(jnp.mean(jnp.abs(ctx_noisy - ctx_clean)))
     d_denoised = float(jnp.mean(jnp.abs(ctx_denoised - ctx_clean)))

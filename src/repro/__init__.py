@@ -3,12 +3,11 @@
 
 Dispatch-decision table (the plan layer, ``repro.plan``)
 --------------------------------------------------------
-Every bilateral-grid entry point (``data.pipeline.denoise_batch``,
-``video.temporal.temporal_denoise``, both frame-serving engines, the video
-packer, ``launch.serve``) executes a compiled :class:`repro.plan.BGPlan`;
-legacy per-call kwargs (``use_kernels``/``sharded``/``mesh``/``stream_input``
-/``batch_tile``/``interpret``/``staged``) are deprecation-shimmed onto an
-equivalent plan, bit-identically. Which backend fires for which geometry:
+Every bilateral-grid dispatch takes a compiled :class:`repro.plan.BGPlan`
+and calls it: the frame engine (``serving.AsyncFrameEngine(plan=)``), the
+video packer and ``video.temporal.temporal_denoise``, the data-pipeline
+frontends, the fleet and ``launch.serve``; a caller denoises a stack of
+frames with ``plan(frames)``. Which backend fires for which geometry:
 
   geometry / intent                     backend (plan_for auto-selection)
   -----------------------------------   ---------------------------------

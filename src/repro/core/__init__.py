@@ -20,14 +20,12 @@ from .noise import (
     synthetic_batch,
     synthetic_image,
 )
-from .streaming import bilateral_grid_filter_streaming
 
 __all__ = [
     "BGConfig",
     "conv3_axis",
     "bilateral_grid_filter",
     "bilateral_grid_filter_fixed",
-    "bilateral_grid_filter_streaming",
     "bilateral_filter",
     "gaussian_blur",
     "gaussian_taps",

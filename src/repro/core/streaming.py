@@ -6,8 +6,11 @@ planes, two blurred planes, and an r-line buffer. This module reproduces that
 dataflow as a ``lax.scan`` whose carry is exactly that working set, so peak
 memory is O(gy*gz + r*w) instead of O(h*w + gx*gy*gz).
 
-Equivalence with the whole-image path is exact (same arithmetic order per
-plane) and asserted in tests.
+It is the ``"streaming"`` backend of :class:`repro.plan.BGPlan`: a plan
+with that backend runs :func:`_streaming_single` per frame (vmapped over a
+batch, and sharded over the plan's batch mesh if it has one). Equivalence
+with the whole-image path is exact (same arithmetic order per plane) and
+asserted in tests.
 
 Key regularity (the paper's counter logic): for a stripe starting at row s*r,
 round((s*r + i)/r) - s = round(i/r) and floor((s*r + i)/r) - s = 0 for
@@ -31,51 +34,6 @@ from .bilateral_grid import (
     grid_shape,
     quantize_intensity,
 )
-
-__all__ = ["bilateral_grid_filter_streaming"]
-
-
-def bilateral_grid_filter_streaming(
-    image: jnp.ndarray,
-    cfg: BGConfig | None = None,
-    quantize_output: bool = True,
-    sharded: bool = False,
-    mesh=None,
-    *,
-    plan=None,
-) -> jnp.ndarray:
-    """Stripe-streaming BG; numerically equivalent to bilateral_grid_filter.
-
-    Accepts a single (h, w) frame or a (b, h, w) batch; batches are vmapped
-    over the scan (the per-frame working set stays O(grid planes + r lines),
-    so b frames stream in parallel with a b x working-set footprint).
-
-    Preferred form: pass a ``repro.plan.BGPlan`` with ``backend="streaming"``
-    via ``plan=``. Legacy ``sharded=True`` shards the batch axis of the
-    vmapped scan over ``mesh`` (default: a 1-D mesh over all local devices) —
-    frames are independent, so this is the same collective-free data
-    parallelism as ``repro.sharding.bg_shard``, just over the jnp scan
-    instead of the Pallas kernel. Falls back to the plain call on a single
-    device.
-    """
-    from repro.plan import BGPlan, warn_legacy_dispatch
-
-    if plan is None:
-        if cfg is None:
-            raise TypeError("bilateral_grid_filter_streaming needs cfg= or plan=")
-        if sharded or mesh is not None:
-            warn_legacy_dispatch("bilateral_grid_filter_streaming")
-        if sharded and mesh is None and jax.device_count() > 1:
-            from repro.sharding.bg_shard import batch_mesh
-
-            mesh = batch_mesh()
-        plan = BGPlan(
-            cfg=cfg,
-            backend="streaming",
-            mesh=mesh if sharded else None,
-            quantize_output=quantize_output,
-        )
-    return plan(image)
 
 
 def _streaming_single(

@@ -5,4 +5,4 @@ from .synthetic import (
     synthetic_video,
     vision_context,
 )
-from .pipeline import denoise_batch, patchify_embed, spectrogram_denoise, vlm_preprocess
+from .pipeline import patchify_embed, spectrogram_denoise, vlm_preprocess

@@ -3,12 +3,10 @@
 This is where the paper's contribution plugs into the LM framework
 (DESIGN.md §Arch-applicability): the [vlm] image frontend and the [audio]
 spectrogram frontend both run bilateral-grid denoising before patch/frame
-embedding. Every stage dispatches through the plan layer (``repro.plan``):
-pass a compiled :class:`repro.plan.BGPlan` via ``plan=`` to pick the backend
-(vmapped jnp reference, fused Pallas kernel, batch-axis device-sharded
-kernel, streamed input DMA), or keep using the legacy ``use_kernels=`` /
-``sharded=`` kwargs, which route into an equivalent plan — so the frontends
-ride the same hot path the serving engine does.
+embedding. Each stage takes a compiled :class:`repro.plan.BGPlan` and calls
+it, so the plan alone picks the backend (vmapped jnp reference, fused Pallas
+kernel, batch-axis device-sharded kernel, streamed input DMA) and the
+frontends ride the same hot path the serving engine does.
 """
 from __future__ import annotations
 
@@ -19,72 +17,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bilateral_grid import BGConfig
+from repro.plan import BGPlan
 
-__all__ = ["denoise_batch", "patchify_embed", "vlm_preprocess", "spectrogram_denoise"]
+__all__ = ["patchify_embed", "vlm_preprocess", "spectrogram_denoise"]
 
-
-def _legacy_plan(
-    cfg: BGConfig,
-    use_kernels: bool,
-    sharded: bool,
-    mesh,
-    stream_input: bool,
-    site: str,
-):
-    """Map the legacy kwarg ladder onto a BGPlan, preserving every pre-plan
-    dispatch decision exactly (reference <- default, fused <- use_kernels,
-    mesh <- sharded, batch_tile None <- the kernel default)."""
-    from repro.plan import BGPlan, warn_legacy_dispatch
-
-    if use_kernels or sharded or stream_input or mesh is not None:
-        warn_legacy_dispatch(site)
-    if sharded:
-        if mesh is None and jax.device_count() > 1:
-            from repro.sharding.bg_shard import batch_mesh
-
-            mesh = batch_mesh()
-        backend = "fused_streamed" if stream_input else "fused"
-        return BGPlan(cfg=cfg, backend=backend, mesh=mesh)
-    if use_kernels:
-        backend = "fused_streamed" if stream_input else "fused"
-        return BGPlan(cfg=cfg, backend=backend)
-    return BGPlan(cfg=cfg, backend="reference")
-
-
-def denoise_batch(
-    images: jnp.ndarray,
-    cfg: BGConfig | None = None,
-    use_kernels: bool = False,
-    sharded: bool = False,
-    mesh=None,
-    stream_input: bool = False,
-    *,
-    plan=None,
-) -> jnp.ndarray:
-    """(B, H, W) or color (B, H, W, 3) noisy [0,255] -> denoised batch.
-
-    Preferred form: ``denoise_batch(images, plan=plan)``. Legacy kwargs:
-    use_kernels=True feeds the whole batch to the fused Pallas macro-pipeline
-    in one dispatch (its native (batch, stripe) grid — constants shared, grid
-    in VMEM); the jnp reference path is vmapped per frame. sharded=True
-    additionally shards the batch axis over ``mesh`` (default: all local
-    devices; falls back to the single-device fused call on one device) and
-    implies the kernel path. ``stream_input`` selects the kernel's explicit
-    double-buffered HBM->VMEM input DMA.
-
-    Color frames are denoised per channel by folding the channel axis into
-    the batch axis before the dispatch — the grid stays per-channel (the
-    paper's grayscale pipeline), and channels of one frame may land on
-    different devices, which is fine because frames and channels are equally
-    independent.
-    """
-    if plan is None:
-        if cfg is None:
-            raise TypeError("denoise_batch needs cfg= or plan=")
-        plan = _legacy_plan(
-            cfg, use_kernels, sharded, mesh, stream_input, "denoise_batch"
-        )
-    return plan(images)
+# The [audio] stage's documented default: a small window over the
+# spectrogram's time/frequency axes, run by the jnp reference.
+SPECTROGRAM_PLAN = BGPlan(
+    cfg=BGConfig(r=4, sigma_s=2.0, sigma_r=40.0), backend="reference"
+)
 
 
 def patchify_embed(
@@ -108,56 +49,23 @@ def patchify_embed(
 
 
 def vlm_preprocess(
-    images: jnp.ndarray,
-    bg_cfg: BGConfig | None,
-    patch: int,
-    dim: int,
-    denoise: bool = True,
-    use_kernels: bool = False,
-    sharded: bool = False,
-    mesh=None,
-    *,
-    plan=None,
+    images: jnp.ndarray, plan: Optional[BGPlan], patch: int, dim: int
 ) -> jnp.ndarray:
     """Full [vlm] frontend stage: BG denoise -> patchify -> project.
 
-    ``plan=`` (or the legacy ``use_kernels``/``sharded`` kwargs) picks the
-    denoiser dispatch exactly as in :func:`denoise_batch` — the VLM frontend
-    rides the fused (and, on a multi-device host, sharded) kernel path rather
-    than being pinned to the vmapped reference.
+    ``plan`` denoises the ``(B, H, W)`` batch; ``None`` embeds the images
+    as they are.
     """
-    if denoise:
-        images = denoise_batch(
-            images,
-            bg_cfg,
-            use_kernels=use_kernels,
-            sharded=sharded,
-            mesh=mesh,
-            plan=plan,
-        )
+    if plan is not None:
+        images = plan(images)
     return patchify_embed(images, patch, dim)
 
 
-def spectrogram_denoise(
-    spec: jnp.ndarray,
-    bg_cfg: Optional[BGConfig] = None,
-    use_kernels: bool = False,
-    sharded: bool = False,
-    mesh=None,
-    *,
-    plan=None,
-):
-    """[audio] stage: treat a (B, T, F) spectrogram as images in [0,255].
-
-    Forwards ``plan=`` (or legacy ``use_kernels``/``sharded``) to
-    :func:`denoise_batch`.
-    """
-    if plan is None and bg_cfg is None:
-        bg_cfg = BGConfig(r=4, sigma_s=2.0, sigma_r=40.0)
+def spectrogram_denoise(spec: jnp.ndarray, plan: BGPlan = SPECTROGRAM_PLAN):
+    """[audio] stage: treat a (B, T, F) spectrogram as images in [0,255],
+    denoise them with ``plan`` and map the answer back to the input range."""
     lo = jnp.min(spec)
     hi = jnp.max(spec)
     scaled = (spec - lo) / jnp.maximum(hi - lo, 1e-9) * 255.0
-    den = denoise_batch(
-        scaled, bg_cfg, use_kernels=use_kernels, sharded=sharded, mesh=mesh, plan=plan
-    )
+    den = plan(scaled)
     return den / 255.0 * (hi - lo) + lo

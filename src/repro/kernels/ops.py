@@ -1,34 +1,21 @@
-"""Public entry points for the bilateral-grid Pallas kernels.
+"""Public names for the bilateral-grid Pallas kernels.
 
-`bilateral_grid_filter_pallas` is the production path: it chains the staged
-kernels (or the fused macro-pipeline kernel) and applies the paper's output
-quantization. Every op auto-selects interpret mode off-TPU.
-
-Dispatch now lives in the plan layer (``repro.plan``): this function routes
-its kwargs into a :class:`repro.plan.BGPlan` (or takes one via ``plan=``) and
-executes the plan's cached compiled callable. Batched throughput path: all
-entry points accept a single (h, w) frame or a (b, h, w) batch. The fused
-kernel consumes the batch natively through its 2-D (batch, stripe) grid —
-one dispatch, shared constants, grid in VMEM — while the staged kernels fall
-back to `vmap` over frames (they round-trip the grid through HBM anyway, so
-there is nothing to share).
+``bg_create`` / ``bg_blur`` / ``bg_slice`` are the three staged kernels (the
+grid round-trips through HBM between them) and ``bg_fused`` is the single
+GC||GF||TI macro-pipeline kernel. Every op auto-selects interpret mode
+off-TPU. A whole denoise is dispatched by a :class:`repro.plan.BGPlan`:
+``backend="staged"`` chains the staged kernels through :func:`_staged_single`
+(vmapped over a batch), ``"fused"`` / ``"fused_streamed"`` run the fused
+kernel on the whole batch through its 2-D (batch, stripe) grid.
 """
 from __future__ import annotations
-
-from repro.core.bilateral_grid import BGConfig
 
 from .bg_blur import bg_blur_kernel_call
 from .bg_create import bg_create_kernel_call
 from .bg_fused import bg_fused_kernel_call
 from .bg_slice import bg_slice_kernel_call
 
-__all__ = [
-    "bg_create",
-    "bg_blur",
-    "bg_slice",
-    "bg_fused",
-    "bilateral_grid_filter_pallas",
-]
+__all__ = ["bg_create", "bg_blur", "bg_slice", "bg_fused"]
 
 bg_create = bg_create_kernel_call
 bg_blur = bg_blur_kernel_call
@@ -43,42 +30,3 @@ def _staged_single(image, cfg, interpret):
     blurred = bg_blur_kernel_call(grid, cfg, interpret=interpret)
     grid_f = grid_normalize(blurred)
     return bg_slice_kernel_call(grid_f, image, cfg, interpret=interpret)
-
-
-def bilateral_grid_filter_pallas(
-    image,
-    cfg: BGConfig | None = None,
-    fused: bool = True,
-    quantize_output: bool = True,
-    interpret: bool | None = None,
-    batch_tile: int | None = None,
-    stream_input: bool = False,
-    *,
-    plan=None,
-):
-    """Kernel-backed BG pipeline (paper normalization), single frame or batch.
-
-    Preferred form: ``bilateral_grid_filter_pallas(image, plan=plan)`` with a
-    :class:`repro.plan.BGPlan`. The kwarg form still works — ``fused=True``
-    maps to the fused macro-pipeline backend (one HBM read/write; batches
-    share one dispatch via the (batch, stripe) grid), ``fused=False`` to the
-    three staged kernels (grid round-trips through HBM — the unfused
-    baseline), ``stream_input=True`` to the explicit double-buffered
-    HBM->VMEM input DMA — and routes into an equivalent plan.
-    """
-    from repro.plan import BGPlan, warn_legacy_dispatch
-
-    if plan is None:
-        if cfg is None:
-            raise TypeError("bilateral_grid_filter_pallas needs cfg= or plan=")
-        if not fused or stream_input or batch_tile is not None:
-            warn_legacy_dispatch("bilateral_grid_filter_pallas")
-        backend = ("fused_streamed" if stream_input else "fused") if fused else "staged"
-        plan = BGPlan(
-            cfg=cfg,
-            backend=backend,
-            batch_tile=batch_tile,
-            quantize_output=quantize_output,
-            interpret=interpret,
-        )
-    return plan(image)

@@ -1,5 +1,5 @@
 """Serving launcher: load (or init) params, run batched requests through the
-continuous-batching engine — or serve denoise frames through the sharded
+continuous-batching engine — or serve denoise frames through the async
 bilateral-grid frame engine — or serve multi-stream video through the async
 engine + temporal grid (``--workers N`` fronts the streams with a
 ``repro.fleet.FleetRouter`` over N workers instead of one bare engine).
@@ -18,12 +18,14 @@ import time
 
 def serve_frames(args) -> None:
     """Frame-denoise service smoke: stream synthetic noisy frames through the
-    mesh-divisible micro-batching engine (sharded over all local devices)."""
+    async frame engine on a plan from ``plan_for`` (a batch mesh over all
+    local devices when there are several)."""
     import jax
+    import numpy as np
 
     from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
     from repro.plan import plan_for
-    from repro.serving import FrameDenoiseEngine, FrameRequest
+    from repro.serving import AsyncFrameEngine
 
     h, w = (int(x) for x in args.frame_hw.split("x"))
     cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
@@ -32,39 +34,34 @@ def serve_frames(args) -> None:
     plan = plan_for(
         cfg, h, w, stream_input=True if args.stream_input else None
     )
-    eng = FrameDenoiseEngine(plan=plan, max_batch=args.micro_batch)
+    max_batch = min(args.micro_batch, args.frames)
     print(
         f"[serve] frame engine: {jax.device_count()} device(s), "
-        f"micro-batch {eng.max_batch} (mesh-divisible by {eng.n_devices}), "
-        f"plan[{plan.describe()}]"
+        f"micro-batch up to {max_batch}, plan[{plan.describe()}]"
     )
     clean = synthetic_batch(args.frames, h, w, seed=0)
-    noisy = add_gaussian_noise(clean, 30.0, seed=1)
+    noisy = np.asarray(add_gaussian_noise(clean, 30.0, seed=1))
 
-    # Warm-up compile on the batch shapes the timed loop will actually
-    # dispatch: frames arrive one per step(), so steady-state dispatches are
-    # n_devices-sized, plus the forced ragged tail.
-    warm_sizes = {min(eng.n_devices, args.frames)}
-    if args.frames % eng.n_devices:
-        warm_sizes.add(args.frames % eng.n_devices)
-    for size in sorted(warm_sizes):
-        for i in range(size):
-            eng.submit(FrameRequest(uid=-1 - i, frame=noisy[i % args.frames]))
-        eng.flush()
+    # Warm-up compile: the engine closes a micro-batch at any length up to
+    # max_batch, and each length is its own set of programs. Serve each
+    # length once through an engine that holds its batch until it is full.
+    for k in range(1, max_batch + 1):
+        with AsyncFrameEngine(
+            plan=plan, max_batch=k, batch_window_ms=60_000.0
+        ) as warm:
+            for f in [warm.submit(noisy[i]) for i in range(k)]:
+                f.result()
 
-    t0 = time.monotonic()
-    done = []
-    for i in range(args.frames):
-        eng.submit(FrameRequest(uid=i, frame=noisy[i]))
-        # dispatches whenever a device-count multiple is queued
-        done.extend(eng.step())
-    done.extend(eng.flush())  # ragged tail
-    jax.block_until_ready([r.result for r in done])
-    dt = time.monotonic() - t0
-    assert len(done) == args.frames and all(r.result is not None for r in done)
+    with AsyncFrameEngine(plan=plan, max_batch=max_batch) as eng:
+        t0 = time.monotonic()
+        futs = [eng.submit(noisy[i]) for i in range(args.frames)]
+        outs = [np.asarray(f.result()) for f in futs]
+        dt = time.monotonic() - t0
+        stats = eng.stats()
+    assert len(outs) == args.frames
     print(
         f"[serve] {args.frames} frames {h}x{w} in {dt:.2f}s "
-        f"({args.frames / dt:.1f} frames/s)"
+        f"({args.frames / dt:.1f} frames/s, {stats.dispatches} dispatches)"
     )
 
 
@@ -214,7 +211,7 @@ def serve_video(args) -> None:
     warm_packer = MultiStreamPacker(plan=plan)
     for s in range(n_streams):
         warm_packer.open(s, alpha=args.alpha)
-    with AsyncFrameEngine(cfg, max_batch=n_streams, packer=warm_packer) as warm:
+    with AsyncFrameEngine(packer=warm_packer, max_batch=n_streams) as warm:
         for f in [warm.submit(traffic[s][0], stream_id=s) for s in range(n_streams)]:
             f.result()
 
@@ -222,7 +219,6 @@ def serve_video(args) -> None:
     for s in range(n_streams):
         packer.open(s, alpha=args.alpha)
     eng = AsyncFrameEngine(
-        cfg,
         max_batch=n_streams,
         batch_window_ms=args.batch_window_ms,
         packer=packer,
@@ -268,7 +264,7 @@ def main():
         "--frames",
         type=int,
         default=0,
-        help="serve N synthetic denoise frames through the sharded BG frame "
+        help="serve N synthetic denoise frames through the async BG frame "
         "engine instead of LM requests",
     )
     ap.add_argument("--frame-hw", default="96x128", help="frame size HxW")

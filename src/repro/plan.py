@@ -2,12 +2,13 @@
 
 The paper's datapath is *configured once, then streamed*: window radius and
 grid geometry fix the FPGA pipeline structure, and frames flow through it at
-line rate with no further decisions. The software equivalent had drifted into
-per-call kwarg threading — ``use_kernels`` / ``sharded`` / ``mesh`` /
-``stream_input`` / ``batch_tile`` / ``interpret`` / temporal carry+alpha —
-re-decided independently by every layer (kernels, data pipeline, both frame
-engines, the video packer, the sharded service path, the launcher). This
-module collapses all of that into one plan/compile/execute layer:
+line rate with no further decisions. This module is the software equivalent:
+one plan/compile/execute layer that every caller dispatches through — the
+frame engine (``repro.serving.AsyncFrameEngine(plan=)``), the video packer
+(``repro.video.MultiStreamPacker(plan)``) and ``temporal_denoise``, the
+fleet workers, the data-pipeline frontends, the launchers and the chip
+benchmark all take a :class:`BGPlan` and call it (``plan(frames)``; the
+temporal form ``plan.as_temporal()(frames, carry=, alpha=)``):
 
   * :class:`BGPlan` — a frozen, hashable record of **every** dispatch
     decision. Invalid combinations (a temporal carry on the manual-DMA input
@@ -140,10 +141,8 @@ first, then ranks the legal ``backend x batch_tile`` candidates by
 which route produced the plan (``"cache"``/``"model"``/``"explicit"``/
 ``"default"``) so bench rows and serving logs stay attributable.
 
-Legacy kwargs (``use_kernels=``, ``sharded=``, ``stream_input=``, ...) on the
-public entry points still work: each entry point routes them into an
-equivalent ``BGPlan`` (batch_tile ``None`` = the kernel's ``DEFAULT_BATCH_TILE``,
-so legacy routes stay bit-identical) and warns once per call site.
+A plan built directly (``BGPlan(cfg=..., backend=...)``) with ``batch_tile``
+``None`` defers the tile to the kernel's ``DEFAULT_BATCH_TILE``.
 """
 from __future__ import annotations
 
@@ -151,7 +150,6 @@ import dataclasses
 import functools
 import hashlib
 import json
-import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -420,10 +418,9 @@ class BGPlan:
                        ``"fused"`` (in-kernel EMA) and ``"reference"`` (the
                        staged jnp oracle) support it.
       batch_tile:      frames per fused-kernel grid step. ``None`` defers to
-                       the kernel's ``DEFAULT_BATCH_TILE`` (what every legacy
-                       kwarg route did); :func:`plan_for` fills in a concrete
-                       auto-tuned value. Ignored (normalized to ``None``) by
-                       non-fused backends.
+                       the kernel's ``DEFAULT_BATCH_TILE``; :func:`plan_for`
+                       fills in a concrete auto-tuned value. Ignored
+                       (normalized to ``None``) by non-fused backends.
       mesh:            1-D device mesh sharding the frame/stream batch axis,
                        or ``None`` for single-device dispatch. Size-1 meshes
                        normalize to ``None``.
@@ -659,9 +656,8 @@ class BGPlan:
         """How this plan was chosen: ``"cache"`` (measured-plan cache hit),
         ``"model"`` (roofline-ranked by ``plan_for``), ``"explicit"``
         (``plan_for`` with every free decision pinned by the caller), or
-        ``"default"`` (constructed directly — kernel-default tiling, the
-        legacy-shim route). Informational — not part of plan equality or
-        the hash."""
+        ``"default"`` (constructed directly — kernel-default tiling).
+        Informational — not part of plan equality or the hash."""
         return self.__dict__.get("_provenance", "default")
 
     def describe(self) -> str:
@@ -1001,24 +997,6 @@ def set_dispatch_hook(hook):
     return prev
 
 
-# ------------------------------------------------------- legacy kwarg shims
-_WARNED_SITES: set = set()
-
-
-def warn_legacy_dispatch(site: str) -> None:
-    """One DeprecationWarning per call site for legacy dispatch kwargs."""
-    if site in _WARNED_SITES:
-        return
-    _WARNED_SITES.add(site)
-    warnings.warn(
-        f"{site}: per-call dispatch kwargs (use_kernels/sharded/mesh/"
-        f"stream_input/batch_tile/interpret/staged) are deprecated; build a "
-        f"repro.plan.BGPlan (e.g. via plan_for) and pass plan=",
-        DeprecationWarning,
-        stacklevel=3,
-    )
-
-
 def _shard_map(inner, mesh, n_in: int, n_out: int):
     """``inner`` over ``mesh`` with plain batch-axis specs on every input
     and output (``check_vma=False`` — pallas_call has no varying-axes rule).
@@ -1066,8 +1044,8 @@ def _plan_executable(plan: BGPlan):
     tile-padding trim, and output quantization — so repeat dispatches of a
     plan hit one compiled executable regardless of which layer (pipeline,
     engine, packer, launcher) issued them. Compositions mirror the pre-plan
-    routes operation-for-operation, which is what keeps legacy shims
-    bit-identical. A mesh plan's executable takes a batch at a multiple of
+    routes operation-for-operation (``tests/test_plan.py`` holds them
+    bit-identical). A mesh plan's executable takes a batch at a multiple of
     the device count, sharded ``P("batch")`` or placed by it, and returns it
     so; :meth:`BGPlan.__call__` pads and trims other lengths around it
     (:func:`_mesh_call`).

@@ -331,8 +331,8 @@ class FaultInjector:
     @contextlib.contextmanager
     def plan_hook(self):
         """Install this injector as the global ``repro.plan`` dispatch hook:
-        every ``BGPlan.__call__`` anywhere in the process (sync engine, data
-        pipeline, direct plan calls) runs ``on_dispatch`` first. The engine
+        every ``BGPlan.__call__`` anywhere in the process (data pipeline,
+        video packer, direct plan calls) runs ``on_dispatch`` first. The engine
         does *not* need this — it calls ``on_dispatch`` inside its guarded
         attempts — it is the integration point for non-engine consumers."""
         from repro.plan import set_dispatch_hook

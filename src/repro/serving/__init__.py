@@ -1,21 +1,14 @@
-"""Serving engines: LM continuous batching + the two frame-denoise fronts.
+"""Serving engines: LM continuous batching + the frame-denoise engine.
 
-Frame serving comes in two flavors; pick by how the caller wants to wait:
-
-  * ``frames.FrameDenoiseEngine`` — **synchronous** micro-batching. The
-    caller's thread owns the loop (``submit``/``step``/``flush``); each
-    dispatch stacks, launches, and returns request objects whose results the
-    caller realizes. Simple, deterministic, no threads — right for batch
-    jobs, tests, and single-tenant pipelines where the caller *is* the
-    frame source.
-  * ``async_engine.AsyncFrameEngine`` — **asynchronous** serving.
-    ``submit`` returns a Future immediately; a background dispatch thread
-    does deadline-aware micro-batching and double-buffered host->device
-    feeding (stacking batch N+1 while batch N computes), and a completion
-    thread resolves futures. Right for services: many producers, bounded
-    queues for backpressure, latency budgets, multi-stream video via a
-    ``repro.video`` packer, and strictly higher sustained frames/sec than
-    the synchronous engine (gated in benchmarks/bench_video_stream.py).
+Frames are served by one engine, ``async_engine.AsyncFrameEngine``, built on
+a ``repro.plan.BGPlan`` (``plan=``) or, for video, on a ``repro.video``
+packer (``packer=``). ``submit`` returns a Future immediately; a background
+dispatch thread does deadline-aware micro-batching and double-buffered
+host->device feeding (stacking batch N+1 while batch N computes), and a
+completion thread resolves futures: many producers, bounded queues for
+backpressure, latency budgets, and multi-stream video with per-stream
+temporal carries. A caller that wants one synchronous answer for a stack of
+frames calls the plan itself (``plan(frames)``).
 
 The async front is additionally **fault-tolerant** (the
 ``repro.reliability`` wiring): admission validation at ``submit``
@@ -31,9 +24,8 @@ Every failure a client observes through a Future is a typed
 ``retries`` / ``fallbacks`` / ``carry_resets`` / ``shed`` /
 ``watchdog_trips``; ``engine.fault_injector`` accepts a deterministic
 ``reliability.FaultInjector`` so every failure mode is drivable in tests
-and the ``benchmarks/bench_bg_chaos.py`` CI soak. The synchronous engine
-stays guard-free on purpose — it is the simple, deterministic oracle the
-async front is equivalence-tested against.
+and the ``benchmarks/bench_bg_chaos.py`` CI soak. Its answers are
+equivalence-tested against the plan's own answer on the stacked frames.
 
 **Telemetry** of the async front: ``EngineStats`` latencies run from
 ``submit`` to the future being set (the consumer's copy of the answer to
@@ -63,5 +55,4 @@ package docstring for the full architecture.
 """
 from .async_engine import AsyncFrameEngine, AsyncFrameRequest, EngineStats, StageRecord
 from .engine import Request, ServeEngine, make_prefill, make_serve_step
-from .frames import FrameDenoiseEngine, FrameRequest
 from .sampling import greedy, sample_temperature, sample_topk

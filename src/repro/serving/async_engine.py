@@ -1,11 +1,9 @@
 """Async frame-denoise engine: pipelined host->device feeding behind futures.
 
-``frames.FrameDenoiseEngine`` is synchronous per micro-batch: the caller's
-thread stacks the batch, dispatches it, and (in any real service) realizes
-the results before it can hand them back — so the device idles while the
-host stacks/converts, and the host idles while the device computes. This
-engine closes ROADMAP's "async/pipelined host-to-device frame feeding" item
-by splitting that loop across threads:
+A synchronous serving loop stacks a batch, dispatches it, and realizes the
+results before it can take more frames — so the device idles while the host
+stacks/converts, and the host idles while the device computes. This engine
+splits that loop across threads:
 
   client threads   -- submit(frame) -> Future          (bounded queue)
   dispatch thread  -- collect micro-batch, transfer it in its own dtype,
@@ -116,9 +114,9 @@ and ``bg.split`` (``repro.serving.device_stages``) around the plan
 executable ``jit_bg_<backend>`` with its ``bg.relayout``, ``bg.kernel``,
 ``bg.trim`` and ``bg.quantize`` stages (``repro.plan``).
 
-Dispatch is plan-driven: pass a ``repro.plan.BGPlan`` via ``plan=`` (or a
-packer whose plan carries the video dispatch); the legacy ``cfg``/``mesh``/
-``stream_input``/``interpret`` kwargs route into an equivalent plan.
+Dispatch is plan-driven: pass a ``repro.plan.BGPlan`` via ``plan=`` (build
+it with ``repro.plan.plan_for``), or in video mode a packer whose plan
+carries the video dispatch.
 """
 from __future__ import annotations
 
@@ -138,7 +136,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from repro.core.bilateral_grid import BGConfig
 from repro.kernels.bg_fused import is_level_dtype
 from repro.reliability import (
     DeadlineExceeded,
@@ -346,17 +343,14 @@ class AsyncFrameEngine:
 
     def __init__(
         self,
-        cfg: BGConfig | None = None,
-        mesh=None,
+        plan=None,
+        *,
+        packer=None,
         max_batch: int = 32,
         max_queue: int = 256,
         batch_window_ms: float = 2.0,
         deadline_margin_ms: float = 1.0,
         max_inflight: int = 2,
-        stream_input: bool = False,
-        interpret: Optional[bool] = None,
-        packer=None,
-        plan=None,
         fault_injector=None,
         watchdog_ms: Optional[float] = None,
         retry_policy: Optional[RetryPolicy] = None,
@@ -383,30 +377,16 @@ class AsyncFrameEngine:
                 )
             plan = packer.plan
         elif plan is None:
-            if cfg is None:
-                raise TypeError("AsyncFrameEngine needs cfg=, plan= or packer=")
-            from repro.plan import BGPlan, warn_legacy_dispatch
-            from repro.sharding.bg_shard import _service_mesh
-
-            if stream_input or mesh is not None:
-                warn_legacy_dispatch("AsyncFrameEngine")
-            plan = BGPlan(
-                cfg=cfg,
-                backend="fused_streamed" if stream_input else "fused",
-                mesh=_service_mesh(mesh),
-                quantize_output=True,
-                interpret=interpret,
+            raise TypeError(
+                "AsyncFrameEngine needs plan= (build one with "
+                "repro.plan.plan_for) or packer="
             )
         elif not plan.quantize_output:
-            # same contract as FrameDenoiseEngine: the two serving fronts
-            # are gated output-identical (bench_video_stream.py), so they
-            # must reject the same plans
             raise ValueError(
                 "AsyncFrameEngine serves quantized frames; build the plan "
                 "with quantize_output=True"
             )
         self.plan = plan
-        self.cfg = cfg if cfg is not None else self.plan.cfg
         self.max_batch = max_batch
         self.batch_window = batch_window_ms / 1e3
         self.deadline_margin = deadline_margin_ms / 1e3
@@ -461,6 +441,11 @@ class AsyncFrameEngine:
         )
         self._dispatcher.start()
         self._completer.start()
+
+    @property
+    def cfg(self):
+        """The grid/window configuration of the plan this engine serves."""
+        return self.plan.cfg
 
     # ------------------------------------------------------------- clients
     def submit(

@@ -9,8 +9,8 @@ Two layers:
     blurred planes blend in VMEM right before TI — one kernel dispatch per
     pack, grid never round-tripping HBM), with the stream axis sharded over
     the ``("batch",)`` mesh. ``a == 0`` reduces exactly to the per-frame
-    fused path (bit-identical); the staged jnp pipeline survives as the
-    ``staged=True`` reference oracle.
+    fused path (bit-identical); the staged jnp pipeline is the reference
+    oracle, a ``BGPlan(backend="reference")``.
   * :mod:`repro.video.session` — per-stream state (grid carry, frame
     counter) plus a multi-stream packer that batches one frame from each of
     N live streams into one single-dispatch pack (warm/cold/first-frame

@@ -18,7 +18,9 @@ micro-batch* (batch composition is timing-dependent under the async engine),
 without the two-dispatch cold/warm split this packer needed while the warm
 path lived on the staged jnp pipeline. A pack whose streams are all cold
 (no session holds a carry, every alpha is 0) short-circuits to the carry-free
-per-frame path and never materializes temporal state at all.
+per-frame path and never materializes temporal state at all. The packer
+holds one :class:`repro.plan.BGPlan` (from ``plan_for(..., temporal=True)``)
+and derives each pack's temporal or per-frame form and tile from it.
 
 Reliability (PR 6): :meth:`MultiStreamPacker.pack_guarded` is ``pack`` plus
 a :class:`repro.reliability.DispatchGuard` — lazy per-row ``jnp.isfinite``
@@ -68,40 +70,13 @@ class StreamSession:
 class MultiStreamPacker:
     """Batches one frame per live stream into a single temporal dispatch.
 
-    Dispatch is plan-driven: construct with ``plan=`` (e.g. from
-    ``repro.plan.plan_for``, which auto-tunes the fused-kernel batch tile
-    from frame geometry) and the packer asks the plan for its tile instead
-    of being handed ``batch_tile=``. The legacy kwarg form still works and
-    routes into an equivalent plan (``batch_tile=None`` = kernel default,
-    preserving the pre-plan dispatch bit-for-bit).
+    Dispatch is plan-driven: construct with a ``repro.plan.BGPlan`` (e.g.
+    from ``repro.plan.plan_for(..., temporal=True)``, which auto-tunes the
+    fused-kernel batch tile from frame geometry) and the packer asks the
+    plan for its tile per pack.
     """
 
-    def __init__(
-        self,
-        cfg: BGConfig | None = None,
-        mesh=None,
-        interpret: Optional[bool] = None,
-        batch_tile: Optional[int] = None,
-        quantize_output: bool = True,
-        *,
-        plan=None,
-    ):
-        if plan is None:
-            if cfg is None:
-                raise TypeError("MultiStreamPacker needs cfg= or plan=")
-            from repro.plan import BGPlan, warn_legacy_dispatch
-            from repro.sharding.bg_shard import _service_mesh
-
-            if mesh is not None or batch_tile is not None:
-                warn_legacy_dispatch("MultiStreamPacker")
-            plan = BGPlan(
-                cfg=cfg,
-                backend="fused",
-                batch_tile=batch_tile,
-                mesh=_service_mesh(mesh),
-                quantize_output=quantize_output,
-                interpret=interpret,
-            )
+    def __init__(self, plan):
         if plan.backend == "fused_streamed":
             # rejected once, here, instead of failing the first warm pack's
             # as_temporal(True) mid-service: the manual-DMA input path does
@@ -288,9 +263,10 @@ class MultiStreamPacker:
         warm = [s for s in sids if sessions[s].alpha > 0.0]
         results = {}
         # the packer asks the plan for this pack's tile (the plan's own
-        # auto-tuned/legacy-default value clamped to the per-device shard,
-        # exactly the clamp the kernel would apply — an explicit plan
-        # decision instead of an implicit kernel one)
+        # auto-tuned value, or the kernel default when the plan defers,
+        # clamped to the per-device shard, exactly the clamp the kernel
+        # would apply — an explicit plan decision instead of an implicit
+        # kernel one)
         base = self.plan if plan is None else plan
         plan = base.with_tile(base.tile_for(len(sids)))
         carry_sids = ()

@@ -31,15 +31,15 @@ blend is the exact float identity, so its output stays *bit-identical* to
 the per-frame fused service no matter which streams share the pack — the
 property that previously forced :class:`repro.video.session.MultiStreamPacker`
 to split mixed packs into two dispatches. A pure cold pack (no carry at all)
-still short-circuits to ``bg_denoise_sharded`` and materializes nothing
-temporal. The pack's stream axis shards over the ``("batch",)`` mesh via
-:func:`repro.sharding.bg_shard.bg_temporal_sharded` (carries travel with
-their stream's device, zero collectives).
+still short-circuits to the plan's per-frame form and materializes nothing
+temporal. On a mesh plan the pack's stream axis shards over the
+``("batch",)`` mesh (carries travel with their stream's device, zero
+collectives).
 
 The staged jnp pipeline (vmapped ``grid_create -> grid_blur``, blend, slice)
-remains available as ``staged=True`` — it is the *reference oracle* the
-fused path is tested against (the two agree to ~5e-3 pre-quantization; the
-fused path is authoritative in service).
+is the temporal form of ``BGPlan(backend="reference")`` — the *reference
+oracle* the fused path is tested against (the two agree to ~5e-3
+pre-quantization; the fused path is authoritative in service).
 """
 from __future__ import annotations
 
@@ -59,25 +59,6 @@ from repro.core.bilateral_grid import (
 )
 
 __all__ = ["blurred_grid_batch", "carry_shape", "temporal_denoise"]
-
-
-@functools.lru_cache(maxsize=128)
-def _legacy_plan(cfg, staged, batch_tile, mesh, quantize_output, interpret):
-    """Cached legacy-kwargs -> BGPlan mapping (temporal_denoise sits on the
-    packer's per-pack hot path; rebuilding the frozen plan per call costs
-    more than the lookup)."""
-    from repro.plan import BGPlan
-    from repro.sharding.bg_shard import _service_mesh
-
-    return BGPlan(
-        cfg=cfg,
-        backend="reference" if staged else "fused",
-        temporal=False,  # the temporal/per-frame variant is derived per pack
-        batch_tile=batch_tile,
-        mesh=None if staged else _service_mesh(mesh),
-        quantize_output=quantize_output,
-        interpret=interpret,
-    )
 
 
 def carry_shape(h: int, w: int, cfg: BGConfig) -> Tuple[int, int, int, int]:
@@ -133,63 +114,38 @@ def blurred_grid_batch(
 
 def temporal_denoise(
     frames: jnp.ndarray,
-    cfg: BGConfig | None = None,
+    *,
+    plan,
     carry: Optional[jnp.ndarray] = None,
     alpha=0.0,
-    *,
-    mesh=None,
-    interpret: Optional[bool] = None,
-    batch_tile: Optional[int] = None,
-    quantize_output: bool = True,
-    staged: bool = False,
-    plan=None,
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """One temporal step for a pack of streams: denoise + advance the carry.
 
     Args:
       frames: ``(n, h, w)`` — one frame from each of n streams (or a single
         ``(h, w)`` frame, treated as n == 1).
+      plan: the ``repro.plan.BGPlan`` that fixes the dispatch (backend,
+        mesh, batch_tile, quantization, interpret). The temporal /
+        per-frame variant of the plan is derived here from the pack
+        (``as_temporal``), so one base plan serves warm, cold and mixed
+        packs. ``backend="reference"`` is the staged jnp oracle.
       carry: ``None`` when no stream has temporal history, else the stacked
         ``(n, gx, gy, gz, 2)`` blurred-grid carries. Streams without history
         inside a warm pack pass a zero carry row *and* a zero alpha entry
         (the blend then reduces to ``B_t``; the packer arranges this).
       alpha: scalar or length-n host-side blend weights in ``[0, 1)``.
         ``alpha`` is configuration, not data — it must not be a traced value.
-      batch_tile: frames per fused-kernel grid step (see
-        ``bg_fused_kernel_call``); a video service packing n modest-sized
-        streams can set ``batch_tile=n`` so the whole pack sweeps the
-        macro-pipeline in one tile. Ignored by the staged oracle.
-      staged: run the staged jnp reference pipeline instead of the fused
-        temporal kernel. The oracle for tests/benchmarks only — the fused
-        path is the service path for every alpha.
-      plan: a base ``repro.plan.BGPlan`` that fixes the dispatch (backend,
-        mesh, batch_tile, quantization, interpret) — the preferred form; the
-        legacy kwargs above route into an equivalent plan. The temporal /
-        per-frame variant of the plan is derived here from the pack
-        (``with_options(temporal=...)``), so one base plan serves warm,
-        cold and mixed packs.
 
-    Returns ``(out, new_carry)``. When ``carry is None`` and every alpha is
-    zero (a pure per-frame pack) the fused kernel path is dispatched with no
-    carry at all: the output is bit-identical to
-    ``bg_denoise_sharded(frames, ...)`` and ``new_carry`` is ``None`` —
+    Returns ``(out, new_carry)``. When ``carry is None``, every alpha is zero
+    and the plan is a kernel plan (a pure per-frame pack) the plan's
+    per-frame form is dispatched with no carry at all: the output is
+    bit-identical to ``plan(frames)`` and ``new_carry`` is ``None`` —
     nothing temporal was computed, which is exactly the "reduces to the
-    per-frame path at a == 0" contract. Otherwise the fused temporal kernel
-    runs the EMA in VMEM (``a == 0`` rows still bit-identical to the
-    per-frame path) and the stream axis shards over the mesh.
+    per-frame path at a == 0" contract. Otherwise the temporal form runs
+    the EMA (in VMEM on the fused kernel; ``a == 0`` rows still
+    bit-identical to the per-frame path) and the stream axis shards over
+    the plan's mesh.
     """
-    from repro.plan import warn_legacy_dispatch
-
-    if plan is not None and staged:
-        raise ValueError("pass either plan= or staged=, not both")
-    if plan is None:
-        if cfg is None:
-            raise TypeError("temporal_denoise needs cfg= or plan=")
-        if staged or mesh is not None or batch_tile is not None:
-            warn_legacy_dispatch("temporal_denoise")
-        plan = _legacy_plan(
-            cfg, staged, batch_tile, mesh, quantize_output, interpret
-        )
     frames = jnp.asarray(frames)
     squeeze = frames.ndim == 2
     if squeeze:
@@ -200,9 +156,8 @@ def temporal_denoise(
     alpha_np = np.broadcast_to(np.asarray(alpha, np.float32), (n,))
     if np.any(alpha_np < 0.0) or np.any(alpha_np >= 1.0):
         raise ValueError(f"temporal alpha must be in [0, 1), got {alpha}")
-    temporal_needed = staged or plan.backend == "reference"
 
-    if carry is None and not alpha_np.any() and not temporal_needed:
+    if carry is None and not alpha_np.any() and plan.backend != "reference":
         out = plan.as_temporal(False)(frames)
         return (out[0] if squeeze else out), None
 

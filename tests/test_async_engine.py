@@ -1,5 +1,5 @@
-"""Async frame engine: equivalence with the sync engine, video-mode stream
-ordering, backpressure, deadlines, and argument validation.
+"""Async frame engine: equivalence with the plan's own answer, video-mode
+stream ordering, backpressure, deadlines, and argument validation.
 
 Wall-clock-sensitive tests (those asserting *when* a dispatch happens, not
 just that it happens) carry ``@pytest.mark.timing`` so loaded CI runners can
@@ -10,9 +10,10 @@ assertion says nothing about the code when every thread is time-slicing
 (see :func:`_timing_relax`). Everything else is scheduling-order
 independent: futures resolve whenever the background threads get there.
 
-On a multi-device host (the forced 8-device CI mesh) the engine auto-builds
-a batch mesh and every dispatch goes through ``bg_denoise_sharded`` — the
-same assertions hold because sharding is bit-invisible (test_bg_sharded.py).
+The engine serves the plan it is given: here a fused plan on a batch mesh
+over all local devices, so on a multi-device host (the forced 8-device CI
+mesh) every dispatch is sharded — the same assertions hold because sharding
+is bit-invisible (test_bg_sharded.py, test_mesh_engine.py).
 """
 import os
 import queue
@@ -23,10 +24,14 @@ import pytest
 
 from repro.core import BGConfig, add_gaussian_noise
 from repro.data import synthetic_video
-from repro.serving import AsyncFrameEngine, FrameDenoiseEngine, FrameRequest
+from repro.plan import BGPlan
+from repro.serving import AsyncFrameEngine
+from repro.sharding.bg_shard import batch_mesh
 from repro.video import MultiStreamPacker
 
 CFG = BGConfig(r=4, sigma_s=4.0, sigma_r=60.0)
+# the service plan: a batch mesh over all local devices (one device: none)
+PLAN = BGPlan(cfg=CFG, mesh=batch_mesh())
 
 # per-CPU 1-minute load above which wall-clock assertions are meaningless
 # (every thread is time-slicing; dispatch latency measures the scheduler,
@@ -64,14 +69,11 @@ def _frames(n, h=32, w=48, seed=0):
     ]
 
 
-def test_results_match_sync_engine():
+def test_results_match_plan_on_stacked_frames():
     frames = _frames(11)
-    sync = FrameDenoiseEngine(CFG, max_batch=4)
-    for i, f in enumerate(frames):
-        sync.submit(FrameRequest(uid=i, frame=f))
-    ref = {r.uid: np.asarray(r.result) for r in sync.flush()}
+    ref = np.asarray(PLAN(np.stack(frames)))
 
-    with AsyncFrameEngine(CFG, max_batch=4, batch_window_ms=20.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=4, batch_window_ms=20.0) as eng:
         futs = [eng.submit(f) for f in frames]
         for i, fut in enumerate(futs):
             np.testing.assert_array_equal(np.asarray(fut.result()), ref[i])
@@ -89,11 +91,11 @@ def test_video_mode_matches_solo_packer():
     per_stream = {s: _frames(n_frames, seed=i * 11) for i, s in enumerate(sids)}
     alphas = {"s0": 0.5, "s1": 0.0, "s2": 0.7}
 
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(PLAN)
     for s in sids:
         packer.open(s, alpha=alphas[s])
     with AsyncFrameEngine(
-        CFG, max_batch=len(sids), batch_window_ms=20.0, packer=packer
+        packer=packer, max_batch=len(sids), batch_window_ms=20.0
     ) as eng:
         futs = [
             (s, t, eng.submit(per_stream[s][t], stream_id=s))
@@ -103,7 +105,7 @@ def test_video_mode_matches_solo_packer():
         outs = {(s, t): np.asarray(f.result()) for s, t, f in futs}
 
     for s in sids:
-        solo = MultiStreamPacker(CFG)
+        solo = MultiStreamPacker(PLAN)
         solo.open(s, alpha=alphas[s])
         for t in range(n_frames):
             ref = solo.pack({s: per_stream[s][t]})[s]
@@ -114,10 +116,10 @@ def test_video_mode_defers_same_stream_frames():
     """Two frames of one stream never share a micro-batch: the second defers
     to the next dispatch and still resolves in order."""
     frames = _frames(6, seed=3)
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(PLAN)
     packer.open("only", alpha=0.6)
     with AsyncFrameEngine(
-        CFG, max_batch=8, batch_window_ms=5.0, packer=packer
+        packer=packer, max_batch=8, batch_window_ms=5.0
     ) as eng:
         futs = [eng.submit(f, stream_id="only") for f in frames]
         [f.result() for f in futs]
@@ -129,7 +131,7 @@ def test_video_mode_defers_same_stream_frames():
 def test_backpressure_and_flush():
     frames = _frames(1)
     with AsyncFrameEngine(
-        CFG, max_batch=1, max_queue=2, batch_window_ms=0.0
+        plan=PLAN, max_batch=1, max_queue=2, batch_window_ms=0.0
     ) as eng:
         rejected = 0
         futs = []
@@ -146,11 +148,11 @@ def test_backpressure_and_flush():
 
 
 def test_dispatch_errors_fail_futures_not_engine():
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(PLAN)
     packer.open("ok", alpha=0.0)
     frames = _frames(2)
     with AsyncFrameEngine(
-        CFG, max_batch=2, batch_window_ms=5.0, packer=packer
+        packer=packer, max_batch=2, batch_window_ms=5.0
     ) as eng:
         bad = eng.submit(frames[0], stream_id="ghost")  # stream never opened
         with pytest.raises(KeyError):
@@ -164,7 +166,7 @@ def test_cancelled_future_does_not_kill_engine():
     thread — later requests (even batch-mates of the cancelled one) still
     resolve."""
     frames = _frames(2)
-    with AsyncFrameEngine(CFG, max_batch=64, batch_window_ms=150.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=64, batch_window_ms=150.0) as eng:
         f1 = eng.submit(frames[0])
         f1.cancel()  # races the window; both outcomes must be survivable
         f2 = eng.submit(frames[1])
@@ -181,13 +183,11 @@ def test_validation_and_lifecycle():
         {"max_inflight": 0},
     ):
         with pytest.raises(ValueError):
-            AsyncFrameEngine(CFG, **bad_kw)
-    # sync engine satellite: 0/negative max_batch rejected, not clamped
-    for bad in (0, -1):
-        with pytest.raises(ValueError):
-            FrameDenoiseEngine(CFG, max_batch=bad)
+            AsyncFrameEngine(plan=PLAN, **bad_kw)
+    with pytest.raises(TypeError, match="plan_for"):
+        AsyncFrameEngine(max_batch=2)  # neither plan= nor packer=
 
-    eng = AsyncFrameEngine(CFG, max_batch=2, packer=MultiStreamPacker(CFG))
+    eng = AsyncFrameEngine(packer=MultiStreamPacker(PLAN), max_batch=2)
     with pytest.raises(ValueError):
         eng.submit(_frames(1)[0])  # video mode requires a stream_id
     eng.close()
@@ -206,7 +206,7 @@ def test_deadline_forces_early_dispatch():
     always overshoots the budget."""
     relax = _timing_relax()  # sample load before the timed section
     frames = _frames(1)
-    with AsyncFrameEngine(CFG, max_batch=64, batch_window_ms=500.0 * relax) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=64, batch_window_ms=500.0 * relax) as eng:
         eng.submit(frames[0]).result()  # warm-up compile outside the clock
         t0 = time.monotonic()
         # budget scales with load too: the PR-6 collect-time shedder fails a
@@ -222,7 +222,7 @@ def test_batch_window_expiry_dispatches_partial_batch():
     """Low traffic: a never-full batch still dispatches after the window."""
     relax = _timing_relax()  # sample load before the timed section
     frames = _frames(2)
-    with AsyncFrameEngine(CFG, max_batch=64, batch_window_ms=40.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=64, batch_window_ms=40.0) as eng:
         eng.submit(frames[0]).result()  # warm-up compile outside the clock
         t0 = time.monotonic()
         out = eng.submit(frames[1]).result()

@@ -6,21 +6,17 @@ The fused kernel's (batch, stripe) grid must be invisible numerically:
   * the degenerate b == 1 batch is bit-identical to the single-frame path;
   * batch-tile padding (b not divisible by the tile) never leaks the zero
     padding frames into real outputs;
-  * the batched wrappers (pallas pipeline, streaming scan, data pipeline)
-    agree with their per-frame equivalents.
+  * batched plans (staged and fused kernels, streaming scan, reference)
+    agree with the same plans on each frame alone.
 """
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (
-    BGConfig,
-    add_gaussian_noise,
-    bilateral_grid_filter_streaming,
-    synthetic_batch,
-)
-from repro.kernels import bg_fused, bilateral_grid_filter_pallas
+from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
+from repro.kernels import bg_fused
 from repro.kernels.ref import ref_fused
+from repro.plan import BGPlan
 
 # ragged shapes: every (shape, r) pair has h % r != 0 and w % r != 0
 RAGGED = [
@@ -77,34 +73,35 @@ def test_batch_tile_padding_is_masked():
 def test_batched_pipeline_wrapper_matches_per_frame(fused):
     cfg = BGConfig(r=7, sigma_s=4.0, sigma_r=50.0)
     imgs = _batch(3, 45, 64)
-    out = bilateral_grid_filter_pallas(imgs, cfg, fused=fused, interpret=True)
+    plan = BGPlan(cfg=cfg, backend="fused" if fused else "staged",
+                  interpret=True)
+    out = plan(imgs)
     assert out.shape == imgs.shape
     for i in range(3):
-        ref = bilateral_grid_filter_pallas(imgs[i], cfg, fused=fused, interpret=True)
+        ref = plan(imgs[i])
         np.testing.assert_array_equal(np.asarray(out[i]), np.asarray(ref))
 
 
 def test_batched_streaming_matches_per_frame():
     cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
     imgs = _batch(3, 40, 55)
-    out = bilateral_grid_filter_streaming(imgs, cfg)
+    plan = BGPlan(cfg=cfg, backend="streaming")
+    out = plan(imgs)
     assert out.shape == imgs.shape
     for i in range(3):
-        ref = bilateral_grid_filter_streaming(imgs[i], cfg)
+        ref = plan(imgs[i])
         np.testing.assert_allclose(
             np.asarray(out[i]), np.asarray(ref), atol=1e-5
         )
 
 
 def test_denoise_batch_kernel_path():
-    """data-pipeline stage feeds the batch natively to the fused kernel and
-    stays within 1 quantized level of the vmapped jnp reference."""
-    from repro.data.pipeline import denoise_batch
-
+    """A fused plan feeds the batch natively to the fused kernel and stays
+    within 1 quantized level of the vmapped jnp reference plan."""
     cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
     imgs = _batch(2, 40, 55)
-    out_k = denoise_batch(imgs, cfg, use_kernels=True)
-    out_j = denoise_batch(imgs, cfg, use_kernels=False)
+    out_k = BGPlan(cfg=cfg, backend="fused")(imgs)
+    out_j = BGPlan(cfg=cfg, backend="reference")(imgs)
     diff = np.abs(np.asarray(out_k) - np.asarray(out_j))
     assert np.mean(diff == 0.0) > 0.995
     assert diff.max() <= 1.0
@@ -133,26 +130,19 @@ def test_stream_input_single_frame_squeeze():
     )
 
 
-@pytest.mark.parametrize("use_kernels", [True, False])
-def test_color_frames_fold_channels_into_batch(use_kernels):
+@pytest.mark.parametrize("kernel", [True, False])
+def test_color_frames_fold_channels_into_batch(kernel):
     """(b, h, w, 3) color batches denoise per channel by folding the channel
     axis into the batch axis; round-trips bit-exactly against denoising each
-    channel plane separately."""
-    from repro.data.pipeline import denoise_batch
-
+    channel plane separately (fused kernel plan, and the reference plan)."""
     cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
+    plan = BGPlan(cfg=cfg, backend="fused" if kernel else "reference")
     base = _batch(3, 40, 55)
     # three genuinely different channel planes per frame
     color = jnp.stack(
         [base, jnp.flip(base, axis=1), jnp.flip(base, axis=2)], axis=-1
     )
-    out = denoise_batch(color, cfg, use_kernels=use_kernels)
+    out = plan(color)
     assert out.shape == color.shape
-    per_channel = jnp.stack(
-        [
-            denoise_batch(color[..., c], cfg, use_kernels=use_kernels)
-            for c in range(3)
-        ],
-        axis=-1,
-    )
+    per_channel = jnp.stack([plan(color[..., c]) for c in range(3)], axis=-1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(per_channel))

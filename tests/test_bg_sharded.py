@@ -9,8 +9,8 @@ as test_distributed.py). Sharding must be numerically invisible:
   * ragged batches (b not divisible by the device count),
   * b < n_devices (idle devices denoising pure padding),
   * b == 1 (a mesh of mostly-idle devices),
-  * mesh=None auto-mesh over all devices,
-  * sharded + stream_input composition,
+  * ``plan_for``'s auto-mesh over all devices,
+  * mesh + ``fused_streamed`` (input DMA) composition,
 
 all bit-identical to ``bg_fused_kernel_call`` on the same batch.
 """
@@ -47,33 +47,39 @@ def test_sharded_bit_identical_to_single_device():
         import jax, numpy as np
         from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
         from repro.kernels import bg_fused
-        from repro.sharding.bg_shard import batch_mesh, bg_denoise_sharded
+        from repro.plan import BGPlan, plan_for
+        from repro.sharding.bg_shard import batch_mesh
 
         assert jax.device_count() == 8
         cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
+
+        def raw_plan(mesh, backend="fused"):
+            return BGPlan(cfg=cfg, backend=backend, mesh=mesh,
+                          interpret=True, quantize_output=False)
+
         # ragged frame shape too (h % r != 0, w % r != 0)
         h, w = 45, 55
         for b, nd in [(8, 8), (5, 4), (6, 8), (3, 8), (1, 8), (1, 1), (7, 2)]:
             imgs = add_gaussian_noise(
                 synthetic_batch(b, h, w, seed=b), 30.0, seed=b + 50)
             ref = bg_fused(imgs, cfg, interpret=True)
-            out = bg_denoise_sharded(
-                imgs, cfg, mesh=batch_mesh(nd), interpret=True)
+            out = raw_plan(batch_mesh(nd))(imgs)
             np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
             print(f"OK b={b} nd={nd}")
 
-        # mesh=None: auto-mesh over all local devices
+        # plan_for auto-meshes over all local devices
         imgs = add_gaussian_noise(synthetic_batch(5, h, w, seed=0), 30.0, seed=9)
+        auto = plan_for(cfg, h, w, backend="fused", batch_tile=4,
+                        quantize_output=False, interpret=True)
+        assert auto.mesh_size == 8
         np.testing.assert_array_equal(
-            np.asarray(bg_denoise_sharded(imgs, cfg, interpret=True)),
+            np.asarray(auto(imgs)),
             np.asarray(bg_fused(imgs, cfg, interpret=True)))
         print("OK auto-mesh")
 
         # sharded + double-buffered input stream composition
         np.testing.assert_array_equal(
-            np.asarray(bg_denoise_sharded(
-                imgs, cfg, mesh=batch_mesh(4), interpret=True,
-                stream_input=True)),
+            np.asarray(raw_plan(batch_mesh(4), "fused_streamed")(imgs)),
             np.asarray(bg_fused(imgs, cfg, interpret=True)))
         print("OK sharded+stream_input")
         """
@@ -81,24 +87,32 @@ def test_sharded_bit_identical_to_single_device():
 
 
 def test_single_device_fallback_is_plain_call():
-    """On a 1-device host mesh=None must degrade to the unsharded kernel."""
+    """On a 1-device host plan_for builds no mesh, and a size-1 mesh
+    normalizes away: both run the unsharded kernel."""
     run_sub(
         """
         import jax, numpy as np
         from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
         from repro.kernels import bg_fused
-        from repro.sharding.bg_shard import bg_denoise_sharded
+        from repro.plan import BGPlan, plan_for
+        from repro.sharding.bg_shard import batch_mesh
 
         assert jax.device_count() == 1
         cfg = BGConfig(r=7, sigma_s=4.0, sigma_r=50.0)
         imgs = add_gaussian_noise(synthetic_batch(3, 41, 60, seed=2), 30.0, seed=3)
-        np.testing.assert_array_equal(
-            np.asarray(bg_denoise_sharded(imgs, cfg, interpret=True)),
-            np.asarray(bg_fused(imgs, cfg, interpret=True)))
-        # single (h, w) frame squeeze path
-        np.testing.assert_array_equal(
-            np.asarray(bg_denoise_sharded(imgs[0], cfg, interpret=True)),
-            np.asarray(bg_fused(imgs[0], cfg, interpret=True)))
+        auto = plan_for(cfg, 41, 60, backend="fused", batch_tile=4,
+                        quantize_output=False, interpret=True)
+        one = BGPlan(cfg=cfg, mesh=batch_mesh(1), interpret=True,
+                     quantize_output=False)
+        assert auto.mesh is None and one.mesh is None
+        for plan in (auto, one):
+            np.testing.assert_array_equal(
+                np.asarray(plan(imgs)),
+                np.asarray(bg_fused(imgs, cfg, interpret=True)))
+            # single (h, w) frame squeeze path
+            np.testing.assert_array_equal(
+                np.asarray(plan(imgs[0])),
+                np.asarray(bg_fused(imgs[0], cfg, interpret=True)))
         print("OK fallback")
         """,
         devices=1,
@@ -106,50 +120,50 @@ def test_single_device_fallback_is_plain_call():
 
 
 def test_frame_engine_micro_batches_mesh_divisible():
-    """The serving engine only dispatches mesh-divisible micro-batches (tail
-    flush excepted) and returns bit-exact per-frame results."""
+    """The frame engine on an 8-device plan: 11 ragged frames, every answer
+    bitwise the single-device plan's, and each micro-batch padded to a
+    device multiple with its pad rows counted in ``mesh_pad_rows``."""
     run_sub(
         """
         import jax, numpy as np
         from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
-        from repro.data.pipeline import denoise_batch
-        from repro.serving import FrameDenoiseEngine, FrameRequest
+        from repro.plan import BGPlan
+        from repro.serving import AsyncFrameEngine
+        from repro.sharding.bg_shard import batch_mesh, row_pad
 
         assert jax.device_count() == 8
         cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
-        frames = add_gaussian_noise(
-            synthetic_batch(11, 40, 48, seed=4), 30.0, seed=5)
-        ref = denoise_batch(frames, cfg, use_kernels=True)
+        frames = np.asarray(add_gaussian_noise(
+            synthetic_batch(11, 40, 48, seed=4), 30.0, seed=5))
+        ref = np.asarray(BGPlan(cfg=cfg, interpret=True)(frames))
 
-        eng = FrameDenoiseEngine(cfg, max_batch=8)
-        assert eng.n_devices == 8 and eng.max_batch == 8
-        done = []
+        plan = BGPlan(cfg=cfg, mesh=batch_mesh(8), interpret=True)
+        with AsyncFrameEngine(plan=plan, max_batch=8,
+                              batch_window_ms=200.0) as eng:
+            futs = [eng.submit(frames[i]) for i in range(11)]
+            outs = [np.asarray(f.result(timeout=300)) for f in futs]
+            recs = eng.timeline()
+            st = eng.stats()
         for i in range(11):
-            eng.submit(FrameRequest(uid=i, frame=frames[i]))
-            batch = eng.step()
-            if batch:  # fires exactly once the 8th frame arrives
-                assert len(batch) % eng.n_devices == 0
-            done.extend(batch)
-        assert len(done) == 8 and eng.pending() == 3
-        done.extend(eng.flush())  # ragged tail: forced, padded internally
-        assert len(done) == 11 and eng.pending() == 0
-        for r in done:
-            np.testing.assert_array_equal(
-                np.asarray(r.result), np.asarray(ref[r.uid]))
+            np.testing.assert_array_equal(outs[i], ref[i])
+        sizes = {r.dispatch: r.n for r in recs}
+        assert sum(sizes.values()) == 11 and max(sizes.values()) <= 8
+        pads = sum(row_pad(8, n) for n in sizes.values())
+        assert pads > 0  # 11 frames cannot all ride device multiples
+        assert st.mesh_pad_rows == pads
         print("OK frame engine")
         """
     )
 
 
 def test_sharded_dispatch_through_pipeline_and_streaming():
-    """denoise_batch(sharded=True) and the streaming scan's sharded wrapper
-    agree with their single-device equivalents on a multi-device host."""
+    """Fused and streaming plans on a mesh agree with their single-device
+    plans on a multi-device host."""
     run_sub(
         """
         import jax, numpy as np
-        from repro.core import (BGConfig, add_gaussian_noise,
-                                bilateral_grid_filter_streaming, synthetic_batch)
-        from repro.data.pipeline import denoise_batch
+        from repro.core import BGConfig, add_gaussian_noise, synthetic_batch
+        from repro.plan import BGPlan
         from repro.sharding.bg_shard import batch_mesh
 
         assert jax.device_count() == 8
@@ -157,12 +171,11 @@ def test_sharded_dispatch_through_pipeline_and_streaming():
         imgs = add_gaussian_noise(synthetic_batch(5, 40, 55, seed=6), 30.0, seed=7)
 
         np.testing.assert_array_equal(
-            np.asarray(denoise_batch(imgs, cfg, sharded=True)),
-            np.asarray(denoise_batch(imgs, cfg, use_kernels=True)))
+            np.asarray(BGPlan(cfg=cfg, mesh=batch_mesh())(imgs)),
+            np.asarray(BGPlan(cfg=cfg)(imgs)))
 
-        out = bilateral_grid_filter_streaming(
-            imgs, cfg, sharded=True, mesh=batch_mesh(4))
-        ref = bilateral_grid_filter_streaming(imgs, cfg)
+        out = BGPlan(cfg=cfg, backend="streaming", mesh=batch_mesh(4))(imgs)
+        ref = BGPlan(cfg=cfg, backend="streaming")(imgs)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=1e-5)
         print("OK pipeline+streaming sharded")

@@ -10,7 +10,6 @@ from repro.core import (
     bilateral_filter,
     bilateral_grid_filter,
     bilateral_grid_filter_fixed,
-    bilateral_grid_filter_streaming,
     grid_blur,
     grid_create,
     grid_shape,
@@ -18,6 +17,7 @@ from repro.core import (
     psnr,
     synthetic_image,
 )
+from repro.plan import BGPlan
 
 H, W = 96, 128
 IMG = synthetic_image(H, W)
@@ -96,12 +96,17 @@ def test_classic_vs_paper_normalization_close():
 
 
 # ----------------------------------------------------- implementation parity
+def _streaming(img, c):
+    """The stripe-streaming scan (Fig. 4 dataflow), unquantized."""
+    return BGPlan(cfg=c, backend="streaming", quantize_output=False)(img)
+
+
 @pytest.mark.parametrize("mode", ["paper", "classic"])
 @pytest.mark.parametrize("r", [2, 5, 7, 12])
 def test_streaming_equals_batch(mode, r):
     c = cfg(r=r, normalize_mode=mode)
     batch = bilateral_grid_filter(NOISY, c, quantize_output=False)
-    stream = bilateral_grid_filter_streaming(NOISY, c, quantize_output=False)
+    stream = _streaming(NOISY, c)
     np.testing.assert_allclose(np.asarray(stream), np.asarray(batch), atol=1e-3)
 
 
@@ -109,7 +114,7 @@ def test_streaming_non_multiple_height():
     img = NOISY[: H - 5]
     c = cfg(r=7)
     batch = bilateral_grid_filter(img, c, quantize_output=False)
-    stream = bilateral_grid_filter_streaming(img, c, quantize_output=False)
+    stream = _streaming(img, c)
     np.testing.assert_allclose(np.asarray(stream), np.asarray(batch), atol=1e-3)
 
 

@@ -11,10 +11,13 @@ import pytest
 
 from repro.core import BGConfig, add_gaussian_noise
 from repro.data import synthetic_video
+from repro.plan import BGPlan
 from repro.serving import AsyncFrameEngine, StageRecord, async_engine
+from repro.sharding.bg_shard import batch_mesh
 from repro.video import MultiStreamPacker
 
 CFG = BGConfig(r=4, sigma_s=4.0, sigma_r=60.0)
+PLAN = BGPlan(cfg=CFG, mesh=batch_mesh())  # all local devices
 STAGES = (("t_submit", "t_taken"), ("t_taken", "t_closed"),
           ("t_closed", "t_launched"), ("t_launched", "t_resolved"))
 
@@ -34,7 +37,7 @@ def _by_dispatch(records):
 
 def test_stages_add_up_to_the_latency_exactly():
     frames = _frames(11)
-    with AsyncFrameEngine(CFG, max_batch=4, batch_window_ms=20.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=4, batch_window_ms=20.0) as eng:
         [f.result() for f in [eng.submit(f) for f in frames]]
         recs = eng.timeline()
     assert len(recs) == 11 and all(isinstance(r, StageRecord) for r in recs)
@@ -48,7 +51,7 @@ def test_stages_add_up_to_the_latency_exactly():
 
 def test_frames_of_one_batch_share_its_dispatch_and_stamps():
     frames = _frames(10)
-    with AsyncFrameEngine(CFG, max_batch=4, batch_window_ms=30.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=4, batch_window_ms=30.0) as eng:
         [f.result() for f in [eng.submit(f) for f in frames]]
         recs = eng.timeline()
         st = eng.stats()
@@ -65,7 +68,7 @@ def test_frames_of_one_batch_share_its_dispatch_and_stamps():
 def test_ring_stays_bounded(monkeypatch):
     monkeypatch.setattr(async_engine, "TIMELINE_SIZE", 5)
     frames = _frames(3)
-    with AsyncFrameEngine(CFG, max_batch=2, batch_window_ms=1.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=2, batch_window_ms=1.0) as eng:
         futs = [eng.submit(frames[i % 3]) for i in range(12)]
         [f.result() for f in futs]
         recs = eng.timeline()
@@ -79,7 +82,7 @@ def test_ring_stays_bounded(monkeypatch):
 
 def test_stats_are_read_from_the_ring():
     frames = _frames(9)
-    with AsyncFrameEngine(CFG, max_batch=4, batch_window_ms=10.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=4, batch_window_ms=10.0) as eng:
         [f.result() for f in [eng.submit(f) for f in frames]]
         recs = eng.timeline()
         st = eng.stats()
@@ -93,10 +96,10 @@ def test_stats_are_read_from_the_ring():
 
 
 def test_failed_launch_is_recorded_without_a_launch_stamp():
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(PLAN)
     packer.open("ok", alpha=0.0)
     frames = _frames(2)
-    with AsyncFrameEngine(CFG, max_batch=2, batch_window_ms=5.0,
+    with AsyncFrameEngine(max_batch=2, batch_window_ms=5.0,
                           packer=packer) as eng:
         with pytest.raises(KeyError):
             eng.submit(frames[0], stream_id="ghost").result(timeout=60.0)
@@ -113,10 +116,10 @@ def test_failed_launch_is_recorded_without_a_launch_stamp():
 def test_video_mode_stamps_packs():
     sids = ("s0", "s1", "s2")
     frames = {s: _frames(4, seed=i * 7) for i, s in enumerate(sids)}
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(PLAN)
     for s in sids:
         packer.open(s, alpha=0.5)
-    with AsyncFrameEngine(CFG, max_batch=3, batch_window_ms=40.0,
+    with AsyncFrameEngine(max_batch=3, batch_window_ms=40.0,
                           packer=packer) as eng:
         futs = [eng.submit(frames[s][t], stream_id=s)
                 for t in range(4) for s in sids]
@@ -140,7 +143,7 @@ def test_engine_spans_reach_the_profiler(tmp_path):
     frames = _frames(4)
     jax.profiler.start_trace(str(tmp_path))
     try:
-        with AsyncFrameEngine(CFG, max_batch=2, batch_window_ms=20.0) as eng:
+        with AsyncFrameEngine(plan=PLAN, max_batch=2, batch_window_ms=20.0) as eng:
             [f.result() for f in [eng.submit(f) for f in frames]]
             dispatches = eng.stats().dispatches
     finally:

@@ -6,14 +6,9 @@ import pytest
 
 from repro.core import BGConfig, add_gaussian_noise, synthetic_image
 from repro.core.bilateral_grid import grid_normalize
-from repro.kernels import (
-    bg_blur,
-    bg_create,
-    bg_fused,
-    bg_slice,
-    bilateral_grid_filter_pallas,
-)
+from repro.kernels import bg_blur, bg_create, bg_fused, bg_slice
 from repro.kernels.ref import ref_blur, ref_create, ref_fused, ref_slice
+from repro.plan import BGPlan
 
 SHAPES = [(32, 32), (61, 83), (96, 128), (45, 200)]
 PARAMS = [
@@ -81,8 +76,9 @@ def test_dtype_sweep_full_pipeline(dtype):
     cfg = BGConfig(r=7, sigma_s=4.0, sigma_r=50.0)
     img32 = _img(61, 83, jnp.float32)
     img = img32.astype(dtype)
-    out = bilateral_grid_filter_pallas(img, cfg, interpret=True)
-    ref = bilateral_grid_filter_pallas(img32, cfg, interpret=True)
+    plan = BGPlan(cfg=cfg, backend="fused", interpret=True)
+    out = plan(img)
+    ref = plan(img32)
     diff = np.abs(np.asarray(out) - np.asarray(ref))
     assert np.mean(diff <= 1.0) > 0.99
 
@@ -93,7 +89,9 @@ def test_pipeline_wrapper_matches_core(fused):
 
     cfg = BGConfig(r=7, sigma_s=4.0, sigma_r=50.0)
     img = _img(61, 83)
-    k = bilateral_grid_filter_pallas(img, cfg, fused=fused, interpret=True)
+    plan = BGPlan(cfg=cfg, backend="fused" if fused else "staged",
+                  interpret=True)
+    k = plan(img)
     ref = bilateral_grid_filter(img, cfg)
     diff = np.abs(np.asarray(k) - np.asarray(ref))
     # float-accumulation order differs; quantized outputs may flip 1 LSB rarely

@@ -1,13 +1,14 @@
-"""The plan layer (repro.plan): legacy-kwarg equivalence matrix + the
-compiled-executable cache + construction-time validation + auto-tuning.
+"""The plan layer (repro.plan): equivalence matrix + the compiled-executable
+cache + construction-time validation + auto-tuning.
 
-Equivalence contract: every combination of legacy dispatch kwargs the shims
-accept must route through ``BGPlan`` to outputs **bit-identical** to the
-pre-refactor code paths. The pre-refactor routes are reconstructed here from
-the primitives they composed (``jax.vmap(bilateral_grid_filter)``,
-``quantize_intensity(bg_fused_kernel_call(...))``, the staged temporal jnp
-pipeline), so this matrix keeps gating even though the old layer-local
-dispatch code is gone.
+Equivalence contract: every backend, temporal form and input shape, reached
+through a ``BGPlan`` (built directly, resolved by ``plan_for``, or called by
+``temporal_denoise`` / the video packer), gives outputs **bit-identical** to
+the pre-refactor code paths. The pre-refactor routes are reconstructed here
+from the primitives they composed (``jax.vmap(bilateral_grid_filter)``,
+``quantize_intensity(bg_fused_kernel_call(...))``, the staged kernels, the
+staged temporal jnp pipeline), so this matrix keeps gating even though the
+old layer-local dispatch code is gone.
 
 Cache contract: repeated dispatches of one plan (from any layer) hit one
 compiled executable — equal plans share the executable object, and the
@@ -35,9 +36,6 @@ from repro.core.bilateral_grid import (
     grid_slice,
     quantize_intensity,
 )
-from repro.core.streaming import bilateral_grid_filter_streaming
-from repro.data import denoise_batch
-from repro.kernels import bilateral_grid_filter_pallas
 from repro.kernels.bg_fused import bg_fused_kernel_call
 from repro.kernels.ops import _staged_single
 from repro.plan import (
@@ -91,8 +89,7 @@ def test_reference_matrix(b):
     imgs = _frames(b)
     ref = np.asarray(_pre_reference(imgs))
     for out in (
-        denoise_batch(imgs, CFG),  # legacy kwargs
-        denoise_batch(imgs, plan=BGPlan(cfg=CFG, backend="reference")),
+        plan_for(CFG, H, W, backend="reference")(imgs),
         BGPlan(cfg=CFG, backend="reference")(imgs),
     ):
         np.testing.assert_array_equal(ref, np.asarray(out))
@@ -105,24 +102,19 @@ def test_fused_matrix(b, stream):
     ref = np.asarray(_pre_fused(imgs, stream_input=stream))
     backend = "fused_streamed" if stream else "fused"
     plan = BGPlan(cfg=CFG, backend=backend, interpret=True)
-    for out in (
-        denoise_batch(imgs, CFG, use_kernels=True, stream_input=stream)
-        if not stream  # legacy denoise_batch never set interpret; fused only
-        else bilateral_grid_filter_pallas(
-            imgs, CFG, stream_input=True, interpret=True
-        ),
-        denoise_batch(imgs, plan=plan),
-        plan(imgs),
-    ):
+    resolved = plan_for(CFG, H, W, stream_input=stream, batch_tile=4,
+                        sharded=False, interpret=True)
+    assert resolved == plan.with_tile(4)  # the kernel's default tile, pinned
+    for out in (resolved(imgs), plan(imgs)):
         np.testing.assert_array_equal(ref, np.asarray(out))
 
 
 def test_single_frame_and_color_matrix():
-    # single (h, w) frame through the kwarg shim and the plan
+    # single (h, w) frame through the auto-interpret and pinned plans
     img = _frames(1)[0]
     ref1 = np.asarray(_pre_fused(img))
     np.testing.assert_array_equal(
-        ref1, np.asarray(bilateral_grid_filter_pallas(img, CFG, interpret=True))
+        ref1, np.asarray(BGPlan(cfg=CFG, backend="fused")(img))
     )
     np.testing.assert_array_equal(
         ref1, np.asarray(BGPlan(cfg=CFG, backend="fused", interpret=True)(img))
@@ -134,7 +126,7 @@ def test_single_frame_and_color_matrix():
     ref = np.moveaxis(ref, 1, -1)
     plan = BGPlan(cfg=CFG, backend="fused", interpret=True)
     np.testing.assert_array_equal(
-        ref, np.asarray(denoise_batch(rgb, CFG, use_kernels=True))
+        ref, np.asarray(BGPlan(cfg=CFG, backend="fused")(rgb))
     )
     np.testing.assert_array_equal(ref, np.asarray(plan(rgb)))
 
@@ -147,29 +139,29 @@ def test_staged_matrix():
         ),
         CFG,
     )
-    out_b = bilateral_grid_filter_pallas(imgs, CFG, fused=False, interpret=True)
+    plan = BGPlan(cfg=CFG, backend="staged", interpret=True)
+    out_b = plan(imgs)
     np.testing.assert_array_equal(np.asarray(ref_b), np.asarray(out_b))
     # single frame: the pre-plan route did NOT vmap
     ref_1 = quantize_intensity(
         _staged_single(jnp.asarray(imgs[0], jnp.float32), CFG, True), CFG
     )
-    out_1 = bilateral_grid_filter_pallas(imgs[0], CFG, fused=False, interpret=True)
+    out_1 = plan(imgs[0])
     np.testing.assert_array_equal(np.asarray(ref_1), np.asarray(out_1))
 
 
 @pytest.mark.parametrize("b", [1, 3])
 def test_streaming_matrix(b):
     imgs = _frames(b, seed=11)
-    legacy = bilateral_grid_filter_streaming(imgs, CFG)
     plan = BGPlan(cfg=CFG, backend="streaming")
-    np.testing.assert_array_equal(np.asarray(legacy), np.asarray(plan(imgs)))
+    out = plan(imgs)
     np.testing.assert_array_equal(
-        np.asarray(legacy),
-        np.asarray(bilateral_grid_filter_streaming(imgs, plan=plan)),
+        np.asarray(out),
+        np.asarray(plan_for(CFG, H, W, backend="streaming", sharded=False)(imgs)),
     )
     # the streaming scan is exactly the whole-image reference
     np.testing.assert_array_equal(
-        np.asarray(legacy), np.asarray(_pre_reference(imgs))
+        np.asarray(out), np.asarray(_pre_reference(imgs))
     )
 
 
@@ -188,16 +180,14 @@ def test_temporal_fused_matrix(n):
         alpha=jnp.asarray(alpha),
     )
     ref_out = np.asarray(quantize_intensity(ref_out, CFG))
-    # legacy kwargs route; the 1-device mesh pins the single-device dispatch
-    # geometry on multi-device hosts (carry bits are only ulp-stable across
-    # geometries — the PR-4 contract; mesh plans are gated in the
-    # multi-device subprocess test with the atol'd carry)
-    from repro.sharding.bg_shard import batch_mesh
-
-    out_l, carry_l = temporal_denoise(
-        frames, CFG, carry=carry, alpha=alpha, interpret=True, mesh=batch_mesh(1)
-    )
-    # plan route (same dispatch geometry -> carry bitwise too)
+    # plan_for's route, single-device (carry bits are only ulp-stable across
+    # dispatch geometries — the PR-4 contract; mesh plans are gated in the
+    # multi-device subprocess test with the atol'd carry), the tile pinned
+    # to the kernel default's clamp
+    resolved = plan_for(CFG, H, W, n_frames=n, temporal=True, batch_tile=n,
+                        sharded=False, interpret=True)
+    out_l, carry_l = resolved(frames, carry=carry, alpha=alpha)
+    # the base plan through temporal_denoise (same geometry -> carry bitwise)
     plan = BGPlan(cfg=CFG, backend="fused", interpret=True)
     out_p, carry_p = temporal_denoise(frames, carry=carry, alpha=alpha, plan=plan)
     direct = plan.with_options(temporal=True)(
@@ -216,7 +206,10 @@ def test_temporal_staged_matrix():
     ref_out, ref_carry = _pre_temporal_staged(
         jnp.asarray(frames), jnp.asarray(carry), jnp.asarray(alpha), CFG
     )
-    out, new_c = temporal_denoise(frames, CFG, carry=carry, alpha=alpha, staged=True)
+    out, new_c = temporal_denoise(
+        frames, plan=BGPlan(cfg=CFG, backend="reference"), carry=carry,
+        alpha=alpha,
+    )
     np.testing.assert_array_equal(np.asarray(ref_out), np.asarray(out))
     np.testing.assert_array_equal(np.asarray(ref_carry), np.asarray(new_c))
 
@@ -230,20 +223,22 @@ def test_temporal_cold_shortcut_matches_per_frame():
 
 
 def test_packer_asks_plan_for_tile():
-    """A plan-built packer needs no batch_tile= threading and matches the
-    legacy packer (which pinned batch_tile) bit-for-bit on the image."""
+    """A packer on plan_for's plan asks it for the tile and matches a packer
+    on a plan with the tile pinned by hand bit-for-bit on the image."""
     n = 3
     plan = plan_for(CFG, H, W, n_frames=n, temporal=True, sharded=False,
                     interpret=True)
     assert plan.batch_tile == n  # whole pack in one macro-pipeline sweep
-    legacy = MultiStreamPacker(CFG, batch_tile=n, interpret=True)
+    pinned = MultiStreamPacker(
+        BGPlan(cfg=CFG, backend="fused", batch_tile=n, interpret=True)
+    )
     modern = MultiStreamPacker(plan=plan)
-    for p in (legacy, modern):
+    for p in (pinned, modern):
         for s in range(n):
             p.open(s, alpha=0.5)
     for t in range(3):
         frames = {s: _frames(1, seed=100 * t + s)[0] for s in range(n)}
-        out_l = legacy.pack(frames)
+        out_l = pinned.pack(frames)
         out_m = modern.pack(frames)
         for s in range(n):
             np.testing.assert_array_equal(
@@ -273,8 +268,8 @@ def test_repeat_dispatches_hit_one_compiled_executable():
     for _ in range(3):
         jax.block_until_ready(plan(imgs))
     assert fn._cache_size() == 1  # one executable for repeat dispatches
-    # layers share it: the pipeline entry dispatches the same plan
-    jax.block_until_ready(denoise_batch(imgs, plan=plan))
+    # layers share it: the video entry's cold path dispatches the same plan
+    jax.block_until_ready(temporal_denoise(imgs, plan=plan)[0])
     assert fn._cache_size() == 1
     # a new batch shape is a new executable entry, nothing more
     jax.block_until_ready(plan(_frames(3, seed=52)))
@@ -383,16 +378,14 @@ def test_packer_rejects_input_streamed_plan():
 
 
 def test_engines_reject_mismatched_plans():
-    from repro.serving import AsyncFrameEngine, FrameDenoiseEngine
+    from repro.serving import AsyncFrameEngine
 
     raw = BGPlan(cfg=CFG, backend="fused", quantize_output=False)
-    with pytest.raises(ValueError, match="quantized"):
-        FrameDenoiseEngine(plan=raw)
     with pytest.raises(ValueError, match="quantized"):
         AsyncFrameEngine(plan=raw)
     # video mode dispatches the packer's plan; a second plan must not be
     # silently ignored
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(BGPlan(cfg=CFG))
     other = BGPlan(cfg=CFG, backend="fused", interpret=True)
     with pytest.raises(ValueError, match="packer"):
         AsyncFrameEngine(plan=other, packer=packer)

@@ -13,10 +13,10 @@ from repro.core import (
     bilateral_filter,
     bilateral_grid_filter,
     bilateral_grid_filter_fixed,
-    bilateral_grid_filter_streaming,
     grid_create,
     mssim,
 )
+from repro.plan import BGPlan
 
 SETTINGS = dict(max_examples=20, deadline=None)
 
@@ -113,7 +113,7 @@ def test_constant_image_invariance(level, h, w, r, ss, sr):
 def test_streaming_equals_batch_property(img, r, ss, sr):
     c = BGConfig(r=r, sigma_s=ss, sigma_r=sr)
     a = bilateral_grid_filter(img, c, quantize_output=False)
-    b = bilateral_grid_filter_streaming(img, c, quantize_output=False)
+    b = BGPlan(cfg=c, backend="streaming", quantize_output=False)(img)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3)
 
 

@@ -47,11 +47,14 @@ from repro.reliability import (
     validate_frame,
 )
 from repro.serving import AsyncFrameEngine
+from repro.sharding.bg_shard import batch_mesh
 from repro.video import MultiStreamPacker
 
 from benchmarks.bench_bg_chaos import chaos_soak, default_fault_plan
 
 CFG = BGConfig(r=4, sigma_s=4.0, sigma_r=60.0)
+# the service plan: a batch mesh over all local devices (one device: none)
+PLAN = BGPlan(cfg=CFG, mesh=batch_mesh())
 
 _TIMING_SKIP_LOAD = 4.0
 
@@ -133,7 +136,7 @@ def test_carry_faults_and_plan_hook():
     """apply_carry_faults mutates exactly the matched streams' sessions; the
     plan_hook contextmanager fires on_dispatch from BGPlan.__call__ and
     restores the previous hook on exit."""
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(PLAN)
     packer.open("w", alpha=0.6)
     packer.open("c", alpha=0.0)
     frames = _frames(2)
@@ -190,7 +193,7 @@ def test_admission_validation():
 def test_engine_rejects_bad_frames_at_submit():
     """A NaN frame never enters the pipeline: submit raises, nothing is
     queued, and the engine's counters don't move."""
-    with AsyncFrameEngine(CFG, max_batch=4, batch_window_ms=5.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=4, batch_window_ms=5.0) as eng:
         with pytest.raises(AdmissionError):
             eng.submit(np.full((32, 48), np.nan, np.float32))
         st = eng.stats()
@@ -339,7 +342,7 @@ def test_nan_frame_poisons_carry_without_guards():
     the EMA never forgets. ``quarantine`` is the cure: reset to cold, and
     the next clean frame serves finite again."""
     frames = _frames(6, seed=5)
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(PLAN)
     packer.open("s", alpha=0.7)
     out = packer.pack({"s": frames[0]})["s"]
     assert np.isfinite(np.asarray(out)).all()
@@ -369,7 +372,7 @@ def test_pack_guarded_flags():
     frames = _frames(1)
     nan_frame = frames[0].copy()
     nan_frame[0, 0] = np.nan
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(PLAN)
     packer.open("bad", alpha=0.6)
     packer.open("good", alpha=0.6)
     packer.open("cold", alpha=0.0)
@@ -482,7 +485,7 @@ def test_engine_fallback_serves_when_kernel_backend_dies():
         )
     )
     with AsyncFrameEngine(
-        CFG, max_batch=2, batch_window_ms=5.0,
+        plan=PLAN, max_batch=2, batch_window_ms=5.0,
         retry_policy=RetryPolicy(max_attempts=2, backoff_s=0.0),
     ) as eng:
         eng.fault_injector = inj
@@ -503,7 +506,7 @@ def test_engine_fallback_disabled_fails_requests():
         FaultPlan(faults=(Fault(kind="raise_dispatch", times=None),))
     )
     with AsyncFrameEngine(
-        CFG, max_batch=1, batch_window_ms=2.0, fallback=False,
+        plan=PLAN, max_batch=1, batch_window_ms=2.0, fallback=False,
         retry_policy=RetryPolicy(max_attempts=2, backoff_s=0.0),
     ) as eng:
         eng.fault_injector = inj
@@ -530,7 +533,7 @@ def test_watchdog_transient_hang_recovers_via_redispatch():
         )
     )
     with AsyncFrameEngine(
-        CFG, max_batch=1, batch_window_ms=2.0, watchdog_ms=400.0 * relax,
+        plan=PLAN, max_batch=1, batch_window_ms=2.0, watchdog_ms=400.0 * relax,
     ) as eng:
         eng.fault_injector = inj
         assert np.isfinite(
@@ -558,7 +561,7 @@ def test_watchdog_persistent_hang_fails_structurally():
         )
     )
     with AsyncFrameEngine(
-        CFG, max_batch=1, batch_window_ms=2.0, watchdog_ms=300.0 * relax,
+        plan=PLAN, max_batch=1, batch_window_ms=2.0, watchdog_ms=300.0 * relax,
         fallback=False,
         retry_policy=RetryPolicy(max_attempts=1, backoff_s=0.0),
     ) as eng:
@@ -583,7 +586,7 @@ def test_expired_deadline_is_shed():
     with DeadlineExceeded instead of being dispatched (a negative budget
     makes the expiry deterministic — no wall-clock race)."""
     frames = _frames(2)
-    with AsyncFrameEngine(CFG, max_batch=4, batch_window_ms=2.0) as eng:
+    with AsyncFrameEngine(plan=PLAN, max_batch=4, batch_window_ms=2.0) as eng:
         fut = eng.submit(frames[0], deadline_ms=-1000.0)
         with pytest.raises(DeadlineExceeded) as exc:
             fut.result(timeout=60.0)
@@ -612,7 +615,7 @@ def test_close_joins_threads_even_with_full_queue():
         )
     )
     eng = AsyncFrameEngine(
-        CFG, max_batch=1, max_queue=1, max_inflight=1, batch_window_ms=0.0
+        plan=PLAN, max_batch=1, max_queue=1, max_inflight=1, batch_window_ms=0.0
     )
     eng.fault_injector = inj
     futs = [eng.submit(frames[0], block=True, timeout=30.0) for _ in range(4)]
@@ -632,7 +635,7 @@ def test_close_joins_threads_even_with_full_queue():
 
 
 def test_submit_after_close_raises_engine_closed():
-    eng = AsyncFrameEngine(CFG, max_batch=1)
+    eng = AsyncFrameEngine(plan=PLAN, max_batch=1)
     eng.close()
     with pytest.raises(EngineClosed):  # an EngineClosed IS a RuntimeError
         eng.submit(_frames(1)[0])

@@ -15,7 +15,8 @@ from repro.core import (
     mssim,
     synthetic_image,
 )
-from repro.data import denoise_batch, lm_batches, vlm_preprocess
+from repro.data import lm_batches, spectrogram_denoise, vlm_preprocess
+from repro.plan import BGPlan
 from repro.serving import Request, ServeEngine
 from repro.train import OptConfig, Trainer
 
@@ -58,13 +59,16 @@ def test_bg_denoise_in_data_pipeline():
     noisy = jnp.stack(
         [add_gaussian_noise(clean[i], 30.0, seed=10 + i) for i in range(3)]
     )
-    cfg = BGConfig(r=4, sigma_s=3.0, sigma_r=50.0)
-    den = denoise_batch(noisy, cfg)
+    plan = BGPlan(cfg=BGConfig(r=4, sigma_s=3.0, sigma_r=50.0),
+                  backend="reference")
+    den = plan(noisy)
     for i in range(3):
         assert float(mssim(clean[i], den[i])) > float(mssim(clean[i], noisy[i]))
-    ctx = vlm_preprocess(noisy, cfg, patch=16, dim=32)
+    ctx = vlm_preprocess(noisy, plan, patch=16, dim=32)
     assert ctx.shape == (3, (64 // 16) * (96 // 16), 32)
     assert bool(jnp.all(jnp.isfinite(ctx)))
+    spec = spectrogram_denoise(noisy)  # the [audio] stage's default plan
+    assert spec.shape == noisy.shape and bool(jnp.all(jnp.isfinite(spec)))
 
 
 def test_paper_workload_presets():

@@ -32,6 +32,8 @@ from repro.core import BGConfig, add_gaussian_noise
 from repro.core.bilateral_grid import quantize_intensity
 from repro.data import synthetic_video
 from repro.kernels import bg_fused
+from repro.plan import BGPlan
+from repro.sharding.bg_shard import batch_mesh
 from repro.video import MultiStreamPacker, blurred_grid_batch, carry_shape, temporal_denoise
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -109,12 +111,13 @@ def test_warm_chained_matches_staged_oracle(shape, n):
     for t in range(4):
         frames = _noisy_stack(n, h, w, seed=31 * t)
         of, cf = temporal_denoise(
-            frames, CFG, carry=cf, alpha=alpha, interpret=True,
-            quantize_output=False,
+            frames, carry=cf, alpha=alpha,
+            plan=BGPlan(cfg=CFG, mesh=batch_mesh(), interpret=True,
+                        quantize_output=False),
         )
         os_, cs = temporal_denoise(
-            frames, CFG, carry=cs, alpha=alpha, staged=True,
-            quantize_output=False,
+            frames, carry=cs, alpha=alpha,
+            plan=BGPlan(cfg=CFG, backend="reference", quantize_output=False),
         )
         np.testing.assert_allclose(
             np.asarray(of), np.asarray(os_), atol=5e-3, rtol=0.0
@@ -161,7 +164,9 @@ def test_mixed_pack_is_single_dispatch(monkeypatch):
         return real(*args, **kwargs)
 
     monkeypatch.setattr(session_mod, "temporal_denoise", counting)
-    packer = MultiStreamPacker(CFG, interpret=True)
+    packer = MultiStreamPacker(
+        BGPlan(cfg=CFG, mesh=batch_mesh(), interpret=True)
+    )
     packer.open("cold", alpha=0.0)
     packer.open("warm", alpha=0.6)
     packer.open("fresh", alpha=0.4)  # first frame: no history yet
@@ -286,12 +291,18 @@ def test_temporal_sharded_identical_1_vs_8_devices():
         import jax, numpy as np, jax.numpy as jnp
         from repro.core import BGConfig, add_gaussian_noise
         from repro.data import synthetic_video
-        from repro.sharding.bg_shard import batch_mesh, bg_temporal_sharded
+        from repro.plan import BGPlan
+        from repro.sharding.bg_shard import batch_mesh
         from repro.video import carry_shape
 
         assert jax.device_count() == 8
         cfg = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
         h, w = 45, 55
+
+        def tplan(nd, quantize=False):
+            return BGPlan(cfg=cfg, temporal=True, mesh=batch_mesh(nd),
+                          interpret=True, quantize_output=quantize)
+
         rng = np.random.default_rng(1)
         for n, nd in [(8, 8), (5, 4), (3, 8), (1, 8), (7, 2)]:
             vid = synthetic_video(n, n, h, w, motion=1.5)
@@ -300,16 +311,10 @@ def test_temporal_sharded_identical_1_vs_8_devices():
             carry = jnp.asarray(rng.uniform(
                 0.0, 4.0, (n,) + carry_shape(h, w, cfg)).astype(np.float32))
             alpha = jnp.asarray(rng.uniform(0.0, 0.9, (n,)).astype(np.float32))
-            ref_o, ref_c = bg_temporal_sharded(
-                frames, carry, alpha, cfg, mesh=batch_mesh(1), interpret=True)
-            out, new_c = bg_temporal_sharded(
-                frames, carry, alpha, cfg, mesh=batch_mesh(nd), interpret=True)
-            q_ref, _ = bg_temporal_sharded(
-                frames, carry, alpha, cfg, mesh=batch_mesh(1), interpret=True,
-                quantize_output=True)
-            q_out, _ = bg_temporal_sharded(
-                frames, carry, alpha, cfg, mesh=batch_mesh(nd), interpret=True,
-                quantize_output=True)
+            ref_o, ref_c = tplan(1)(frames, carry=carry, alpha=alpha)
+            out, new_c = tplan(nd)(frames, carry=carry, alpha=alpha)
+            q_ref, _ = tplan(1, True)(frames, carry=carry, alpha=alpha)
+            q_out, _ = tplan(nd, True)(frames, carry=carry, alpha=alpha)
             np.testing.assert_array_equal(np.asarray(q_out), np.asarray(q_ref))
             np.testing.assert_array_max_ulp(
                 np.asarray(out), np.asarray(ref_o), maxulp=4)
@@ -317,11 +322,12 @@ def test_temporal_sharded_identical_1_vs_8_devices():
                 np.asarray(new_c), np.asarray(ref_c), maxulp=4)
             print(f"OK n={n} nd={nd}")
 
-        # the packer auto-meshes over all 8 devices; a mixed pack must stay
-        # one dispatch and cold rows bit-identical to the per-frame service
+        # a packer on an 8-device plan: a mixed pack must stay one
+        # dispatch and cold rows bit-identical to the per-frame service
         from repro.kernels import bg_fused
         from repro.video import MultiStreamPacker
-        packer = MultiStreamPacker(cfg, interpret=True)
+        packer = MultiStreamPacker(
+            BGPlan(cfg=cfg, mesh=batch_mesh(), interpret=True))
         packer.open("cold", alpha=0.0)
         packer.open("warm", alpha=0.6)
         vid = synthetic_video(3, 2, h, w, motion=1.5)

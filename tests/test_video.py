@@ -6,7 +6,8 @@ The contracts under test:
     temporal subsystem must cost nothing when switched off;
   * a warm-up pack (``alpha > 0``, no history) is bit-identical to the
     per-frame fused path (effective alpha 0 on the fused temporal kernel),
-    while the ``staged=True`` oracle still equals the jnp reference exactly;
+    while the staged oracle (``BGPlan(backend="reference")``) still equals
+    the jnp reference exactly;
   * on a static scene, PSNR improves monotonically with alpha (the EMA
     accumulates evidence instead of flickering);
   * per-stream carries never leak across streams in the multi-stream packer;
@@ -20,6 +21,8 @@ from repro.core import BGConfig, add_gaussian_noise, bilateral_grid_filter, psnr
 from repro.core.bilateral_grid import quantize_intensity
 from repro.data import synthetic_video
 from repro.kernels import bg_fused
+from repro.plan import BGPlan
+from repro.sharding.bg_shard import batch_mesh
 from repro.video import (
     MultiStreamPacker,
     blurred_grid_batch,
@@ -28,6 +31,9 @@ from repro.video import (
 )
 
 CFG = BGConfig(r=6, sigma_s=4.0, sigma_r=60.0)
+# the fused service plan, on a batch mesh over all local devices
+PLAN = BGPlan(cfg=CFG, mesh=batch_mesh(), interpret=True)
+STAGED = BGPlan(cfg=CFG, backend="reference")  # the staged jnp oracle
 
 # ragged (h, w) wrt r=6, stream counts covering n == 1 and odd packs
 RAGGED_PACKS = [((45, 55), 1), ((45, 55), 3), ((33, 47), 5)]
@@ -45,7 +51,7 @@ def test_alpha0_bit_identical_to_fused_per_frame(shape, n):
     h, w = shape
     assert h % CFG.r and w % CFG.r  # genuinely ragged
     frames = _noisy_stack(n, h, w)
-    out, carry = temporal_denoise(frames, CFG, alpha=0.0, interpret=True)
+    out, carry = temporal_denoise(frames, plan=PLAN, alpha=0.0)
     assert carry is None  # nothing temporal was computed
     ref = quantize_intensity(bg_fused(frames, CFG, interpret=True), CFG)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -53,7 +59,7 @@ def test_alpha0_bit_identical_to_fused_per_frame(shape, n):
 
 def test_alpha0_single_frame_squeeze():
     frame = _noisy_stack(1, 45, 55)[0]
-    out, carry = temporal_denoise(frame, CFG, alpha=0.0, interpret=True)
+    out, carry = temporal_denoise(frame, plan=PLAN, alpha=0.0)
     assert out.shape == frame.shape and carry is None
     ref = quantize_intensity(bg_fused(frame, CFG, interpret=True), CFG)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -62,15 +68,15 @@ def test_alpha0_single_frame_squeeze():
 def test_warmup_pack_matches_fused_per_frame():
     """alpha > 0 with no history: effective alpha 0 on the fused temporal
     kernel — bit-identical to the per-frame fused path, and must emit a
-    carry. The staged oracle (staged=True) still equals the jnp reference
+    carry. The staged oracle (the reference plan) still equals the jnp reference
     exactly, and the fused carry tracks the staged carry."""
     frames = _noisy_stack(3, 45, 55)
-    out, carry = temporal_denoise(frames, CFG, alpha=0.5, interpret=True)
+    out, carry = temporal_denoise(frames, plan=PLAN, alpha=0.5)
     assert carry.shape == (3,) + carry_shape(45, 55, CFG)
     ref = quantize_intensity(bg_fused(frames, CFG, interpret=True), CFG)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
-    out_s, carry_s = temporal_denoise(frames, CFG, alpha=0.5, staged=True)
+    out_s, carry_s = temporal_denoise(frames, plan=STAGED, alpha=0.5)
     ref_s = jnp.stack([bilateral_grid_filter(frames[i], CFG) for i in range(3)])
     np.testing.assert_array_equal(np.asarray(out_s), np.asarray(ref_s))
     np.testing.assert_allclose(
@@ -95,10 +101,10 @@ def test_alpha_validation():
     frames = _noisy_stack(2, 33, 47)
     for bad in (-0.1, 1.0, 1.5):
         with pytest.raises(ValueError):
-            temporal_denoise(frames, CFG, alpha=bad)
+            temporal_denoise(frames, plan=PLAN, alpha=bad)
     with pytest.raises(ValueError):  # carry/frames stream mismatch
         carry = jnp.zeros((3,) + carry_shape(33, 47, CFG))
-        temporal_denoise(frames, CFG, carry=carry, alpha=0.5)
+        temporal_denoise(frames, plan=PLAN, carry=carry, alpha=0.5)
 
 
 def test_static_scene_psnr_monotone_in_alpha():
@@ -106,7 +112,7 @@ def test_static_scene_psnr_monotone_in_alpha():
     clean = synthetic_video(1, 1, 48, 64, motion=0.0)[0]
     vals = []
     for alpha in (0.0, 0.3, 0.6, 0.8):
-        packer = MultiStreamPacker(cfg)
+        packer = MultiStreamPacker(BGPlan(cfg=cfg, mesh=batch_mesh()))
         packer.open(0, alpha=alpha)
         for t in range(12):
             out = packer.pack({0: add_gaussian_noise(clean, 30.0, seed=100 + t)})[0]
@@ -120,10 +126,10 @@ def test_packer_no_cross_stream_leak():
     cfg = BGConfig(r=4, sigma_s=4.0, sigma_r=60.0)
     nA = _noisy_stack(5, 40, 56, seed=3)
     nB = _noisy_stack(5, 40, 56, seed=7)
-    solo = MultiStreamPacker(cfg)
+    solo = MultiStreamPacker(BGPlan(cfg=cfg, mesh=batch_mesh()))
     solo.open("A", alpha=0.5)
     solo_out = [solo.pack({"A": nA[t]})["A"] for t in range(5)]
-    duo = MultiStreamPacker(cfg)
+    duo = MultiStreamPacker(BGPlan(cfg=cfg, mesh=batch_mesh()))
     duo.open("A", alpha=0.5)
     duo.open("B", alpha=0.7)
     for t in range(5):
@@ -139,7 +145,7 @@ def test_packer_mixed_alpha_and_zero_alpha_carry_free():
     bits must not depend on it); mixed packs still advance the temporal
     sessions; an all-zero-alpha pack is the fused path (no carries
     materialized anywhere)."""
-    packer = MultiStreamPacker(CFG, interpret=True)
+    packer = MultiStreamPacker(PLAN)
     packer.open("warm", alpha=0.6)
     packer.open("cold", alpha=0.0)
     frames = _noisy_stack(2, 33, 47)
@@ -152,7 +158,7 @@ def test_packer_mixed_alpha_and_zero_alpha_carry_free():
     assert packer.sessions["warm"].carry is not None
     assert packer.sessions["cold"].carry is None
 
-    allzero = MultiStreamPacker(CFG, interpret=True)
+    allzero = MultiStreamPacker(PLAN)
     allzero.open(0)
     allzero.open(1)
     out = allzero.pack({0: frames[0], 1: frames[1]})
@@ -163,7 +169,7 @@ def test_packer_mixed_alpha_and_zero_alpha_carry_free():
 
 
 def test_packer_errors():
-    packer = MultiStreamPacker(CFG)
+    packer = MultiStreamPacker(BGPlan(cfg=CFG, mesh=batch_mesh()))
     packer.open("a", alpha=0.2)
     with pytest.raises(ValueError):
         packer.open("a")  # double open
