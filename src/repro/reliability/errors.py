@@ -92,16 +92,20 @@ class DeadlineExceeded(ReliabilityError):
 
 class NonFiniteOutput(ReliabilityError):
     """The post-dispatch finite-guard caught NaN/Inf in this request's
-    output frame — the frame is withheld (a structured error beats silently
+    output frame, or (``carry=True``) its stream's advanced temporal carry
+    failed the carry guard, so the frame was computed from a poisoned
+    state — the frame is withheld (a structured error beats silently
     serving corrupted pixels)."""
 
-    def __init__(self, uid: int, *, stream_id: Hashable = None):
+    def __init__(self, uid: int, *, stream_id: Hashable = None,
+                 carry: bool = False):
         self.uid = uid
         self.stream_id = stream_id
+        self.carry = carry
         sid = "" if stream_id is None else f" (stream {stream_id!r})"
-        super().__init__(
-            f"request {uid}{sid}: output frame contains non-finite values"
-        )
+        what = ("the stream's temporal carry failed its guard" if carry
+                else "output frame contains non-finite values")
+        super().__init__(f"request {uid}{sid}: {what}")
 
 
 class AllBackendsFailed(ReliabilityError):

@@ -8,7 +8,14 @@ host->device feeding (stacking batch N+1 while batch N computes), and a
 completion thread resolves futures: many producers, bounded queues for
 backpressure, latency budgets, and multi-stream video with per-stream
 temporal carries. A caller that wants one synchronous answer for a stack of
-frames calls the plan itself (``plan(frames)``).
+frames calls the plan itself (``plan(frames)``), which answers in float32.
+
+**Result dtype**: an engine answer follows its own request's frame. A frame
+of 8-bit integer levels (``uint8``, ``int8``) is answered as ``uint8``
+levels, cast on the device after the output guard has read them as float32
+(``device_stages.answer_dtypes``); any other frame is answered as the
+plan's float32. The values are the same either way; ``EngineStats``
+counts the ``uint8`` answers as ``level_answers``.
 
 The async front is additionally **fault-tolerant** (the
 ``repro.reliability`` wiring): admission validation at ``submit``

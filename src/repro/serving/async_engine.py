@@ -57,7 +57,8 @@ Fault tolerance (the ``repro.reliability`` wiring — PR 6):
     fails exactly that request with ``NonFiniteOutput``; a bad carry row
     quarantines exactly that stream (``packer.quarantine``: reset to cold,
     re-warm through the standard first-frame path) instead of poisoning
-    every later frame.
+    every later frame, and withholds the frame that advanced it
+    (``NonFiniteOutput(carry=True)``), which may read finite.
   * **Watchdog** — ``watchdog_ms`` bounds ``block_until_ready`` per
     in-flight batch. A wedged device (or injected hang) fails that batch's
     futures with a structured ``EngineTimeout``, charges the breaker of the
@@ -117,6 +118,14 @@ executable ``jit_bg_<backend>`` with its ``bg.relayout``, ``bg.kernel``,
 Dispatch is plan-driven: pass a ``repro.plan.BGPlan`` via ``plan=`` (build
 it with ``repro.plan.plan_for``), or in video mode a packer whose plan
 carries the video dispatch.
+
+An answer's dtype follows its own request's frame
+(``device_stages.answer_dtypes``): a frame of 8-bit integer levels (``uint8``
+or ``int8``) gets a ``uint8`` answer, the plan's quantized levels cast in
+the ``bg.split`` program after the output guard has read them as float32;
+every other frame gets the plan's float32 answer. The values are the same
+either way, and a batch that mixes the two hands each request its own.
+``EngineStats.level_answers`` counts the ``uint8`` answers handed out.
 """
 from __future__ import annotations
 
@@ -149,7 +158,8 @@ from repro.reliability import (
     validate_frame,
 )
 from repro.serving.device_stages import (
-    ingest_rows, place_rows, row_dtype, split_rows, split_shards, stack_rows,
+    answer_dtypes, ingest_rows, place_rows, row_dtype, split_rows, split_shards,
+    stack_rows,
 )
 
 __all__ = ["AsyncFrameEngine", "AsyncFrameRequest", "EngineStats", "StageRecord"]
@@ -197,6 +207,11 @@ class EngineStats:
     the plan as 8-bit levels (``repro.kernels.bg_fused.is_level_dtype``),
     so that a fused plan ran grid creation in one bf16 MXU pass.
 
+    ``level_answers`` — answers handed out as ``uint8`` levels, because
+    their own frame came as 8-bit levels
+    (``repro.serving.device_stages.answer_dtypes``); ``completed -
+    level_answers`` answers were handed out as float32.
+
     ``stats["key"]`` indexing is kept as a legacy shim for the former dict
     form; prefer attribute access. ``as_dict()`` feeds exporters (the
     ``BENCH_<ts>.json`` snapshot rows in benchmarks/bench_video_stream.py).
@@ -230,6 +245,7 @@ class EngineStats:
     host_casts: int = 0
     mesh_pad_rows: int = 0
     level_dispatches: int = 0
+    level_answers: int = 0
     latency_samples: Tuple[float, ...] = ()
 
     def __getitem__(self, key: str):
@@ -296,6 +312,7 @@ class EngineStats:
             host_casts=sum(p.host_casts for p in parts),
             mesh_pad_rows=sum(p.mesh_pad_rows for p in parts),
             level_dispatches=sum(p.level_dispatches for p in parts),
+            level_answers=sum(p.level_answers for p in parts),
             latency_samples=tuple(samples),
         )
 
@@ -431,6 +448,7 @@ class AsyncFrameEngine:
         self._host_casts = 0
         self._mesh_pad_rows = 0
         self._level_dispatches = 0
+        self._level_answers = 0
         self._watchdog_trips = 0
 
         self._dispatcher = threading.Thread(
@@ -456,7 +474,9 @@ class AsyncFrameEngine:
         block: bool = True,
         timeout: Optional[float] = None,
     ) -> Future:
-        """Queue one frame; returns a Future resolving to the denoised frame.
+        """Queue one frame; returns a Future resolving to the denoised frame:
+        ``uint8`` levels for a frame of 8-bit integer levels, else float32
+        (the module docstring).
 
         Blocks when ``max_queue`` requests are already pending (``block=False``
         raises ``queue.Full`` instead — the service's load-shed hook).
@@ -587,6 +607,7 @@ class AsyncFrameEngine:
                 mesh_pad_rows=self._mesh_pad_rows,
                 level_dispatches=self._level_dispatches
                 + (getattr(self.packer, "level_dispatches", 0) or 0),
+                level_answers=self._level_answers,
                 latency_samples=tuple(x * 1e3 for x in lat),
             )
 
@@ -721,7 +742,8 @@ class AsyncFrameEngine:
     def _launch_with(self, plan, batch: List[AsyncFrameRequest]):
         """Transfer, stack and dispatch (async) one micro-batch via
         ``plan``. Returns ``(outs, guard)``: the lazy per-request outputs in
-        submission order plus the batch's lazy finite-guard flags. A mesh
+        submission order, each in its own answer dtype, plus the batch's
+        lazy finite-guard flags, which read the float32 batch. A mesh
         plan's batch is padded and placed across its chips in an
         ``engine.place`` span, and each answer stays on its shard's chip."""
         if self.packer is not None:
@@ -732,7 +754,9 @@ class AsyncFrameEngine:
                     carry_limit=self.carry_limit,
                 )
             return [out[r.stream_id] for r in batch], guard
-        rows, casts = ingest_rows([r.frame for r in batch])
+        frames = [r.frame for r in batch]
+        dtypes = answer_dtypes(plan, frames)
+        rows, casts = ingest_rows(frames)
         if casts:
             with self._lock:
                 self._host_casts += casts
@@ -745,7 +769,7 @@ class AsyncFrameEngine:
             guard = DispatchGuard(
                 out_ok=finite_rows(out) if self.output_guard else None
             )
-            return list(split_rows(out, n)), guard
+            return list(split_rows(out, n, dtypes)), guard
         with TraceAnnotation("engine.place", dispatch=batch[0].dispatch, n=n) as span:
             placed, pad = place_rows(rows, plan.mesh)
             span.set_metadata(pad=pad)
@@ -755,7 +779,7 @@ class AsyncFrameEngine:
         out = plan(placed)
         self._count_levels(placed)
         guard = DispatchGuard(out_ok=finite_rows(out) if self.output_guard else None)
-        return split_shards(out, n), guard
+        return split_shards(out, n, dtypes), guard
 
     def _count_levels(self, batch) -> None:
         if is_level_dtype(batch.dtype):
@@ -868,14 +892,14 @@ class AsyncFrameEngine:
     def _resolve(self, batch, outs, guard, out_ok, carry_ok, didx=None) -> None:
         """Post-completion guard pass + future resolution for one batch."""
         # carry quarantine: exactly the streams whose carry went bad
+        bad_carry = []
         if carry_ok is not None and len(guard.carry_sids):
             flags = np.asarray(carry_ok)
-            self._quarantine(
-                [s for s, ok in zip(guard.carry_sids, flags) if not ok]
-            )
+            bad_carry = [s for s, ok in zip(guard.carry_sids, flags) if not ok]
+            self._quarantine(bad_carry)
         errors = None
-        if self.output_guard and out_ok is not None:
-            flags = np.asarray(out_ok)
+        if self.output_guard and (out_ok is not None or bad_carry):
+            flags = None if out_ok is None else np.asarray(out_ok)
             pos = (
                 {sid: i for i, sid in enumerate(guard.order)}
                 if guard.order is not None
@@ -884,11 +908,16 @@ class AsyncFrameEngine:
             errors = []
             for j, req in enumerate(batch):
                 row = j if pos is None else pos[req.stream_id]
-                errors.append(
-                    None
-                    if bool(flags[row])
-                    else NonFiniteOutput(req.uid, stream_id=req.stream_id)
-                )
+                if flags is not None and not bool(flags[row]):
+                    errors.append(NonFiniteOutput(req.uid, stream_id=req.stream_id))
+                elif req.stream_id in bad_carry:
+                    # a poisoned carry can blend to a finite frame (the
+                    # kernel's normalization maps a NaN weight to 0), which
+                    # the output guard cannot see: withhold it too
+                    errors.append(NonFiniteOutput(
+                        req.uid, stream_id=req.stream_id, carry=True))
+                else:
+                    errors.append(None)
             if not any(e is not None for e in errors):
                 errors = None
         self._finish(batch, outs=outs, errors=errors)
@@ -968,6 +997,8 @@ class AsyncFrameEngine:
                 if req.deadline is not None and now > req.deadline:
                     self._deadline_misses += 1
                 self._completed += per_req[i] is None
+                self._level_answers += (
+                    per_req[i] is None and outs[i].dtype == np.uint8)
                 self._failed += per_req[i] is not None
             self._outstanding -= len(batch)
             self._drained.notify_all()

@@ -9,7 +9,8 @@ the primitive JAX happened to dispatch:
   ``bg.ingest`` -- :func:`stack_rows`: the stack of the frames in their
                    common dtype (:func:`row_dtype`), and in video of the
                    per-stream carries in the plan's storage dtype
-  ``bg.split``  -- :func:`split_rows`: the per-request output rows
+  ``bg.split``  -- :func:`split_rows`: the per-request output rows, each
+                   in its answer dtype (:func:`answer_dtypes`)
   ``bg.guard``  -- ``repro.reliability.finite_rows`` / ``carry_ok_rows``
 
 :func:`ingest_rows` decides, from the dtypes it sees, how a micro-batch's
@@ -36,6 +37,15 @@ own it, and the plan executable widens on each chip.
 that made them (one ``split_rows`` per shard); pad rows are never split
 off.
 
+:func:`answer_dtypes` decides each answer's dtype from its own request's
+frame, never from its batch-mates': a frame of 8-bit integer levels gets a
+``uint8`` answer when the plan rounds its answer to whole levels that a
+``uint8`` holds, so the client copies 1 byte per pixel, not 4; every other
+frame gets the plan's float32 answer. The cast is exact (the plan has
+already rounded and clipped), and it comes after the output guard, which
+reads the float32 batch (``repro.reliability.finite_rows``), so a NaN is
+flagged before a cast could turn it into a level.
+
 The plan executable's own stages (``bg.relayout``, ``bg.kernel``,
 ``bg.trim``, ``bg.quantize``) are scoped in ``repro.plan`` and
 ``repro.kernels.bg_fused``.
@@ -44,18 +54,19 @@ from __future__ import annotations
 
 import functools
 from concurrent.futures import ThreadPoolExecutor
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.kernels.bg_fused import is_level_dtype
 from repro.sharding.bg_shard import row_pad
 
 __all__ = [
-    "ingest_rows", "place_rows", "row_dtype", "split_rows", "split_shards",
-    "stack_rows",
+    "answer_dtypes", "ingest_rows", "place_rows", "row_dtype", "split_rows",
+    "split_shards", "stack_rows",
 ]
 
 # host dtypes that travel to the device as submitted (besides integers of at
@@ -103,11 +114,34 @@ def stack_rows(rows, dtype, pad=0):
         return jnp.stack(rows + [jnp.zeros_like(rows[0])] * pad)
 
 
-@functools.partial(jax.jit, static_argnums=1)
-def split_rows(x, n):
-    """The first ``n`` rows of ``x`` as ``n`` arrays (scope ``bg.split``)."""
+def answer_dtypes(plan, frames) -> Optional[Tuple]:
+    """The dtype of each frame's answer from ``plan``, as :func:`split_rows`
+    takes it: ``uint8`` for a frame of 8-bit integer levels
+    (``repro.kernels.bg_fused.is_level_dtype``) when the plan quantizes to
+    a whole ``intensity_max`` of at most 255, so that every answer value is
+    a level a ``uint8`` holds; None (the plan's float32) for any other
+    frame. Returns None when no answer is cast."""
+    top = plan.cfg.intensity_max
+    if not (plan.quantize_output and float(top).is_integer() and 0 <= top <= 255):
+        return None
+    dtypes = tuple(
+        np.dtype(np.uint8)
+        if is_level_dtype(f.dtype if hasattr(f, "dtype") else np.asarray(f).dtype)
+        else None
+        for f in frames)
+    return dtypes if any(d is not None for d in dtypes) else None
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def split_rows(x, n, dtypes=None):
+    """The first ``n`` rows of ``x`` as ``n`` arrays (scope ``bg.split``),
+    row ``i`` cast to ``dtypes[i]`` where ``dtypes`` (from
+    :func:`answer_dtypes`) gives one."""
     with jax.named_scope("bg.split"):
-        return tuple(x[i] for i in range(n))
+        rows = tuple(x[i] for i in range(n))
+        if dtypes is None:
+            return rows
+        return tuple(r if d is None else r.astype(d) for r, d in zip(rows, dtypes))
 
 
 @functools.lru_cache(maxsize=None)
@@ -143,14 +177,16 @@ def place_rows(rows, mesh) -> Tuple[jax.Array, int]:
     return jax.make_array_from_single_device_arrays(shape, sharding, shards), pad
 
 
-def split_shards(x, n) -> List:
+def split_shards(x, n, dtypes=None) -> List:
     """The first ``n`` rows of the batch-sharded ``x`` as ``n`` arrays, each
-    on the device of the shard that holds it: one :func:`split_rows` per
-    shard, on that shard's own data, so nothing crosses between chips."""
+    on the device of the shard that holds it and in its dtype from
+    ``dtypes`` (:func:`split_rows`): one :func:`split_rows` per shard, on
+    that shard's own data, so nothing crosses between chips."""
     rows = []
     for shard in sorted(x.addressable_shards, key=lambda s: s.index[0].start or 0):
         start = shard.index[0].start or 0
         k = min(n - start, shard.data.shape[0])
         if k > 0:
-            rows.extend(split_rows(shard.data, k))
+            mine = None if dtypes is None else dtypes[start:start + k]
+            rows.extend(split_rows(shard.data, k, mine))
     return rows

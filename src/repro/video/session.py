@@ -93,6 +93,7 @@ class MultiStreamPacker:
         self.carry_restores = 0  # lifetime count of snapshot-restored carries
         self.host_casts = 0      # lifetime count of frames converted on the host
         self.level_dispatches = 0  # lifetime count of packs stacked as 8-bit levels
+        self.level_answers = 0  # lifetime count of answers split off as uint8
 
     @property
     def cfg(self) -> BGConfig:
@@ -201,8 +202,10 @@ class MultiStreamPacker:
         stream per pack — the serving engine defers same-stream repeats to
         the next micro-batch). All frames of a pack share one (h, w): the
         batch axis of the fused kernel (and the stacked carry) needs a single
-        static frame shape. Returns stream id -> denoised frame and advances
-        each stream's carry/counter. ``plan=`` dispatches an alternate base
+        static frame shape. Returns stream id -> denoised frame (``uint8``
+        levels for a frame of 8-bit integer levels, else float32:
+        ``repro.serving.device_stages.answer_dtypes``) and advances each
+        stream's carry/counter. ``plan=`` dispatches an alternate base
         plan (a fallback-ladder rung) for this pack only.
         """
         results, _ = self.pack_guarded(frames, plan=plan)
@@ -221,7 +224,8 @@ class MultiStreamPacker:
         finite flags in ``guard.order`` (the pack's sorted stream-id order)
         and ``guard.carry_ok`` per-stream carry health flags (finite and
         ``|carry| < carry_limit``) for ``guard.carry_sids`` — the streams
-        whose temporal carry advanced this pack. The flags are tiny
+        whose temporal carry advanced this pack. Both read the pack's float32
+        output and the carries before any answer is cast. The flags are tiny
         ``jnp.isfinite`` reductions launched with the dispatch (they ride
         the same async dataflow; nothing here synchronizes) — the engine
         realizes them with the outputs and quarantines bad carries.
@@ -242,7 +246,7 @@ class MultiStreamPacker:
         )
         from repro.kernels.bg_fused import is_level_dtype
         from repro.serving.device_stages import (
-            ingest_rows, row_dtype, split_rows, stack_rows,
+            answer_dtypes, ingest_rows, row_dtype, split_rows, stack_rows,
         )
 
         if carry_limit is None:
@@ -253,6 +257,8 @@ class MultiStreamPacker:
         if missing:
             raise KeyError(f"streams not open: {missing!r}")
         sids = sorted(frames, key=repr)
+        base = self.plan if plan is None else plan
+        dtypes = answer_dtypes(base, [frames[s] for s in sids])
         rows, casts = ingest_rows([frames[s] for s in sids])
         self.host_casts += casts
         shapes = {r.shape for r in rows}
@@ -267,7 +273,6 @@ class MultiStreamPacker:
         # clamped to the per-device shard, exactly the clamp the kernel
         # would apply — an explicit plan decision instead of an implicit
         # kernel one)
-        base = self.plan if plan is None else plan
         plan = base.with_tile(base.tile_for(len(sids)))
         carry_sids = ()
         carry_ok = None
@@ -276,7 +281,7 @@ class MultiStreamPacker:
             # all-cold pack: the carry-free per-frame fused path — nothing
             # temporal is materialized anywhere (temporal_denoise contract)
             out, _ = temporal_denoise(batch, alpha=0.0, plan=plan)
-            results = dict(zip(sids, split_rows(out, len(sids))))
+            results = dict(zip(sids, split_rows(out, len(sids), dtypes)))
         else:
             # ONE dispatch for the whole pack: the fused kernel's in-kernel
             # EMA takes a per-stream alpha row, and a == 0 rows (cold
@@ -300,8 +305,8 @@ class MultiStreamPacker:
                 batch, carry=carry, alpha=alpha, plan=plan
             )
             warm_rows = [i for i, s in enumerate(sids) if sessions[s].alpha > 0.0]
-            outs = split_rows(out, len(sids))
-            carries = split_rows(new_carry, len(sids))
+            outs = split_rows(out, len(sids), dtypes)
+            carries = split_rows(new_carry, len(sids))  # the storage dtype
             for i, s in enumerate(sids):
                 results[s] = outs[i]
                 if sessions[s].alpha > 0.0:
@@ -316,6 +321,8 @@ class MultiStreamPacker:
             sessions[s].frames_seen += 1
         if is_level_dtype(batch.dtype):
             self.level_dispatches += 1
+        if dtypes is not None:
+            self.level_answers += sum(d is not None for d in dtypes)
         guard = DispatchGuard(
             out_ok=finite_rows(out),
             order=tuple(sids),

@@ -114,6 +114,40 @@ def test_subprocess_parity_bit_equal_with_local(payload):
         local.close(timeout=10)
 
 
+def test_uint8_answers_cross_the_codec_and_router_unchanged(payload):
+    """8-bit frames through a router over a child-process worker come back
+    as uint8 answers, the same levels as the in-process worker's float32
+    answers to the same values, warm carries included; a float32 frame
+    still comes back float32; the fleet's stats count the uint8 answers."""
+    from repro.fleet import FleetRouter
+
+    rng = np.random.default_rng(7)
+    frames = {s: [rng.integers(0, 256, (H, W), dtype=np.uint8) for _ in range(3)]
+              for s in (0, 1)}
+    local = LocalWorker("loc", payload, max_batch=4, batch_window_ms=1.0)
+    router = FleetRouter(workers=[_sub(payload)], health_interval_s=None)
+    try:
+        for s in (0, 1):
+            local.open_stream(s, alpha=ALPHA)
+            router.open_stream(s, alpha=ALPHA)
+        for t in range(3):
+            kind = np.float32 if t == 2 else np.uint8
+            futs = {s: (router.submit(frames[s][t].astype(kind), stream_id=s),
+                        local.submit(frames[s][t].astype(np.float32), stream_id=s))
+                    for s in (0, 1)}
+            for s, (remote, near) in futs.items():
+                a = np.asarray(remote.result(timeout=60))
+                b = np.asarray(near.result(timeout=60))
+                assert a.dtype == kind and b.dtype == np.float32
+                assert a.shape == b.shape
+                np.testing.assert_array_equal(a, b)
+        merged = router.stats().merged
+        assert merged.completed == 6 and merged.level_answers == 4
+    finally:
+        router.close(timeout=10)
+        local.close(timeout=10)
+
+
 def test_wid_and_sid_must_be_json_plain(payload):
     with pytest.raises(TypeError, match="JSON-plain"):
         SubprocessWorker(("tuple", "wid"), payload)

@@ -1,10 +1,11 @@
 """The serving engine on a four-device batch mesh: placement, the mesh
-executable, the split and the guard.
+executable, the split and the guard, and the answers' dtypes.
 
 A mesh plan's micro-batch is padded to a multiple of the mesh size on the
 host and placed sharded ``P("batch")``, so each row reaches only the device
 of its shard; the executable neither pads nor trims, so it holds no
-collective; each answer is split off on its shard's device and stays there.
+collective; each answer is split off on its shard's device and stays there,
+cast there to ``uint8`` when its frame came as 8-bit levels.
 Everything here runs once, in one subprocess with four forced host devices
 (the main pytest process keeps its single-device view, as in
 test_bg_sharded.py); the tests read what it reports.
@@ -50,9 +51,9 @@ frames = np.clip(np.round(np.asarray(add_gaussian_noise(
     synthetic_batch(9, h, w, seed=3), 30.0, seed=4))), 0, 255).astype(np.uint8)
 dev_ids = [d.id for d in mesh.devices.flat]
 
-def serve(k):
+def serve(k, rows=frames):
     with AsyncFrameEngine(plan=plan, max_batch=k, batch_window_ms=60000.0) as eng:
-        outs = [f.result(timeout=600) for f in [eng.submit(f) for f in frames[:k]]]
+        outs = [f.result(timeout=600) for f in [eng.submit(f) for f in rows[:k]]]
         return outs, eng.stats()
 
 worst, largest = 0.0, 0.0
@@ -71,8 +72,21 @@ for k in LENGTHS:
         'shard_devices': [[dev_ids[j // per]] for j in range(k)],
         'dispatches': st.dispatches, 'mesh_pad_rows': st.mesh_pad_rows,
         'pad_in_dict': st.as_dict()['mesh_pad_rows'],
+        'dtypes': [str(o.dtype) for o in outs], 'level_answers': st.level_answers,
     }
 report['reference'] = {'off_share_worst': worst, 'max_abs_diff': largest}
+
+# float32 frames, and a batch that mixes them with uint8 ones, over shards
+want = np.asarray(single(jnp.asarray(frames[:6])))
+for name, rows in (('float32', list(frames[:6].astype(np.float32))),
+                   ('mixed', [f.astype(np.float32) if i % 2 else f
+                              for i, f in enumerate(frames[:6])])):
+    outs, st = serve(6, rows)
+    report['dtypes_' + name] = {
+        'dtypes': [str(o.dtype) for o in outs], 'level_answers': st.level_answers,
+        'equal': bool(np.array_equal(np.stack([np.asarray(o) for o in outs]), want)),
+        'answer_devices': [sorted(d.id for d in o.devices()) for o in outs],
+    }
 
 def found(text):
     return [c for c in COLLECTIVES if c in text]
@@ -87,7 +101,9 @@ for k in (6, 8):
         'guard': found(_guard_programs()[0].lower(out).compile().as_text()),
         'split': sorted({c for s in shards if min(per, k - s.index[0].start) > 0
                          for c in found(split_rows.lower(
-                             s.data, min(per, k - s.index[0].start)).compile().as_text())}),
+                             s.data, min(per, k - s.index[0].start),
+                             (np.dtype(np.uint8),) * min(per, k - s.index[0].start)
+                         ).compile().as_text())}),
         'out_spec': str(out.sharding.spec),
     }
     report['placed_%d' % k] = {
@@ -217,6 +233,24 @@ def test_device_rows_reach_their_shards_without_the_host(report):
     assert rows["pad"] == 1 and rows["shape"][0] == 8 and rows["dtype"] == "float32"
     assert rows["devices"] == [[0, 0], [1, 1], [2, 2], [3, 3]]
     assert rows["rows_equal"] and rows["answers_equal"]
+
+
+@pytest.mark.parametrize("k", LENGTHS)
+def test_mesh_answers_of_uint8_frames_are_uint8(report, k):
+    r = report[str(k)]
+    assert r["dtypes"] == ["uint8"] * k and r["level_answers"] == k
+
+
+@pytest.mark.parametrize("name,dtypes", [
+    ("float32", ["float32"] * 6),
+    ("mixed", ["uint8", "float32"] * 3),
+])
+def test_mesh_answer_dtype_follows_its_own_frame(report, name, dtypes):
+    r = report["dtypes_" + name]
+    assert r["dtypes"] == dtypes and r["equal"]
+    assert r["level_answers"] == dtypes.count("uint8")
+    # rows 0-1 on device 0, 2-3 on 1, 4-5 on 2: each answer on its shard's
+    assert r["answer_devices"] == [[0], [0], [1], [1], [2], [2]]
 
 
 def test_each_answer_lives_on_its_shards_device_only(report):
